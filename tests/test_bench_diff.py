@@ -1,10 +1,10 @@
 """scripts/bench_diff.py — the cross-round bench regression sentinel.
 
-Tier-1 (pure python, no jax): the sentinel must (a) run over the REAL
-checked-in BENCH_r04/BENCH_r05 rounds and structurally kill the 640 ns
-shape confound (quick-floor record unpaired, same-shape serving NOT a
-regression), and (b) flag a synthetically injected per-stage regression
-past its noise threshold.
+Tier-1 (pure python, no jax): the sentinel must (a) run over two rounds
+in the driver's wrapper format and structurally kill the 640 ns shape
+confound of rounds 4 and 5 (the small-shape record unpaired, same-shape
+serving NOT a regression), and (b) flag a synthetically injected
+per-stage regression past its noise threshold.
 """
 
 import importlib.util
@@ -17,8 +17,45 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "scripts", "bench_diff.py")
-R04 = os.path.join(REPO, "BENCH_r04.json")
-R05 = os.path.join(REPO, "BENCH_r05.json")
+
+# The headline figures of rounds 4 and 5 (CPU, one core), in the driver's
+# wrapper format: {"tail": <the last stdout lines>}. Round 4 printed a
+# (20k rows, 5 trees) record before its full record; round 5 printed a
+# projection line before its full record.
+_METRIC = "gbt_train_rows_x_trees_per_sec_per_chip"
+_ROUND4_LINES = [
+    "# a stderr line the loader must skip",
+    {"metric": _METRIC, "value": 368735.9, "unit": "rows*trees/s",
+     "backend": "cpu", "rows": 20000, "trees": 5, "depth": 6,
+     "train_wall_s": 0.27, "train_wall_incl_compile_s": 8.15,
+     "vs_baseline": 0.06, "infer_ns_per_example": 640.5},
+    {"metric": _METRIC, "value": 599451.1, "unit": "rows*trees/s",
+     "backend": "cpu", "rows": 500000, "trees": 20, "depth": 6,
+     "train_wall_s": 16.68, "train_wall_incl_compile_s": 24.04,
+     "vs_baseline": 0.202, "infer_ns_per_example": 1451.2},
+]
+_ROUND5_LINES = [
+    {"metric": _METRIC + "_PROJECTED", "value": 29082813.7,
+     "backend": "analytic_projection", "rows": 500000, "depth": 6},
+    {"metric": _METRIC, "value": 1466165.9, "unit": "rows*trees/s",
+     "backend": "cpu", "rows": 500000, "trees": 20, "depth": 6,
+     "train_wall_s": 6.82, "train_wall_incl_compile_s": 13.9,
+     "vs_baseline": 0.494, "infer_ns_per_example": 1380.7},
+]
+
+
+def _wrapper(path, lines):
+    tail = "\n".join(
+        ln if isinstance(ln, str) else json.dumps(ln) for ln in lines
+    )
+    path.write_text(json.dumps({"n": 1, "rc": 0, "tail": tail}))
+    return str(path)
+
+
+@pytest.fixture()
+def rounds(tmp_path):
+    return (_wrapper(tmp_path / "r04.json", _ROUND4_LINES),
+            _wrapper(tmp_path / "r05.json", _ROUND5_LINES))
 
 
 def _load():
@@ -38,14 +75,17 @@ def bd():
 # ---------------------------------------------------------------------- #
 
 
-def test_loads_driver_wrapper_and_drops_projections(bd):
-    recs = bd.load_records(R04)
-    # r04's tail holds the quick floor + the full record; projections
-    # (if any) and error records must never survive loading.
-    assert len(recs) >= 2
-    assert all("PROJECTED" not in r["metric"] for r in recs)
+def test_loads_driver_wrapper_and_drops_projections(bd, rounds):
+    r04, r05 = rounds
+    recs = bd.load_records(r04)
+    # r04's tail holds the small record + the full record.
+    assert len(recs) == 2
     shapes = {bd.shape_key(r) for r in recs}
-    assert len(shapes) == 2  # quick (20k, 5) and full (500k, 20)
+    assert len(shapes) == 2  # (20k, 5) and (500k, 20)
+    # Projections must never survive loading.
+    recs = bd.load_records(r05)
+    assert len(recs) == 1
+    assert all("PROJECTED" not in r["metric"] for r in recs)
 
 
 def test_loads_jsonl_and_single_record(bd, tmp_path):
@@ -71,19 +111,19 @@ def test_error_records_dropped(bd, tmp_path):
 # ---------------------------------------------------------------------- #
 
 
-def test_r04_r05_pairs_by_shape_and_flags_no_false_regression(bd):
-    """The acceptance criterion verbatim: run on the checked-in rounds,
-    the quick-floor shape must be UNPAIRED (never compared — the 640 ns
-    confound class is dead structurally) and the same-shape serving
-    fields must not be flagged as a regression (they improved 5%)."""
-    doc = bd.diff(R04, R05)
+def test_r04_r05_pairs_by_shape_and_flags_no_false_regression(bd, rounds):
+    """Run on the figures of rounds 4 and 5, the small shape must be
+    UNPAIRED (never compared — the 640 ns confound class is dead
+    structurally) and the same-shape serving fields must not be flagged
+    as a regression (they improved 5%)."""
+    doc = bd.diff(*rounds)
     assert doc["ok"], doc["regressions"]
     assert doc["regressions"] == []
     # Exactly one shared shape: the (500000, 20) full record.
     assert len(doc["pairs"]) == 1
     shape = doc["pairs"][0]["shape"]
     assert (shape["rows"], shape["trees"]) == (500_000, 20)
-    # The 640.5 ns quick-floor record exists only in r04: unpaired.
+    # The 640.5 ns small-shape record exists only in r04: unpaired.
     assert any("rows=20000" in s for s in doc["unpaired_a"])
     # Same-shape serving: 1451.2 -> 1380.7 is an improvement-direction
     # move inside the noise band — anything but "regression".
@@ -291,8 +331,8 @@ def test_cli_markdown_json_and_exit_codes(bd, tmp_path):
     assert out2.returncode == 0
 
 
-def test_markdown_mentions_unpaired_confound_warning(bd):
-    doc = bd.diff(R04, R05)
+def test_markdown_mentions_unpaired_confound_warning(bd, rounds):
+    doc = bd.diff(*rounds)
     md = bd.to_markdown(doc)
     assert "NOT compared" in md
     assert "640" in md  # the lesson is named in the report itself

@@ -1,14 +1,12 @@
-"""bench.py artifact protocol (VERDICT r3 #1: the bench must NEVER
-yield an unparseable artifact). The driver parses the LAST JSON line on
-stdout; every exit path — clean, SIGTERM mid-run, watchdog — must leave
-one."""
+"""bench.py record protocol: one parseable JSON record per run, every
+record names the device it ran on (platform, device_kind, device count),
+a machine with no accelerator is an error unless --cpu is passed, and a
+failure exits non-zero instead of degrading to a record."""
 
 import json
 import os
-import signal
 import subprocess
 import sys
-import time
 
 import pytest
 
@@ -33,9 +31,12 @@ def test_small_cpu_run_emits_parseable_record():
     )
     assert out.returncode == 0
     rec = _last_json(out.stdout)
-    assert rec["metric"] == "gbt_train_rows_x_trees_per_sec_per_chip"
+    assert rec["metric"] == "gbt_train_rows_x_trees_per_sec"
     assert rec["value"] > 0
     assert "vs_baseline" in rec
+    # The record names the device it measured.
+    assert rec["platform"] == "cpu" and rec["backend"] == "cpu"
+    assert rec["device_kind"] and rec["device_count"] >= 1
     # The ingestion/binning split rides every headline record so the
     # trajectory tracks the fused-binning target (round 6).
     assert "ingest_s" in rec and rec["ingest_s"] >= 0
@@ -67,8 +68,8 @@ def test_small_cpu_run_emits_parseable_record():
     assert rec["infer_p50_ns"] > 0
     assert rec["infer_p99_ns"] >= rec["infer_p50_ns"]
     # Serving-regression guard (this round): the --small shape
-    # (20k rows, 5 trees) has a recorded floor (BENCH_r04's 640.5 ns
-    # quick floor); the record must carry the comparison, and the
+    # (20k rows, 5 trees) has a recorded CPU floor (640.5 ns); the
+    # record must carry the comparison, and the
     # measured p50 must hold the floor (1.5x margin absorbs box
     # contention — the recorded runs show the native engine well
     # under it).
@@ -156,10 +157,6 @@ def test_small_cpu_run_emits_parseable_record():
     assert rec["train_peak_rss_bytes"] > 0
     assert rec["serve_bank_bytes"] > 0
     assert rec["infer_peak_rss_delta_bytes"] >= 0
-    # The backend-probe outcome is persisted across rounds; the record
-    # names whether this run used the cache (--cpu skips the probe, so
-    # here it is simply present and False).
-    assert rec["probe_cached"] in (True, False)
     if rec["route_impl"] == "native":
         assert "route_s" in rec and rec["route_s"] >= 0
         assert "update_s" in rec and rec["update_s"] >= 0
@@ -225,10 +222,10 @@ def test_small_cpu_run_with_distributed_family():
     assert rec["dist_rpc_payload_bytes"] > 0
 
 
-def test_bench_dist_workers_env_validation(tmp_path):
+def test_bench_dist_workers_env_validation():
     """A malformed YDF_TPU_BENCH_DIST_WORKERS lands as a recorded
     family error, never a crashed bench (artifact protocol)."""
-    mod = _load_bench(tmp_path)
+    mod = _load_bench()
     rec = {}
     os.environ["YDF_TPU_BENCH_DIST_WORKERS"] = "banana"
     try:
@@ -277,10 +274,10 @@ def test_small_cpu_run_with_cache_build_family():
     assert rec["sketch_bytes"] < rec["cache_build_peak_rss_bytes"]
 
 
-def test_bench_cache_workers_env_validation(tmp_path):
+def test_bench_cache_workers_env_validation():
     """A malformed YDF_TPU_BENCH_CACHE_WORKERS lands as a recorded
     family error, never a crashed bench (artifact protocol)."""
-    mod = _load_bench(tmp_path)
+    mod = _load_bench()
     rec = {}
     os.environ["YDF_TPU_BENCH_CACHE_WORKERS"] = "one"
     try:
@@ -293,10 +290,10 @@ def test_bench_cache_workers_env_validation(tmp_path):
     assert rec2 == {}
 
 
-def test_bench_fleet_elastic_env_validation(tmp_path):
+def test_bench_fleet_elastic_env_validation():
     """A malformed YDF_TPU_BENCH_FLEET_ELASTIC lands as a recorded
     family error, never a crashed bench (artifact protocol)."""
-    mod = _load_bench(tmp_path)
+    mod = _load_bench()
     rec = {}
     os.environ["YDF_TPU_BENCH_FLEET_ELASTIC"] = "yes"
     try:
@@ -306,7 +303,7 @@ def test_bench_fleet_elastic_env_validation(tmp_path):
     assert "must be 0 or 1" in rec["fleet_family_error"]
 
 
-def test_bench_fleet_family_elastic_mode(tmp_path):
+def test_bench_fleet_family_elastic_mode():
     """YDF_TPU_BENCH_FLEET_ELASTIC=1 (in-process, tier-1): the fleet
     closed loop spans a live add_replica of a freshly spawned replica
     and a remove_replica drain of it, and the record carries the
@@ -319,7 +316,7 @@ def test_bench_fleet_family_elastic_mode(tmp_path):
     import ydf_tpu as ydf
     from ydf_tpu.config import Task
 
-    mod = _load_bench(tmp_path)
+    mod = _load_bench()
     rng = np.random.RandomState(0)
     rows = 1500
     data = {
@@ -356,78 +353,55 @@ def test_bench_fleet_family_elastic_mode(tmp_path):
     assert rec["fleet_replicas"] == 2
 
 
-def _load_bench(tmp_path):
-    """Imports bench.py as a module (its top level only defines) with
-    the probe cache redirected into the test's tmp dir."""
+def _load_bench():
+    """Imports bench.py as a module (its top level only defines)."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location("bench_under_test", BENCH)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    mod.PROBE_CACHE_PATH = str(tmp_path / "probe_cache.json")
-    mod.PROBE_CACHE_TTL_S = 3600.0
     return mod
 
 
-def test_probe_cache_positive_roundtrip(tmp_path):
-    """A fresh positive probe outcome is served from disk — no
-    subprocess probe, `cached` in the log, `_PROBE_CACHED` armed for
-    the record field."""
-    mod = _load_bench(tmp_path)
-    mod._probe_cache_store("cpu", timed_out=False)
-    log = []
-    assert mod.probe_backend(log) == "cpu"
-    assert log == [log[0]] and log[0]["cached"] is True
-    assert log[0]["backend"] == "cpu"
-    assert mod._PROBE_CACHED is True
-    assert mod._PROBE_TIMED_OUT is False
-
-
-def test_probe_cache_negative_timeout_skips_reprobe(tmp_path):
-    """The BENCH_r02-r05 fix: a persisted timed-out probe arms the
-    in-run negative flag immediately, so the round never re-burns the
-    240 s hang."""
-    mod = _load_bench(tmp_path)
-    mod._probe_cache_store(None, timed_out=True)
-    log = []
-    assert mod.probe_backend(log) is None
-    assert log[0]["cached"] is True and log[0]["timed_out"] is True
-    assert mod._PROBE_TIMED_OUT is True
-    # Further probes short-circuit on the cached negative.
-    log2 = []
-    assert mod.probe_backend(log2) is None
-    assert log2[0].get("cached") or "skipped" in log2[0]
-
-
-def test_probe_cache_ttl_expiry_and_corruption(tmp_path):
-    mod = _load_bench(tmp_path)
-    mod._probe_cache_store("tpu", timed_out=False)
-    assert mod._probe_cache_load()["backend"] == "tpu"
-    mod.PROBE_CACHE_TTL_S = 0.0  # expired → live probe required
-    assert mod._probe_cache_load() is None
-    mod.PROBE_CACHE_TTL_S = 3600.0
-    with open(mod.PROBE_CACHE_PATH, "w") as f:
-        f.write("{not json")
-    assert mod._probe_cache_load() is None  # corrupt file → live probe
-
-
-@pytest.mark.slow
-def test_sigterm_mid_run_still_leaves_a_record():
-    """The round-3 failure: the driver killed bench.py before emission
-    and the artifact was unparseable. SIGTERM at any point must flush a
-    structured record and exit 0."""
+def test_no_accelerator_without_cpu_flag_is_an_error():
+    """Without --cpu the default backend is used, and finding no
+    accelerator exits non-zero with no record on stdout."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.Popen(
-        [sys.executable, BENCH, "--cpu", "--small"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-        cwd=REPO, env=env,
+    out = subprocess.run(
+        [sys.executable, BENCH, "--small"],
+        capture_output=True, text=True, timeout=120, cwd=REPO, env=env,
     )
-    time.sleep(4)  # mid-compile/train, before any result
-    p.send_signal(signal.SIGTERM)
-    stdout, _ = p.communicate(timeout=120)
-    assert p.returncode == 0
-    rec = _last_json(stdout)
-    assert rec["metric"] == "gbt_train_rows_x_trees_per_sec_per_chip"
-    # Either a banked partial (value > 0) or a structured zero-record
-    # naming the signal — both parse; neither is a stack trace.
-    assert "value" in rec
+    assert out.returncode != 0
+    assert "no accelerator" in out.stderr and "'cpu'" in out.stderr
+    assert not [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
+
+
+def test_failure_propagates_instead_of_exiting_zero(monkeypatch):
+    """A failing measurement is a failing run: main() lets the exception
+    out (non-zero exit, traceback) and prints no record."""
+    import ydf_tpu.config
+
+    mod = _load_bench()
+
+    def boom(*a, **k):
+        raise RuntimeError("measurement failed")
+
+    monkeypatch.setattr(mod, "run_bench", boom)
+    monkeypatch.setattr(ydf_tpu.config, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--cpu", "--small"])
+    with pytest.raises(RuntimeError, match="measurement failed"):
+        mod.main()
+
+
+def test_every_record_names_the_device(capsys):
+    """emit() is the one way a record leaves bench.py, and it stamps the
+    device as JAX reports it."""
+    import jax
+
+    mod = _load_bench()
+    mod.emit({"metric": "m", "value": 1.0})
+    rec = _last_json(capsys.readouterr().out)
+    d = jax.devices()
+    assert rec["platform"] == d[0].platform == "cpu"
+    assert rec["device_kind"] == d[0].device_kind
+    assert rec["device_count"] == len(d)

@@ -1,9 +1,7 @@
 """Native-build smoke check: the tier-1 run must fail LOUDLY — not
 silently benchmark the ~5x-slower XLA fallback — when the native kernel
 library cannot be built, is stale against its sources, or its FFI
-registration is missing (PR 3 satellite; the historical failure mode
-was `jax.ffi` vs `jax.extend.ffi` silently deselecting the native
-histogram for a whole round).
+registration is missing (PR 3 satellite).
 
 These tests assert which impl the suite ACTUALLY exercises. The only
 sanctioned skip is a container with no C++ toolchain at all (not this

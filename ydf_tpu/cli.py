@@ -395,6 +395,9 @@ def cmd_worker(args):
 
 
 def main(argv=None):
+    from ydf_tpu.config import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(prog="ydf_tpu", description=__doc__)
     sub = ap.add_subparsers(dest="command", required=True)
 

@@ -491,6 +491,14 @@ class GradientBoostedTreesLearner(GenericLearner):
             bins_va = shard_bins(self.mesh, bins_va)
             y_va = pmesh.shard_batch(self.mesh, y_va)
             w_va = pmesh.shard_batch(self.mesh, w_va)
+            # Which devices each sharded training input landed on
+            # (training_logs["mesh"]; chip_smoke.py --mesh checks it).
+            mesh_input_devices = {
+                name: sorted(d.id for d in a.sharding.device_set)
+                for name, a in (
+                    ("bins", bins_tr), ("labels", y_tr), ("weights", w_tr)
+                )
+            }
             if set_tr is not None:
                 # Set features ride the data axis only (replicated over the
                 # feature axis — their per-item stats all-reduce via the
@@ -1011,6 +1019,24 @@ class GradientBoostedTreesLearner(GenericLearner):
             # count, reduce bytes, per-verb RPC p50s, recoveries) — the
             # bench family's source (bench.measure_distributed_family).
             model.training_logs["distributed"] = logs["distributed"]
+        else:
+            # What "auto" resolved to for this train on this backend
+            # (chip_smoke.py asserts the TPU answers).
+            from ydf_tpu.ops.histogram import (
+                resolve_hist_impl,
+                resolve_hist_quant,
+            )
+
+            model.training_logs["implementations"] = {
+                "hist_impl": resolve_hist_impl("auto"),
+                "hist_quant": resolve_hist_quant(None),
+                "route_impl": route_impl,
+            }
+        if self.mesh is not None:
+            model.training_logs["mesh"] = {
+                "shape": dict(self.mesh.shape),
+                "input_devices": mesh_input_devices,
+            }
         timer.seconds["finalize"] = time.perf_counter() - _t_fin
         # Per-stage wall breakdown (reference Monitoring per-stage logs);
         # device_loop includes XLA compile on first call.

@@ -979,52 +979,47 @@ class GenericModel:
             # (K trees/iter) go through the routed engine per class.
             and getattr(self, "num_trees_per_iter", 1) == 1
         ):
-            try:
-                from ydf_tpu.serving import (
-                    build_binned_quickscorer,
-                    build_quickscorer,
-                )
+            # A builder returns None for a forest outside its envelope;
+            # a compile or run failure of an applicable engine raises.
+            from ydf_tpu.serving import (
+                build_binned_quickscorer,
+                build_quickscorer,
+            )
+            from ydf_tpu.serving.native_serve import (
+                build_native_binned_engine,
+                build_native_engine,
+            )
 
-                qs = build_quickscorer(self)
-                if qs is not None:
-                    eng["quickscorer"] = _time_engine(
-                        lambda: qs(jx_num, jx_cat)
-                    )
-                bq = build_binned_quickscorer(self)
-                if bq is not None:
-                    bins_u8 = jnp.asarray(
-                        self.binner.transform(ds)[
-                            :, : self.binner.num_scalar
-                        ]
-                    )
-                    eng["binned_quickscorer"] = _time_engine(
-                        lambda: bq(bins_u8, jx_cat)
-                    )
-            except Exception as e:  # engine inapplicable to this forest
-                eng["quickscorer_error"] = f"{type(e).__name__}: {e}"
-            try:
-                from ydf_tpu.serving.native_serve import (
-                    build_native_binned_engine,
-                    build_native_engine,
+            qs = build_quickscorer(self)
+            if qs is not None:
+                eng["quickscorer"] = _time_engine(
+                    lambda: qs(jx_num, jx_cat)
                 )
-
-                nb = build_native_engine(self)
-                if nb is not None:
-                    eng["native_batch"] = _time_engine(
-                        lambda: nb(x_num, x_cat)
-                    )
-                nbb = build_native_binned_engine(self)
-                if nbb is not None:
-                    bins_nb = np.ascontiguousarray(
-                        self.binner.transform(ds)[
-                            :, : self.binner.num_scalar
-                        ]
-                    )
-                    eng["native_binned"] = _time_engine(
-                        lambda: nbb(bins_nb)
-                    )
-            except Exception as e:  # engine inapplicable to this forest
-                eng["native_batch_error"] = f"{type(e).__name__}: {e}"
+            bq = build_binned_quickscorer(self)
+            if bq is not None:
+                bins_u8 = jnp.asarray(
+                    self.binner.transform(ds)[
+                        :, : self.binner.num_scalar
+                    ]
+                )
+                eng["binned_quickscorer"] = _time_engine(
+                    lambda: bq(bins_u8, jx_cat)
+                )
+            nb = build_native_engine(self)
+            if nb is not None:
+                eng["native_batch"] = _time_engine(
+                    lambda: nb(x_num, x_cat)
+                )
+            nbb = build_native_binned_engine(self)
+            if nbb is not None:
+                bins_nb = np.ascontiguousarray(
+                    self.binner.transform(ds)[
+                        :, : self.binner.num_scalar
+                    ]
+                )
+                eng["native_binned"] = _time_engine(
+                    lambda: nbb(bins_nb)
+                )
         out["engines_ns_per_example"] = eng
         return out
 

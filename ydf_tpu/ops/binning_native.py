@@ -113,10 +113,8 @@ def binning_native(values, boundaries, nbounds, impute):
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     F, n = values.shape
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_binning",
         jax.ShapeDtypeStruct((n, F), jnp.uint8),
     )(

@@ -80,13 +80,22 @@ _HIST_IMPLS = frozenset(
 
 # Gradient-quantization modes for the stats operand (the one-hot operand
 # is exact in bf16, so only `stats` needs a precision strategy):
-#   f32     exact — bit-identical to the pre-quantization pipeline.
+#   f32     exact — bit-identical to the pre-quantization pipeline. The
+#           matmul impl asks for Precision.HIGHEST: XLA:TPU's default
+#           for an f32 dot is ONE bf16 pass, which rounds every gradient
+#           to 8 mantissa bits. Measured on a v5e (PR 21, CHANGES.md):
+#           at the default the sums sit 9e-4 of a cell's magnitude from
+#           the segment impl and a 200k x 28 GBT diverges (train loss
+#           rises from the second tree, leaf values reach -559); at
+#           HIGHEST they sit 3e-7 away and losses and AUC equal
+#           segment's.
 #   bf16x2  split every f32 stat column into a bf16 high part plus a
 #           bf16 residual; the contraction runs on native bf16 MXU tiles
-#           (2 passes instead of the 3 an f32 operand decomposes into)
-#           with f32 accumulation. Reconstruction error per example is
-#           bounded by the bf16 rounding of the RESIDUAL, ~2^-16 of the
-#           stat magnitude (docs/histogram_quantization.md).
+#           (2 passes instead of the 6 XLA:TPU spends on an f32 operand
+#           at HIGHEST) with f32 accumulation. Reconstruction error per
+#           example is bounded by the bf16 rounding of the RESIDUAL,
+#           ~2^-16 of the stat magnitude
+#           (docs/histogram_quantization.md).
 #   int8    LightGBM-GPU-style quantized gradients: stats are rounded to
 #           int8 with a dynamic per-column scale (per-layer in the
 #           grower), accumulated EXACTLY in integers, and dequantized
@@ -181,6 +190,11 @@ def _histogram_matmul(
         jnp.int32 if jnp.issubdtype(stats.dtype, jnp.integer)
         else jnp.float32
     )
+    # Only the f32 operand needs more than one pass (see _HIST_QUANTS);
+    # the bf16x2 halves and int8 stats are exact in their tile type.
+    precision = (
+        jax.lax.Precision.HIGHEST if stats.dtype == jnp.float32 else None
+    )
 
     def one_chunk(carry, xs):
         b_chunk, s_chunk, st_chunk = xs  # [chunk, F], [chunk], [chunk, S]
@@ -201,6 +215,7 @@ def _histogram_matmul(
                 oh,
                 a_chunk,
                 (((0,), (0,)), ((), ())),
+                precision=precision,
                 preferred_element_type=acc_dtype,
             )  # [B, L*S]
             return acc.at[f].add(h)

@@ -67,13 +67,11 @@ def histogram_native(bins, slot, stats, num_slots: int, num_bins: int):
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     n, F = bins.shape
     S = stats.shape[1]
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_histogram",
         jax.ShapeDtypeStruct((num_slots, F, num_bins, S), jnp.float32),
     )(
@@ -94,13 +92,11 @@ def histogram_native_q8(
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     n, F = bins.shape
     S = stats_q8.shape[1]
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_histogram_q8",
         jax.ShapeDtypeStruct((num_slots, F, num_bins, S), jnp.float32),
     )(

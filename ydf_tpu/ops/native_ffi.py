@@ -65,21 +65,6 @@ def sanitize_mode():
     return env
 
 
-def ffi_module():
-    """jax's FFI namespace across versions: `jax.ffi` (>= 0.5) or
-    `jax.extend.ffi` (0.4.x). The old per-module code hardcoded
-    `jax.ffi`, which on jax 0.4.37 raised AttributeError inside the
-    swallow-everything registration path — i.e. the native histogram
-    kernel silently deselected itself on exactly this box (the invisible
-    ~5x regression ADVICE r5 warned about)."""
-    import jax
-
-    ffi = getattr(jax, "ffi", None)
-    if ffi is None:
-        from jax.extend import ffi  # jax 0.4.x
-    return ffi
-
-
 class NativeLibrary:
     """One native shared library: built on first use, loaded once,
     optionally registered as XLA FFI custom-call targets.
@@ -176,7 +161,9 @@ class NativeLibrary:
         cmd += list(self.extra_cflags)
         cmd += ["-I", NATIVE_DIR]
         if self.needs_ffi_headers:
-            cmd += ["-I", ffi_module().include_dir()]
+            import jax
+
+            cmd += ["-I", jax.ffi.include_dir()]
         os.makedirs(BUILD_DIR, exist_ok=True)
         # Per-process temp name: concurrent cold builds must not
         # os.replace each other's half-written objects.
@@ -226,11 +213,12 @@ class NativeLibrary:
                 return True
             try:
                 failpoints.hit("native.register")
-                ffi = ffi_module()
+                import jax
+
                 for target, symbol in self.ffi_targets.items():
-                    ffi.register_ffi_target(
+                    jax.ffi.register_ffi_target(
                         target,
-                        ffi.pycapsule(getattr(lib, symbol)),
+                        jax.ffi.pycapsule(getattr(lib, symbol)),
                         platform="cpu",
                     )
                 self._ffi_registered = True

@@ -137,14 +137,12 @@ def route_update(
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     n = bins_t.shape[1]
     L1 = do_split.shape[0]
     i32 = jnp.int32
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_route_update",
         (
             jax.ShapeDtypeStruct((n,), i32),        # new_slot
@@ -189,8 +187,6 @@ def histogram_routed(
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     n, F = bins.shape
@@ -218,11 +214,11 @@ def histogram_routed(
     if stats.dtype == jnp.int8:
         if quant_scale is None:
             raise ValueError("int8 fused histogram requires quant_scale")
-        return ffi_module().ffi_call("ydf_histogram_q8_routed", out_types)(
+        return jax.ffi.ffi_call("ydf_histogram_q8_routed", out_types)(
             bins.astype(jnp.uint8), *table_args,
             stats, quant_scale.astype(f32),
         )
-    return ffi_module().ffi_call("ydf_histogram_routed", out_types)(
+    return jax.ffi.ffi_call("ydf_histogram_routed", out_types)(
         bins.astype(jnp.uint8), *table_args, stats.astype(f32),
     )
 
@@ -237,7 +233,7 @@ def update_uses_fma() -> bool:
     to fma(raw, η, preds) — ONE rounding — instead of the plain
     two-rounding mul+add.
 
-    Measured fact (jax 0.4.37, x86-64 CPU with FMA units): XLA's fusion
+    Measured fact (x86-64 CPU with FMA units): XLA's fusion
     inlines the η-multiply producer through the leaf-value gather into
     the consumer loop, where LLVM contracts mul+add to vfmadd — and an
     hlo OptimizationBarrier around the scaled leaf values does NOT stop
@@ -292,15 +288,13 @@ def leaf_update(leaf_id, leaf_value_raw, scale, preds, use_fma=None):
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     if use_fma is None:
         use_fma = update_uses_fma()
     n = leaf_id.shape[0]
     f32 = jnp.float32
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_leaf_update", jax.ShapeDtypeStruct((n,), f32)
     )(
         leaf_id.astype(jnp.int32),
@@ -322,15 +316,13 @@ def leaf_update_grad(leaf_id, leaf_value_raw, scale, preds, y, w,
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     if use_fma is None:
         use_fma = update_uses_fma()
     n = leaf_id.shape[0]
     f32 = jnp.float32
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_leaf_update_grad",
         (
             jax.ShapeDtypeStruct((n,), f32),
@@ -360,8 +352,6 @@ def route_tree(
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
 
     n, Fb = bins.shape
@@ -370,7 +360,7 @@ def route_tree(
         x_set = jnp.zeros((1, 1, 1), jnp.uint32)
     offset = Fb if num_scalar is None else num_scalar
     params = jnp.asarray([max_depth, offset], i32)
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_route_tree", jax.ShapeDtypeStruct((n,), i32)
     )(
         bins.astype(jnp.uint8),

@@ -779,6 +779,13 @@ def start_worker(
     the env), the process exposition server is started and a /statusz
     section is registered for this worker — id, per-run (tree, layer)
     position stamps and shard ownership (docs/observability.md)."""
+    if blocking:
+        # Serving until shutdown makes this call the process's entry
+        # point (cli worker, SubprocessReplicaProvider); an in-process
+        # blocking=False worker leaves the cache to its host program.
+        from ydf_tpu.config import enable_compile_cache
+
+        enable_compile_cache()
     if secret is None:
         secret = _env_secret()
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)

@@ -154,7 +154,10 @@ class SubprocessReplicaProvider:
         import subprocess
 
         port = _free_port()
-        env = dict(os.environ)
+        # A replica serves from a native CPU bank or the routed scan
+        # (serving/replica.py) and never needs the accelerator; the
+        # parent may hold it, and a chip belongs to one process.
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         if self.secret is not None:
             env["YDF_TPU_WORKER_SECRET"] = self.secret.decode()
         proc = subprocess.Popen(

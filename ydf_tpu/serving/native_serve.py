@@ -432,13 +432,11 @@ def serve_batch_ffi(bank: ServeBank, x_num, x_cat):
     import jax
     import jax.numpy as jnp
 
-    from ydf_tpu.ops.native_ffi import ffi_module
-
     _require_registered()
     x_num = jnp.asarray(x_num, jnp.float32)
     x_cat = jnp.asarray(x_cat, jnp.int32)
     n = x_num.shape[0]
-    return ffi_module().ffi_call(
+    return jax.ffi.ffi_call(
         "ydf_serve_batch",
         jax.ShapeDtypeStruct((n, bank.leaf_width), jnp.float32),
     )(

@@ -126,7 +126,7 @@ def _bank_kernel(
     leafv_ref,   # [T, Np]
     mlo_ref,     # [T, W, Np]
     mhi_ref,     # [T, W, Np]
-    out_ref,     # [BN]
+    out_ref,     # [1, BN]
     *, T: int, Np: int, W: int, max_depth: int,
 ):
     BN = x_ref.shape[0]
@@ -165,7 +165,9 @@ def _bank_kernel(
                 word16 = jnp.where(weff == w, half, word16)
             shift = jnp.where(idx < 16, idx, idx - 16)
             bit = (word16 >> shift) & 1
-            go_left = jnp.where(is_cat, bit == 1, v < thr)
+            # Not a select between two bool vectors: Mosaic has no
+            # i8 -> i1 truncation for its result.
+            go_left = (is_cat & (bit == 1)) | (~is_cat & (v < thr))
             nxt = jnp.where(go_left, left, right)
             return jnp.where(is_leaf, node, nxt)
 
@@ -177,7 +179,7 @@ def _bank_kernel(
 
     out_ref[...] = jax.lax.fori_loop(
         0, T, tree_body, jnp.zeros((BN,), f32)
-    )
+    )[None, :]
 
 
 class PallasBankEngine:
@@ -255,8 +257,11 @@ class PallasBankEngine:
                 pl.BlockSpec((T, W, Np), full3),
                 pl.BlockSpec((T, W, Np), full3),
             ],
-            out_specs=pl.BlockSpec((BN,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n_pad,), f32),
+            # A 2-D row block: Mosaic tiles a rank-1 (BN,) block as
+            # T(BN), XLA lays the rank-1 array out as T(1024), and the
+            # compiler refuses the mismatch.
+            out_specs=pl.BlockSpec((1, BN), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((1, n_pad), f32),
             interpret=self.interpret,
         )(
             x_pad,
@@ -270,7 +275,7 @@ class PallasBankEngine:
             jnp.asarray(tb.mask_lo),
             jnp.asarray(tb.mask_hi),
         )
-        return out[:n]
+        return out[0, :n]
 
 
 def in_envelope(model) -> bool:

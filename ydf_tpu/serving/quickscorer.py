@@ -232,7 +232,8 @@ def _ctz32(v):
 def _qs_kernel(
     # scalar-prefetch (SMEM)
     cond_feature, cond_thresh, cond_mask_lo, cond_mask_hi, cond_tree,
-    cond_is_cat, cond_bitmap,
+    cond_is_cat,
+    cond_bitmap,  # u32 [C*W], row-major; [1] when W == 0
     # VMEM inputs
     x_ref,        # [F, BN] feature-major example block
     values_ref,   # [T, 64]
@@ -240,11 +241,11 @@ def _qs_kernel(
     out_ref,      # [BN]
     # scratch
     live_lo, live_hi,  # [T, BN] u32
+    *, W: int,
 ):
     C = cond_feature.shape[0]
     T = values_ref.shape[0]
     BN = x_ref.shape[1]
-    W = cond_bitmap.shape[1]
 
     live_lo[:] = jnp.full((T, BN), 0xFFFFFFFF, jnp.uint32)
     live_hi[:] = jnp.full((T, BN), 0xFFFFFFFF, jnp.uint32)
@@ -263,7 +264,7 @@ def _qs_kernel(
             idx = xrow.astype(jnp.int32)
             bit = jnp.zeros((BN,), jnp.uint32)
             for w in range(W):
-                word = cond_bitmap[c, w]
+                word = cond_bitmap[c * W + w]
                 sel = (idx >> 5) == w
                 bit = bit | jnp.where(
                     sel,
@@ -272,8 +273,10 @@ def _qs_kernel(
                     jnp.uint32(0),
                 )
             # Bit set → category goes LEFT; trigger prunes the left
-            # subtree, so trigger = bit NOT set.
-            trig = jnp.where(cond_is_cat[c] == 1, bit == 0, trig)
+            # subtree, so trigger = bit NOT set. (Not a select between
+            # two bool vectors: Mosaic does not legalize it.)
+            is_cat = jnp.full((BN,), cond_is_cat[c], jnp.int32) == 1
+            trig = (is_cat & (bit == 0)) | (~is_cat & trig)
         mlo = cond_mask_lo[c]
         mhi = cond_mask_hi[c]
         row_lo = live_lo[t, :]
@@ -354,10 +357,18 @@ class QuickScorerEngine:
         xT = jnp.pad(x_all, ((0, pad), (0, 0))).T  # [F, n_pad]
         n_pad = n + pad
         T = qsm.num_trees
+        # The bitmap rides SMEM flat: a 2-D SMEM array pads its minor
+        # axis to 128 words, and Mosaic refuses the zero-width operand
+        # a forest with no categorical condition would pass.
+        W = int(qsm.cond_bitmap.shape[1])
+        bitmap = (
+            qsm.cond_bitmap.reshape(-1) if W > 0
+            else np.zeros((1,), np.uint32)
+        )
 
         grid = (n_pad // BN,)
         out = pl.pallas_call(
-            _qs_kernel,
+            functools.partial(_qs_kernel, W=W),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=7,
                 grid=grid,
@@ -388,7 +399,7 @@ class QuickScorerEngine:
             jnp.asarray(qsm.cond_mask_hi),
             jnp.asarray(qsm.cond_tree),
             jnp.asarray(qsm.cond_is_cat),
-            jnp.asarray(qsm.cond_bitmap),
+            jnp.asarray(bitmap),
             xT,
             jnp.asarray(qsm.leaf_values),
         )

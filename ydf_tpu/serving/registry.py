@@ -248,15 +248,11 @@ def list_engines() -> List[EngineFactory]:
 
 
 def compatible_engines(model) -> List[EngineFactory]:
-    """Compatible factories, fastest first."""
-    out = []
-    for f in _REGISTRY:
-        try:
-            if f.is_compatible(model):
-                out.append(f)
-        except Exception:
-            continue
-    return out
+    """Compatible factories, fastest first. `is_compatible` answers the
+    envelope question with True/False; anything it raises (a compiler
+    refusing a kernel, a bad override) propagates instead of quietly
+    changing the engine."""
+    return [f for f in _REGISTRY if f.is_compatible(model)]
 
 
 # Last engine selection + live batchers — the /statusz "serving"
@@ -485,8 +481,7 @@ def _native_compatible(model) -> bool:
     CPU production path. YDF_TPU_SERVE_IMPL=xla disables it;
     =native claims compatibility for every in-envelope model and lets
     build() raise loudly when the kernel cannot register (the
-    no-silent-fallback contract — compatible_engines swallows
-    is_compatible exceptions, build exceptions propagate)."""
+    no-silent-fallback contract)."""
     from ydf_tpu.config import is_tpu_backend
     from ydf_tpu.serving import native_serve
 
