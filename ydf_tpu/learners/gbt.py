@@ -325,8 +325,15 @@ class GradientBoostedTreesLearner(GenericLearner):
     ) -> GradientBoostedTreesModel:
         from ydf_tpu.utils.profiling import StageTimer, maybe_trace
 
-        # Root of the train→chunk→tree→layer trace; recorded via
-        # emit_span at the end so the huge body needs no re-indent.
+        # An operator's trace (YDF_TPU_PROFILE_DIR) holds all of
+        # train(): the host spans before the device loop too.
+        with maybe_trace("gbt_train"):
+            return self._train(data, valid, StageTimer())
+
+    def _train(self, data, valid, timer) -> GradientBoostedTreesModel:
+        # Root of the telemetry trace (its children: the timer's
+        # `ydf.*` spans and `train.chunk`); recorded via emit_span at
+        # the end.
         _t_train0_ns = time.perf_counter_ns()
         # Deadline clock starts at train() entry — ingestion and binning
         # count against maximum_training_duration like the reference's.
@@ -336,7 +343,6 @@ class GradientBoostedTreesLearner(GenericLearner):
             and self.maximum_training_duration > 0
             else None
         )
-        timer = StageTimer()
         with timer.stage("ingest_bin"):
             prep = self._prepare(data, valid=valid)
         binner = prep["binner"]
@@ -376,81 +382,82 @@ class GradientBoostedTreesLearner(GenericLearner):
         vs_tr = vs_va = None  # (values, lengths) pairs
         if vs_all is not None:
             vs_all = (vs_all[0], vs_all[1])
-        if "valid_bins" in prep:
-            bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
-            bins_va = prep["valid_bins"]
-            y_va = prep["valid_labels"]
-            w_va = prep.get(
-                "valid_weights", np.ones((bins_va.shape[0],), np.float32)
-            )
-            set_tr, set_va = set_all, prep.get("valid_set_bits")
-            if vs_all is not None:
-                vs_tr = vs_all
-                vv = prep.get("valid_vs")
-                vs_va = (vv[0], vv[1]) if vv is not None else None
-            tr_groups = group_values
-            if self.task == Task.RANKING:
-                va_groups = np.asarray(
-                    prep["valid_dataset"].data[self.ranking_group]
+        with timer.stage("split"):
+            if "valid_bins" in prep:
+                bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
+                bins_va = prep["valid_bins"]
+                y_va = prep["valid_labels"]
+                w_va = prep.get(
+                    "valid_weights", np.ones((bins_va.shape[0],), np.float32)
                 )
-        elif (
-            self.validation_ratio > 0
-            and self.early_stopping != "NONE"
-            and not (self.distributed_workers and prep.get("cache"))
-        ):
-            # Distributed training from a cache skips this branch: the
-            # slice bins_all[tr_idx] would materialize the FULL bin
-            # matrix on the manager, defeating row-parallel memory
-            # scaling. The row-parallel entry point recomputes the
-            # identical deterministic split (same rng expressions) and
-            # ships index sets; feature-parallel still rejects
-            # validation with its targeted error.
-            rng = np.random.RandomState(self.random_seed)
-            if group_values is not None:
-                uniq = np.unique(group_values)
-                # Never consume every group (nor zero): a single-group
-                # dataset trains without validation rather than on nothing.
-                nvg = min(
-                    max(int(len(uniq) * self.validation_ratio), 1),
-                    len(uniq) - 1,
-                )
-                gperm = rng.permutation(len(uniq))
-                va_mask = np.isin(group_values, uniq[gperm[:nvg]])
-                va_idx = np.flatnonzero(va_mask)
-                tr_idx = np.flatnonzero(~va_mask)
-                tr_groups = group_values[tr_idx]
-                va_groups = group_values[va_idx]
+                set_tr, set_va = set_all, prep.get("valid_set_bits")
+                if vs_all is not None:
+                    vs_tr = vs_all
+                    vv = prep.get("valid_vs")
+                    vs_va = (vv[0], vv[1]) if vv is not None else None
+                tr_groups = group_values
+                if self.task == Task.RANKING:
+                    va_groups = np.asarray(
+                        prep["valid_dataset"].data[self.ranking_group]
+                    )
+            elif (
+                self.validation_ratio > 0
+                and self.early_stopping != "NONE"
+                and not (self.distributed_workers and prep.get("cache"))
+            ):
+                # Distributed training from a cache skips this branch: the
+                # slice bins_all[tr_idx] would materialize the FULL bin
+                # matrix on the manager, defeating row-parallel memory
+                # scaling. The row-parallel entry point recomputes the
+                # identical deterministic split (same rng expressions) and
+                # ships index sets; feature-parallel still rejects
+                # validation with its targeted error.
+                rng = np.random.RandomState(self.random_seed)
+                if group_values is not None:
+                    uniq = np.unique(group_values)
+                    # Never consume every group (nor zero): a single-group
+                    # dataset trains without validation rather than on nothing.
+                    nvg = min(
+                        max(int(len(uniq) * self.validation_ratio), 1),
+                        len(uniq) - 1,
+                    )
+                    gperm = rng.permutation(len(uniq))
+                    va_mask = np.isin(group_values, uniq[gperm[:nvg]])
+                    va_idx = np.flatnonzero(va_mask)
+                    tr_idx = np.flatnonzero(~va_mask)
+                    tr_groups = group_values[tr_idx]
+                    va_groups = group_values[va_idx]
+                else:
+                    perm = rng.permutation(n)
+                    nv = min(max(int(n * self.validation_ratio), 1), n - 1)
+                    va_idx, tr_idx = perm[:nv], perm[nv:]
+                if len(va_idx) == 0:
+                    va_idx = np.zeros((0,), np.int64)
+                    tr_idx = np.arange(n)
+                bins_tr, y_tr, w_tr = bins_all[tr_idx], labels_all[tr_idx], w_all[tr_idx]
+                bins_va, y_va, w_va = bins_all[va_idx], labels_all[va_idx], w_all[va_idx]
+                if set_all is not None:
+                    set_tr, set_va = set_all[tr_idx], set_all[va_idx]
+                if vs_all is not None:
+                    vs_tr = (vs_all[0][tr_idx], vs_all[1][tr_idx])
+                    vs_va = (vs_all[0][va_idx], vs_all[1][va_idx])
             else:
-                perm = rng.permutation(n)
-                nv = min(max(int(n * self.validation_ratio), 1), n - 1)
-                va_idx, tr_idx = perm[:nv], perm[nv:]
-            if len(va_idx) == 0:
-                va_idx = np.zeros((0,), np.int64)
-                tr_idx = np.arange(n)
-            bins_tr, y_tr, w_tr = bins_all[tr_idx], labels_all[tr_idx], w_all[tr_idx]
-            bins_va, y_va, w_va = bins_all[va_idx], labels_all[va_idx], w_all[va_idx]
-            if set_all is not None:
-                set_tr, set_va = set_all[tr_idx], set_all[va_idx]
-            if vs_all is not None:
-                vs_tr = (vs_all[0][tr_idx], vs_all[1][tr_idx])
-                vs_va = (vs_all[0][va_idx], vs_all[1][va_idx])
-        else:
-            bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
-            bins_va = np.zeros((0, bins_all.shape[1]), np.uint8)
-            y_va = np.zeros((0,), labels_all.dtype)
-            w_va = np.zeros((0,), np.float32)
-            if set_all is not None:
-                set_tr = set_all
-                set_va = np.zeros(
-                    (0,) + set_all.shape[1:], set_all.dtype
-                )
-            if vs_all is not None:
-                vs_tr = vs_all
-                vs_va = (
-                    np.zeros((0,) + vs_all[0].shape[1:], np.float32),
-                    np.zeros((0,) + vs_all[1].shape[1:], np.int32),
-                )
-            tr_groups = group_values
+                bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
+                bins_va = np.zeros((0, bins_all.shape[1]), np.uint8)
+                y_va = np.zeros((0,), labels_all.dtype)
+                w_va = np.zeros((0,), np.float32)
+                if set_all is not None:
+                    set_tr = set_all
+                    set_va = np.zeros(
+                        (0,) + set_all.shape[1:], set_all.dtype
+                    )
+                if vs_all is not None:
+                    vs_tr = vs_all
+                    vs_va = (
+                        np.zeros((0,) + vs_all[0].shape[1:], np.float32),
+                        np.zeros((0,) + vs_all[1].shape[1:], np.int32),
+                    )
+                tr_groups = group_values
 
         if self.mesh is not None:
             from ydf_tpu.parallel import mesh as pmesh
@@ -789,8 +796,7 @@ class GradientBoostedTreesLearner(GenericLearner):
         # single-scan and early-stop drivers used to run unguarded — an
         # OOM there died without a flight-recorder post-mortem; the
         # checkpointed/distributed drivers keep their inner guards).
-        with timer.stage("device_loop"), maybe_trace("gbt_train"), \
-                _flight_guard():
+        with timer.stage("device_loop"), _flight_guard():
             if self.distributed_workers:
                 # Feature-parallel manager–worker training: the bins
                 # never materialize on this host (workers hold the
@@ -804,13 +810,25 @@ class GradientBoostedTreesLearner(GenericLearner):
                     vs_Pv=vs_Pv, set_tr=set_tr,
                 )
             else:
+                with timer.stage("device_loop.h2d"):
+                    # Every array this train() sends to the device, in
+                    # one place: the enqueue is the span, the bytes the
+                    # counter. (Under a mesh they were placed when they
+                    # were sharded, above; the bytes are the same.)
+                    inputs = dict(
+                        bins_tr=bins_tr, y_tr=y_tr, w_tr=w_tr,
+                        bins_va=bins_va, y_va=y_va, w_va=w_va,
+                        x_tr_raw=x_tr_raw, x_va_raw=x_va_raw,
+                        set_tr=set_tr, set_va=set_va,
+                        vs_tr=vs_tr, vs_va=vs_va,
+                    )
+                    device_loop.count_h2d(
+                        sum(a.nbytes for a in jax.tree.leaves(inputs))
+                    )
+                    inputs = jax.tree.map(jnp.asarray, inputs)
                 forest_stacked, leaf_values, logs = _train_gbt(
-                    jnp.asarray(bins_tr),
-            jnp.asarray(y_tr),
-            jnp.asarray(w_tr),
-            jnp.asarray(bins_va),
-            jnp.asarray(y_va),
-            jnp.asarray(w_va),
+            **inputs,
+            timer=timer,
             loss_obj=loss_obj,
             rule=rule,
             tree_cfg=tree_cfg,
@@ -854,20 +872,6 @@ class GradientBoostedTreesLearner(GenericLearner):
                 else None
             ),
             monotone=monotone,
-            x_tr_raw=None if x_tr_raw is None else jnp.asarray(x_tr_raw),
-            x_va_raw=None if x_va_raw is None else jnp.asarray(x_va_raw),
-            set_tr=None if set_tr is None else jnp.asarray(set_tr),
-            set_va=None if set_va is None else jnp.asarray(set_va),
-            vs_tr=(
-                None
-                if vs_tr is None
-                else (jnp.asarray(vs_tr[0]), jnp.asarray(vs_tr[1]))
-            ),
-            vs_va=(
-                None
-                if vs_va is None
-                else (jnp.asarray(vs_va[0]), jnp.asarray(vs_va[1]))
-            ),
             vs_Ac=vs_Ac,
             vs_Ap=vs_Ap,
             route_impl=route_impl,
@@ -885,166 +889,163 @@ class GradientBoostedTreesLearner(GenericLearner):
             deadline=deadline,
         )
 
-        _t_fin = time.perf_counter()
-        train_losses = np.asarray(logs["train_loss"])
-        valid_losses = np.asarray(logs["valid_loss"])
-        has_valid = bins_va.shape[0] > 0 or bool(
-            # Row-parallel distributed training row-shards the
-            # validation split onto the workers (bins_va never
-            # materializes here); its real per-iteration valid losses
-            # ride logs["valid_loss"] and drive the same argmin trim.
-            logs.get("distributed", {}).get("has_valid")
-        )
-        if has_valid and self.early_stopping != "NONE":
-            best_iter = int(np.argmin(valid_losses))
-            num_iters = best_iter + 1
-        else:
-            # A deadline (maximum_training_duration) may have stopped the
-            # chunked loop early: keep the iterations actually trained.
-            num_iters = min(self.num_trees, len(train_losses))
-
-        # [T, K, ...] → [T*K, ...] iteration-major (the reference's
-        # num_trees_per_iter layout, gradient_boosted_trees.h:57-151).
-        def flatten(a):
-            a = np.asarray(a)
-            return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])[
-                : num_iters * K
-            ]
-
-        stacked = grower.TreeArrays(
-            feature=flatten(forest_stacked.feature),
-            threshold_bin=flatten(forest_stacked.threshold_bin),
-            is_cat=flatten(forest_stacked.is_cat),
-            is_set=flatten(forest_stacked.is_set),
-            cat_mask=flatten(forest_stacked.cat_mask),
-            left=flatten(forest_stacked.left),
-            right=flatten(forest_stacked.right),
-            is_leaf=flatten(forest_stacked.is_leaf),
-            leaf_stats=flatten(forest_stacked.leaf_stats),
-            num_nodes=flatten(forest_stacked.num_nodes[..., None])[:, 0],
-        )
-        if obl_P > 0 or vs_Pv > 0:
-            # Tree features: [0, Fn) numerical, [Fn, Fn+P) oblique
-            # projections, [Fn+P, Fn+P+Pv) vector-sequence anchors,
-            # [Fn+P+Pv, ...) categorical(+set). Remap to the Forest
-            # convention (projection blocks after ALL real features, same
-            # order) and attach each tree's per-projection data + bin
-            # cutpoints. Both blocks shift by the same Freal - Fn.
-            Fn = binner.num_numerical
-            Freal = binner.num_features
-            PB = obl_P + vs_Pv
-            feat = np.asarray(stacked.feature)
-            in_block = (feat >= Fn) & (feat < Fn + PB)
-            remapped = np.where(
-                in_block,
-                Freal + (feat - Fn),
-                np.where(feat >= Fn + PB, feat - PB, feat),
+        with timer.stage("finalize"):
+            train_losses = np.asarray(logs["train_loss"])
+            valid_losses = np.asarray(logs["valid_loss"])
+            has_valid = bins_va.shape[0] > 0 or bool(
+                # Row-parallel distributed training row-shards the
+                # validation split onto the workers (bins_va never
+                # materializes here); its real per-iteration valid losses
+                # ride logs["valid_loss"] and drive the same argmin trim.
+                logs.get("distributed", {}).get("has_valid")
             )
-            stacked = stacked._replace(feature=remapped.astype(np.int32))
+            if has_valid and self.early_stopping != "NONE":
+                best_iter = int(np.argmin(valid_losses))
+                num_iters = best_iter + 1
+            else:
+                # A deadline (maximum_training_duration) may have stopped the
+                # chunked loop early: keep the iterations actually trained.
+                num_iters = min(self.num_trees, len(train_losses))
 
-            def per_iter(key):
-                return np.repeat(np.asarray(logs[key]), K, axis=0)[
+            # [T, K, ...] → [T*K, ...] iteration-major (the reference's
+            # num_trees_per_iter layout, gradient_boosted_trees.h:57-151).
+            def flatten(a):
+                a = np.asarray(a)
+                return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])[
                     : num_iters * K
                 ]
 
-            kwargs = {}
-            if obl_P > 0:
-                kwargs["oblique_weights"] = per_iter("oblique_w")
-                kwargs["oblique_boundaries"] = per_iter("oblique_b")
-            if vs_Pv > 0:
-                Tn = num_iters * K
-                per_kind = [True] * vs_Ac + [False] * vs_Ap
-                kwargs["vs_anchors"] = per_iter("vs_a")
-                kwargs["vs_boundaries"] = per_iter("vs_b")
-                kwargs["vs_feat"] = np.broadcast_to(
-                    np.repeat(
-                        np.arange(binner.num_vs, dtype=np.int32),
-                        vs_Ac + vs_Ap,
+            stacked = grower.TreeArrays(
+                feature=flatten(forest_stacked.feature),
+                threshold_bin=flatten(forest_stacked.threshold_bin),
+                is_cat=flatten(forest_stacked.is_cat),
+                is_set=flatten(forest_stacked.is_set),
+                cat_mask=flatten(forest_stacked.cat_mask),
+                left=flatten(forest_stacked.left),
+                right=flatten(forest_stacked.right),
+                is_leaf=flatten(forest_stacked.is_leaf),
+                leaf_stats=flatten(forest_stacked.leaf_stats),
+                num_nodes=flatten(forest_stacked.num_nodes[..., None])[:, 0],
+            )
+            if obl_P > 0 or vs_Pv > 0:
+                # Tree features: [0, Fn) numerical, [Fn, Fn+P) oblique
+                # projections, [Fn+P, Fn+P+Pv) vector-sequence anchors,
+                # [Fn+P+Pv, ...) categorical(+set). Remap to the Forest
+                # convention (projection blocks after ALL real features, same
+                # order) and attach each tree's per-projection data + bin
+                # cutpoints. Both blocks shift by the same Freal - Fn.
+                Fn = binner.num_numerical
+                Freal = binner.num_features
+                PB = obl_P + vs_Pv
+                feat = np.asarray(stacked.feature)
+                in_block = (feat >= Fn) & (feat < Fn + PB)
+                remapped = np.where(
+                    in_block,
+                    Freal + (feat - Fn),
+                    np.where(feat >= Fn + PB, feat - PB, feat),
+                )
+                stacked = stacked._replace(feature=remapped.astype(np.int32))
+
+                def per_iter(key):
+                    return np.repeat(np.asarray(logs[key]), K, axis=0)[
+                        : num_iters * K
+                    ]
+
+                kwargs = {}
+                if obl_P > 0:
+                    kwargs["oblique_weights"] = per_iter("oblique_w")
+                    kwargs["oblique_boundaries"] = per_iter("oblique_b")
+                if vs_Pv > 0:
+                    Tn = num_iters * K
+                    per_kind = [True] * vs_Ac + [False] * vs_Ap
+                    kwargs["vs_anchors"] = per_iter("vs_a")
+                    kwargs["vs_boundaries"] = per_iter("vs_b")
+                    kwargs["vs_feat"] = np.broadcast_to(
+                        np.repeat(
+                            np.arange(binner.num_vs, dtype=np.int32),
+                            vs_Ac + vs_Ap,
+                        ),
+                        (Tn, vs_Pv),
+                    )
+                    kwargs["vs_is_closer"] = np.broadcast_to(
+                        np.tile(np.array(per_kind, bool), binner.num_vs),
+                        (Tn, vs_Pv),
+                    )
+                forest = forest_from_stacked_trees(
+                    stacked, flatten(leaf_values), binner.boundaries, **kwargs
+                )
+            else:
+                forest = forest_from_stacked_trees(
+                    stacked, flatten(leaf_values), binner.boundaries
+                )
+
+            if self.monotonic_constraints:
+                forest = _clamp_monotone_leaves(
+                    forest, binner, self.monotonic_constraints
+                )
+
+            initial_predictions = np.asarray(logs["initial_predictions"])
+            chunk_walls = logs.get("chunk_walls") or []
+            model = GradientBoostedTreesModel(
+                task=self.task,
+                label=self.label,
+                classes=prep.get("classes"),
+                dataspec=prep["dataset"].dataspec,
+                binner=binner,
+                forest=forest,
+                initial_predictions=initial_predictions,
+                num_trees_per_iter=K,
+                max_depth=self.max_depth,
+                loss_name=loss_obj.name,
+                apply_link_function=self.apply_link_function,
+                training_logs={
+                    "train_loss": train_losses[:num_iters].tolist(),
+                    "valid_loss": valid_losses[:num_iters].tolist()
+                    if has_valid
+                    else None,
+                    "num_trees": num_iters,
+                    # Iterations the boosting loop actually ran — less than the
+                    # requested num_trees when in-loop early stopping fired
+                    # (reference early_stopping.h:29-66).
+                    "num_trees_trained": int(train_losses.shape[0]),
+                    # One YDF-style record per TRAINED boosting iteration
+                    # (reference TrainingLogs; the tuner/early-stopping
+                    # consumable). Seconds are per-chunk wall attributed
+                    # uniformly within the chunk (docs/observability.md).
+                    "iterations": _iteration_records(
+                        train_losses, valid_losses, has_valid, chunk_walls
                     ),
-                    (Tn, vs_Pv),
+                },
+                extra_metadata=self._model_metadata(),
+            )
+            if "distributed" in logs:
+                # Exchange accounting of the feature-parallel run (worker
+                # count, reduce bytes, per-verb RPC p50s, recoveries) — the
+                # bench family's source (bench.measure_distributed_family).
+                model.training_logs["distributed"] = logs["distributed"]
+            else:
+                # What "auto" resolved to for this train on this backend
+                # (chip_smoke.py asserts the TPU answers).
+                from ydf_tpu.ops.histogram import (
+                    resolve_hist_impl,
+                    resolve_hist_quant,
                 )
-                kwargs["vs_is_closer"] = np.broadcast_to(
-                    np.tile(np.array(per_kind, bool), binner.num_vs),
-                    (Tn, vs_Pv),
-                )
-            forest = forest_from_stacked_trees(
-                stacked, flatten(leaf_values), binner.boundaries, **kwargs
-            )
-        else:
-            forest = forest_from_stacked_trees(
-                stacked, flatten(leaf_values), binner.boundaries
-            )
 
-        if self.monotonic_constraints:
-            forest = _clamp_monotone_leaves(
-                forest, binner, self.monotonic_constraints
-            )
-
-        initial_predictions = np.asarray(logs["initial_predictions"])
-        chunk_walls = logs.get("chunk_walls") or []
-        model = GradientBoostedTreesModel(
-            task=self.task,
-            label=self.label,
-            classes=prep.get("classes"),
-            dataspec=prep["dataset"].dataspec,
-            binner=binner,
-            forest=forest,
-            initial_predictions=initial_predictions,
-            num_trees_per_iter=K,
-            max_depth=self.max_depth,
-            loss_name=loss_obj.name,
-            apply_link_function=self.apply_link_function,
-            training_logs={
-                "train_loss": train_losses[:num_iters].tolist(),
-                "valid_loss": valid_losses[:num_iters].tolist()
-                if has_valid
-                else None,
-                "num_trees": num_iters,
-                # Iterations the boosting loop actually ran — less than the
-                # requested num_trees when in-loop early stopping fired
-                # (reference early_stopping.h:29-66).
-                "num_trees_trained": int(train_losses.shape[0]),
-                # One YDF-style record per TRAINED boosting iteration
-                # (reference TrainingLogs; the tuner/early-stopping
-                # consumable). Seconds are per-chunk wall attributed
-                # uniformly within the chunk (docs/observability.md).
-                "iterations": _iteration_records(
-                    train_losses, valid_losses, has_valid, chunk_walls
-                ),
-            },
-            extra_metadata=self._model_metadata(),
-        )
-        if "distributed" in logs:
-            # Exchange accounting of the feature-parallel run (worker
-            # count, reduce bytes, per-verb RPC p50s, recoveries) — the
-            # bench family's source (bench.measure_distributed_family).
-            model.training_logs["distributed"] = logs["distributed"]
-        else:
-            # What "auto" resolved to for this train on this backend
-            # (chip_smoke.py asserts the TPU answers).
-            from ydf_tpu.ops.histogram import (
-                resolve_hist_impl,
-                resolve_hist_quant,
-            )
-
-            model.training_logs["implementations"] = {
-                "hist_impl": resolve_hist_impl("auto"),
-                "hist_quant": resolve_hist_quant(None),
-                "route_impl": route_impl,
-            }
-        if self.mesh is not None:
-            model.training_logs["mesh"] = {
-                "shape": dict(self.mesh.shape),
-                "input_devices": mesh_input_devices,
-            }
-        timer.seconds["finalize"] = time.perf_counter() - _t_fin
+                model.training_logs["implementations"] = {
+                    "hist_impl": resolve_hist_impl("auto"),
+                    "hist_quant": resolve_hist_quant(None),
+                    "route_impl": route_impl,
+                }
+            if self.mesh is not None:
+                model.training_logs["mesh"] = {
+                    "shape": dict(self.mesh.shape),
+                    "input_devices": mesh_input_devices,
+                }
         # Per-stage wall breakdown (reference Monitoring per-stage logs);
-        # device_loop includes XLA compile on first call.
+        # `device_loop.compile` is the XLA compile inside it.
         model.training_profile = timer.finish()
         if telemetry.ENABLED:
-            _emit_train_spans(
-                chunk_walls, int(train_losses.shape[0]), self.max_depth
-            )
+            _emit_chunk_spans(chunk_walls)
             telemetry.emit_span(
                 "train",
                 _t_train0_ns,
@@ -1444,9 +1445,10 @@ def _make_boost_fn(
                 # here; w_tr·1 ≡ w_tr bit for bit.
                 w_eff = w_tr
             else:
-                g, h = loss_obj.grad_hess(y_tr, preds_used)  # [n, K]
-                m = sample_mask(k_sub, g, preds_used)
-                w_eff = w_tr * m
+                with jax.named_scope("ydf.grad"):
+                    g, h = loss_obj.grad_hess(y_tr, preds_used)  # [n, K]
+                    m = sample_mask(k_sub, g, preds_used)
+                    w_eff = w_tr * m
 
             if P > 0:
                 key, k_proj = jax.random.split(key)
@@ -1534,9 +1536,11 @@ def _make_boost_fn(
                 if fuse_grad:
                     stats = stats_carry
                 else:
-                    stats = jnp.stack(
-                        [g[:, k] * w_eff, h[:, k] * w_eff, w_eff], axis=1
-                    )
+                    with jax.named_scope("ydf.grad"):
+                        stats = jnp.stack(
+                            [g[:, k] * w_eff, h[:, k] * w_eff, w_eff],
+                            axis=1,
+                        )
                 res = grower.grow_tree(
                     grow_bins, stats, kk,
                     bins_t=bins_tr_T if grow_bins is bins_tr else None,
@@ -1565,97 +1569,117 @@ def _make_boost_fn(
                 # oracle are fma(raw, η, preds) while the model stores
                 # round(raw·η). The kernels take (raw, η) and replicate
                 # the host's observed contraction to stay bit-identical.
-                lv_raw = rule.leaf_value(res.tree.leaf_stats, None)
-                lv = lv_raw * shrinkage
-                if fused:
-                    # End-of-tree update as ONE fused kernel pass per
-                    # class column: preds[:, k] += lv[leaf_id], and
-                    # (squared error) the next iteration's stats rows
-                    # from the same pass — bit-identical to the
-                    # gather+mul+add(+grad) chain below. Safe inside
-                    # the k loop: g for every class was computed from
-                    # preds_used at the top of the iteration.
-                    from ydf_tpu.ops import routing_native
-
-                    if fuse_grad:
-                        p_col, stats_next = routing_native.leaf_update_grad(
-                            res.leaf_id, lv_raw[:, 0], shrinkage,
-                            preds[:, 0], y_f, w_tr
-                        )
-                    else:
-                        p_col = routing_native.leaf_update(
-                            res.leaf_id, lv_raw[:, 0], shrinkage,
-                            preds[:, k]
-                        )
-                    preds = (
-                        p_col[:, None] if K == 1
-                        else preds.at[:, k].set(p_col)
-                    )
-                else:
-                    new_contrib = new_contrib.at[:, k].set(lv[res.leaf_id, 0])
-                if nv > 0:
-                    vleaves = route_tree_bins(
-                        res.tree, grow_bins_va, tree_cfg.max_depth,
-                        x_set=set_va,
-                        # Stored set-feature ids are offset by the UNPADDED
-                        # scalar count (see grow_tree best_f_store).
-                        num_scalar=grow_num_valid,
-                        impl=route_impl,
-                    )
+                with jax.named_scope("ydf.leaf"):
+                    lv_raw = rule.leaf_value(res.tree.leaf_stats, None)
+                    lv = lv_raw * shrinkage
                     if fused:
-                        vp_col = apply_leaf_values(
-                            vleaves, lv_raw[:, 0], vpreds[:, k],
-                            scale=shrinkage, impl=route_impl
-                        )
-                        vpreds = (
-                            vp_col[:, None] if K == 1
-                            else vpreds.at[:, k].set(vp_col)
+                        # End-of-tree update as ONE fused kernel pass per
+                        # class column: preds[:, k] += lv[leaf_id], and
+                        # (squared error) the next iteration's stats rows
+                        # from the same pass — bit-identical to the
+                        # gather+mul+add(+grad) chain below. Safe inside
+                        # the k loop: g for every class was computed from
+                        # preds_used at the top of the iteration.
+                        from ydf_tpu.ops import routing_native
+
+                        if fuse_grad:
+                            p_col, stats_next = (
+                                routing_native.leaf_update_grad(
+                                    res.leaf_id, lv_raw[:, 0], shrinkage,
+                                    preds[:, 0], y_f, w_tr
+                                )
+                            )
+                        else:
+                            p_col = routing_native.leaf_update(
+                                res.leaf_id, lv_raw[:, 0], shrinkage,
+                                preds[:, k]
+                            )
+                        preds = (
+                            p_col[:, None] if K == 1
+                            else preds.at[:, k].set(p_col)
                         )
                     else:
-                        new_vcontrib = new_vcontrib.at[:, k].set(lv[vleaves, 0])
+                        new_contrib = new_contrib.at[:, k].set(
+                            lv[res.leaf_id, 0]
+                        )
+                if nv > 0:
+                    with jax.named_scope("ydf.valid"):
+                        vleaves = route_tree_bins(
+                            res.tree, grow_bins_va, tree_cfg.max_depth,
+                            x_set=set_va,
+                            # Stored set-feature ids are offset by the
+                            # UNPADDED scalar count (see grow_tree
+                            # best_f_store).
+                            num_scalar=grow_num_valid,
+                            impl=route_impl,
+                        )
+                        if fused:
+                            vp_col = apply_leaf_values(
+                                vleaves, lv_raw[:, 0], vpreds[:, k],
+                                scale=shrinkage, impl=route_impl
+                            )
+                            vpreds = (
+                                vp_col[:, None] if K == 1
+                                else vpreds.at[:, k].set(vp_col)
+                            )
+                        else:
+                            new_vcontrib = new_vcontrib.at[:, k].set(
+                                lv[vleaves, 0]
+                            )
                 trees_k.append(res.tree)
                 leaves_k.append(lv)
 
             if use_dart:
                 # New tree enters at weight 1/(nd+1); dropped trees shrink
                 # by nd/(nd+1) (reference :1558-1573).
-                factor = 1.0 / (nd + 1.0)
-                tree_scale_old = tree_scale
-                tree_scale = jnp.where(drop, tree_scale * nd * factor, tree_scale)
-                tree_scale = tree_scale.at[it].set(factor)
-                contrib = jax.lax.dynamic_update_index_in_dim(
-                    contrib, new_contrib, it, 0
-                )
-                preds = preds_used + dropped_sum * nd * factor + new_contrib * factor
+                with jax.named_scope("ydf.leaf"):
+                    factor = 1.0 / (nd + 1.0)
+                    tree_scale_old = tree_scale
+                    tree_scale = jnp.where(
+                        drop, tree_scale * nd * factor, tree_scale
+                    )
+                    tree_scale = tree_scale.at[it].set(factor)
+                    contrib = jax.lax.dynamic_update_index_in_dim(
+                        contrib, new_contrib, it, 0
+                    )
+                    preds = (
+                        preds_used
+                        + dropped_sum * nd * factor
+                        + new_contrib * factor
+                    )
                 if nv > 0:
                     # Same incremental form as the train preds: only the
                     # dropped-trees contraction is O(T); recomputing the
                     # full ensemble each step would be O(T^2) overall.
-                    vdropped = jnp.einsum(
-                        "t,tnk->nk", drop * tree_scale_old, vcontrib
-                    )
-                    vcontrib = jax.lax.dynamic_update_index_in_dim(
-                        vcontrib, new_vcontrib, it, 0
-                    )
-                    vpreds = (
-                        vpreds
-                        - vdropped
-                        + vdropped * nd * factor
-                        + new_vcontrib * factor
-                    )
+                    with jax.named_scope("ydf.valid"):
+                        vdropped = jnp.einsum(
+                            "t,tnk->nk", drop * tree_scale_old, vcontrib
+                        )
+                        vcontrib = jax.lax.dynamic_update_index_in_dim(
+                            vcontrib, new_vcontrib, it, 0
+                        )
+                        vpreds = (
+                            vpreds
+                            - vdropped
+                            + vdropped * nd * factor
+                            + new_vcontrib * factor
+                        )
             elif not fused:
-                preds = preds + new_contrib
+                with jax.named_scope("ydf.leaf"):
+                    preds = preds + new_contrib
                 if nv > 0:
-                    vpreds = vpreds + new_vcontrib
+                    with jax.named_scope("ydf.valid"):
+                        vpreds = vpreds + new_vcontrib
 
             trees = jax.tree.map(lambda *xs: jnp.stack(xs), *trees_k)
             lvs = jnp.stack(leaves_k)  # [K, N, 1]
-            tl = loss_obj.loss(y_tr, preds, w_tr, tag="train")
-            vl = (
-                loss_obj.loss(y_va, vpreds, w_va, tag="valid")
-                if nv > 0
-                else jnp.float32(0)
-            )
+            with jax.named_scope("ydf.loss"):
+                tl = loss_obj.loss(y_tr, preds, w_tr, tag="train")
+                vl = (
+                    loss_obj.loss(y_va, vpreds, w_va, tag="valid")
+                    if nv > 0
+                    else jnp.float32(0)
+                )
             if use_dart:
                 new_carry = (preds, vpreds, key, contrib, vcontrib, tree_scale)
             elif fuse_grad:
@@ -1716,7 +1740,7 @@ def _note_chunk(
 ):
     """Per-chunk bookkeeping shared by the three boosting drivers:
     records the chunk's host wall (the attribution source for the
-    per-iteration training logs and the train→chunk→tree→layer trace),
+    per-iteration training logs and the `train.chunk` telemetry span),
     feeds the training metrics, and emits the per-chunk progress line
     at debug level (the reference manager's per-stage Monitoring log,
     distributed_gradient_boosted_trees.cc:832-836)."""
@@ -1766,34 +1790,15 @@ def _iteration_records(train_losses, valid_losses, has_valid, chunk_walls):
     return out
 
 
-def _emit_train_spans(chunk_walls, trained, max_depth):
-    """Chrome-tracing spans for the boosting timeline: one measured
-    span per chunk, subdivided into per-tree and per-layer spans by
-    uniform attribution (flagged `attributed: true` — the scan is one
-    fused device program, so within-chunk splits are bookkeeping, not
-    measurement). Only runs when telemetry is armed."""
-    if not telemetry.ENABLED:
-        return
+def _emit_chunk_spans(chunk_walls):
+    """One measured `train.chunk` telemetry span per dispatched chunk.
+    The scan is one fused device program: the host cannot time a tree
+    or a layer inside it (the device's own trace can:
+    profiling.device_seconds_by_scope)."""
     for s, c, t0, dur in chunk_walls or []:
-        n = max(min(s + c, trained) - s, 0)
         telemetry.emit_span(
             "train.chunk", t0, dur, {"start_iter": s, "iterations": c}
         )
-        if n == 0 or dur <= 0:
-            continue
-        tree_dur = dur // c
-        layer_dur = max(tree_dur // max(max_depth, 1), 1)
-        for j in range(n):
-            tt0 = t0 + j * tree_dur
-            telemetry.emit_span(
-                "train.tree", tt0, tree_dur,
-                {"iteration": s + j + 1, "attributed": True},
-            )
-            for d in range(max_depth):
-                telemetry.emit_span(
-                    "train.layer", tt0 + d * layer_dur, layer_dur,
-                    {"depth": d, "attributed": True},
-                )
 
 
 def _chunk_len(clen: int, start: int, num_trees: int, use_dart: bool) -> int:
@@ -1804,18 +1809,23 @@ def _chunk_len(clen: int, start: int, num_trees: int, use_dart: bool) -> int:
     return min(clen, num_trees - start) if use_dart else clen
 
 
-def _chunk_arrays_from_ys(ys) -> dict:
+def _chunk_arrays_from_ys(ys, timer) -> dict:
     """run_chunk outputs → the flat dict layout shared by the in-memory
     early-stop path and the on-disk snapshot payloads."""
+    with timer.stage("device_loop.wait"):
+        # The fetch below would block on the chunk anyway: waiting here
+        # tells the host's wait for the device from the copies.
+        jax.block_until_ready(ys)
     trees_c, lvs_c, tls_c, vls_c, ow_c, ob_c, va_c, vb_c = ys
-    d = {f"trees_{j}": np.asarray(a) for j, a in enumerate(trees_c)}
-    d["lvs"] = np.asarray(lvs_c)
-    d["tls"] = np.asarray(tls_c)
-    d["vls"] = np.asarray(vls_c)
-    d["ow"] = np.asarray(ow_c)
-    d["ob"] = np.asarray(ob_c)
-    d["vsa"] = np.asarray(va_c)
-    d["vsb"] = np.asarray(vb_c)
+    with timer.stage("device_loop.fetch"):
+        d = {f"trees_{j}": np.asarray(a) for j, a in enumerate(trees_c)}
+        d["lvs"] = np.asarray(lvs_c)
+        d["tls"] = np.asarray(tls_c)
+        d["vls"] = np.asarray(vls_c)
+        d["ow"] = np.asarray(ow_c)
+        d["ob"] = np.asarray(ob_c)
+        d["vsa"] = np.asarray(va_c)
+        d["vsb"] = np.asarray(vb_c)
     # This materialization is THE host-sync point of the chunked drivers:
     # everything else (carry, bin matrix, labels) stays device-resident.
     device_loop.count_host_sync(sum(a.nbytes for a in d.values()))
@@ -1879,13 +1889,14 @@ def _train_gbt(
     route_fuse=True,
     cache_dir=None, resume=False, snapshot_interval=50,
     abort_after_chunks=None, preempt_after_chunks=None,
-    early_stop_lookahead=0, deadline=None,
+    early_stop_lookahead=0, deadline=None, timer,
 ):
     """The jitted boosting loop. Returns stacked trees [T, K, ...], leaf
-    values [T, K, N, 1] and per-iteration logs. `deadline` is an absolute
-    time.monotonic() value: the chunked drivers stop within one chunk of
-    it and return the iterations finished so far (reference GBT deadline
-    check, gradient_boosted_trees.cc:1314-1325)."""
+    values [T, K, N, 1] and per-iteration logs; `timer` is the calling
+    train()'s StageTimer (the `device_loop.*` spans). `deadline` is an
+    absolute time.monotonic() value: the chunked drivers stop within one
+    chunk of it and return the iterations finished so far (reference GBT
+    deadline check, gradient_boosted_trees.cc:1314-1325)."""
     # Identity-hashed losses (LambdaMartNdcg carries per-dataset group
     # arrays) can never hit the cache — bypass it so dead entries don't pin
     # device memory or evict the reusable frozen-dataclass ones.
@@ -1897,19 +1908,20 @@ def _train_gbt(
         and not isinstance(loss_obj, CustomLoss)  # identity-hashed fields
         else _make_boost_fn.__wrapped__
     )
-    run = builder(
-        loss_obj, rule, tree_cfg, num_trees, shrinkage, subsample,
-        candidate_features, num_numerical, num_valid_features, seed,
-        bins_tr.shape[0], bins_va.shape[0],
-        sampling, goss_alpha, goss_beta, selgb_ratio, dart_dropout,
-        oblique_P, oblique_density, oblique_weight_type,
-        oblique_weight_range, oblique_mode, mhld_max_attributes,
-        num_label_classes, monotone,
-        vs_Ac if vs_tr is not None else 0,
-        vs_Ap if vs_tr is not None else 0,
-        route_impl=route_impl,
-        route_fuse=route_fuse,
-    )
+    with timer.stage("device_loop.init"):
+        run = builder(
+            loss_obj, rule, tree_cfg, num_trees, shrinkage, subsample,
+            candidate_features, num_numerical, num_valid_features, seed,
+            bins_tr.shape[0], bins_va.shape[0],
+            sampling, goss_alpha, goss_beta, selgb_ratio, dart_dropout,
+            oblique_P, oblique_density, oblique_weight_type,
+            oblique_weight_range, oblique_mode, mhld_max_attributes,
+            num_label_classes, monotone,
+            vs_Ac if vs_tr is not None else 0,
+            vs_Ap if vs_tr is not None else 0,
+            route_impl=route_impl,
+            route_fuse=route_fuse,
+        )
     nv_rows = bins_va.shape[0]
     data_args = (bins_tr, y_tr, w_tr, bins_va, y_va, w_va) + (
         (x_tr_raw, x_va_raw) if oblique_P > 0 else ()
@@ -1937,7 +1949,8 @@ def _train_gbt(
             # and truncating post-hoc. A deadline forces this chunked
             # driver too (the fused single scan cannot stop mid-flight).
             use_dart = getattr(run, "use_dart", False)
-            carry, init_pred = run.init_state(y_tr, w_tr)
+            with timer.stage("device_loop.init"):
+                carry, init_pred = run.init_state(y_tr, w_tr)
             # Trees grown per XLA dispatch: the env knob when set
             # (YDF_TPU_TREES_PER_DISPATCH — the paired A/B in bench.py
             # pins it), else the early-stop look-ahead window.
@@ -1955,9 +1968,10 @@ def _train_gbt(
                 # (its buffers were reused in place on device); everything
                 # below reads only the NEW carry / the fetched ys.
                 carry, ys = device_loop.run_chunk(
-                    run, carry, start, c, *data_args, **data_kwargs
+                    run, carry, start, c, *data_args, timer=timer,
+                    **data_kwargs
                 )
-                parts.append(_chunk_arrays_from_ys(ys))
+                parts.append(_chunk_arrays_from_ys(ys, timer))
                 _note_chunk(
                     chunk_walls, start, c, num_trees, t0_ns, parts[-1],
                     nv_rows,
@@ -1971,9 +1985,10 @@ def _train_gbt(
                     break
                 if deadline is not None and time.monotonic() >= deadline:
                     break
-            trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = (
-                _merge_chunk_parts(parts, num_trees, use_dart, carry)
-            )
+            with timer.stage("device_loop.merge"):
+                trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = (
+                    _merge_chunk_parts(parts, num_trees, use_dart, carry)
+                )
             logs = {
                 "train_loss": tls,
                 "valid_loss": vls,
@@ -1986,13 +2001,15 @@ def _train_gbt(
             }
             return trees, lvs, logs
         t0_ns = time.perf_counter_ns()
-        trees, lvs, tls, vls, init_pred, obl_w, obl_b, vs_a, vs_b = run(
-            *data_args, **data_kwargs
-        )
+        with timer.stage("device_loop.dispatch"):
+            trees, lvs, tls, vls, init_pred, obl_w, obl_b, vs_a, vs_b = run(
+                *data_args, **data_kwargs
+            )
         # Block before reading the clock: the jit call returns futures,
         # and every output is materialized a few lines later anyway —
         # this just keeps the single "chunk" wall honest.
-        jax.block_until_ready(tls)
+        with timer.stage("device_loop.wait"):
+            jax.block_until_ready(tls)
         device_loop.count_dispatch(num_trees)
         device_loop.count_host_sync(
             sum(
@@ -2085,7 +2102,8 @@ def _train_gbt(
         )
         init_pred = jnp.asarray(arrays["init_pred"])
     if carry is None:
-        carry, init_pred = run.init_state(y_tr, w_tr)
+        with timer.stage("device_loop.init"):
+            carry, init_pred = run.init_state(y_tr, w_tr)
 
     chunks_done = 0
     vls_seen = []
@@ -2116,9 +2134,10 @@ def _train_gbt(
             # Donated-carry dispatch: the old carry dies here; the
             # snapshot below serializes the NEW carry.
             carry, ys = device_loop.run_chunk(
-                run, carry, start, clen, *data_args, **data_kwargs
+                run, carry, start, clen, *data_args, timer=timer,
+                **data_kwargs
             )
-            chunk_arrays = _chunk_arrays_from_ys(ys)
+            chunk_arrays = _chunk_arrays_from_ys(ys, timer)
             _note_chunk(
                 chunk_walls, start, clen, num_trees, t0_ns, chunk_arrays,
                 nv_rows,
@@ -2132,9 +2151,10 @@ def _train_gbt(
             _durable_replace(tmp, _chunk_path(start))
 
             start_next = start + clen
-            arrays = {"init_pred": np.asarray(init_pred)}
-            for i, leaf in enumerate(jax.tree.leaves(carry)):
-                arrays[f"carry_{i}"] = np.asarray(leaf)
+            with timer.stage("device_loop.fetch"):
+                arrays = {"init_pred": np.asarray(init_pred)}
+                for i, leaf in enumerate(jax.tree.leaves(carry)):
+                    arrays[f"carry_{i}"] = np.asarray(leaf)
             # Snapshot durability is the checkpointed driver's extra
             # host-sync point on top of the chunk payload fetch.
             device_loop.count_host_sync(
@@ -2177,9 +2197,7 @@ def _train_gbt(
                 # lose every span since the previous flush). Both are
                 # no-ops when telemetry is off / has no export dir.
                 if telemetry.ENABLED:
-                    _emit_train_spans(
-                        chunk_walls, start, tree_cfg.max_depth
-                    )
+                    _emit_chunk_spans(chunk_walls)
                     telemetry.flight_record(
                         "preempt", signal=guard.signal_name,
                         completed_iters=start, num_trees=num_trees,
@@ -2216,9 +2234,10 @@ def _train_gbt(
     for st in all_starts:
         with np.load(_chunk_path(st)) as z:
             parts.append({k: z[k] for k in z.files})
-    trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = _merge_chunk_parts(
-        parts, num_trees, use_dart, carry
-    )
+    with timer.stage("device_loop.merge"):
+        trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = _merge_chunk_parts(
+            parts, num_trees, use_dart, carry
+        )
     logs = {
         "train_loss": tls,
         "valid_loss": vls,

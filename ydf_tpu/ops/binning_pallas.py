@@ -111,6 +111,7 @@ def binning_pallas(
     grid = (n_pad // chunk,)
     out = pl.pallas_call(
         functools.partial(_bin_kernel, F=F),
+        name="ydf_bin",
         grid=grid,
         in_specs=[
             pl.BlockSpec((F, chunk), lambda c: (0, c)),

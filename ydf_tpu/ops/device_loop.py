@@ -32,11 +32,14 @@ eliminating per-iteration host round trips:
   every previously compiled loop shape instead of rebuilding the jit
   wrapper and retracing `_grow_tree_jit` underneath it
   (tests/test_device_loop.py has the retrace regression).
-* **Host-sync accounting** — every dispatch and every byte the
-  drivers materialize on host at a chunk boundary is counted here, so
-  bench.py can emit `dispatches_per_tree` / `host_sync_bytes_per_tree`
-  on headline records and docs/device_loop.md can inventory the
-  remaining host-sync points instead of hand-waving them.
+* **Host-sync accounting** — every dispatch, every byte the drivers
+  materialize on host at a chunk boundary and every byte train() sends
+  to the device is counted here, so bench.py can emit
+  `dispatches_per_tree` / `host_sync_bytes_per_tree` on headline
+  records and docs/device_loop.md can inventory the remaining
+  host-sync points instead of hand-waving them. The same boundaries
+  are spans (`ydf.device_loop.*`, utils/profiling.py): a span gives
+  the time, a counter the count or the bytes.
 
 The scan body itself (gradient recompute, per-tree quantization grid,
 routing, histogram, gain/argmax via the shared grower seams
@@ -56,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from ydf_tpu.utils import telemetry
+from ydf_tpu.utils.profiling import StageTimer
 
 __all__ = [
     "trees_per_dispatch",
@@ -63,6 +67,7 @@ __all__ = [
     "run_chunk",
     "count_dispatch",
     "count_host_sync",
+    "count_h2d",
     "reset_stats",
     "stats_snapshot",
 ]
@@ -129,7 +134,8 @@ def chunk_fn(run):
         return fn
 
 
-def run_chunk(run, carry, start, chunk_len, *data_args, **data_kwargs):
+def run_chunk(run, carry, start, chunk_len, *data_args,
+              timer: Optional[StageTimer] = None, **data_kwargs):
     """One device dispatch growing `chunk_len` trees: iterations
     [start, start + chunk_len) of the boosting loop, with the carry
     donated. Drop-in for `run.run_chunk` (learners/gbt.py routes its
@@ -140,11 +146,13 @@ def run_chunk(run, carry, start, chunk_len, *data_args, **data_kwargs):
 
     The donated carry is dead after the call — callers must use the
     returned carry (the drivers already do; they snapshot/fetch carry
-    state only AFTER each chunk)."""
+    state only AFTER each chunk). `timer` is the calling train()'s: the
+    host's time to enqueue the chunk is its `device_loop.dispatch`."""
     fn = chunk_fn(run)
-    new_carry, ys = fn(
-        carry, jnp.asarray(start), chunk_len, *data_args, **data_kwargs
-    )
+    with (timer or StageTimer()).stage("device_loop.dispatch"):
+        new_carry, ys = fn(
+            carry, jnp.asarray(start), chunk_len, *data_args, **data_kwargs
+        )
     count_dispatch(chunk_len)
     return new_carry, ys
 
@@ -161,7 +169,9 @@ class _Stats:
     numbers with telemetry off; the telemetry counters below feed the
     always-on dashboards."""
 
-    __slots__ = ("dispatches", "trees", "host_sync_bytes", "chunk_len")
+    __slots__ = (
+        "dispatches", "trees", "host_sync_bytes", "h2d_bytes", "chunk_len"
+    )
 
     def __init__(self) -> None:
         self.reset()
@@ -170,6 +180,7 @@ class _Stats:
         self.dispatches = 0
         self.trees = 0
         self.host_sync_bytes = 0
+        self.h2d_bytes = 0
         self.chunk_len = 0
 
 
@@ -196,14 +207,24 @@ def count_host_sync(nbytes: int) -> None:
     """Records bytes materialized on host at a chunk boundary (the
     per-chunk tree/leaf/loss payload fetch in
     learners/gbt.py:_chunk_arrays_from_ys, snapshot carry fetches,
-    ...). This is the host←device half of the sync; the host→device
-    half is zero after init because every input array is
-    device-resident for the whole train."""
+    ...). This is the host←device half of the sync; `count_h2d` has
+    the other."""
     _STATS.host_sync_bytes += int(nbytes)
     if telemetry.ENABLED:
         telemetry.counter("ydf_train_host_sync_bytes_total").inc(
             int(nbytes)
         )
+
+
+def count_h2d(nbytes: int) -> None:
+    """Records bytes train() sends host→device: the bin matrices,
+    labels and weights of the training and validation rows, once per
+    train() (learners/gbt.py, the `device_loop.h2d` span). Nothing is
+    sent after that: every input stays device-resident for the whole
+    train."""
+    _STATS.h2d_bytes += int(nbytes)
+    if telemetry.ENABLED:
+        telemetry.counter("ydf_train_h2d_bytes_total").inc(int(nbytes))
 
 
 def stats_snapshot() -> Dict[str, float]:
@@ -215,6 +236,7 @@ def stats_snapshot() -> Dict[str, float]:
         "dispatches": _STATS.dispatches,
         "trees": _STATS.trees,
         "host_sync_bytes": _STATS.host_sync_bytes,
+        "h2d_bytes": _STATS.h2d_bytes,
         "device_loop": _STATS.chunk_len,
         "dispatches_per_tree": round(_STATS.dispatches / trees, 6),
         "host_sync_bytes_per_tree": round(

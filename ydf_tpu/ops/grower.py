@@ -129,6 +129,7 @@ def prepare_stats_for_hist(stats, hist_quant: str):
     return hist_stats, qscale, total
 
 
+@jax.named_scope("ydf.sibling")
 def sibling_reconstruct(hist_small, parent_hist, small_is_left, Ld: int):
     """Sibling-subtraction reconstruction: the [Lh, F, B, S] histograms
     of the SMALLER children plus the carried parent histograms →
@@ -151,6 +152,7 @@ def sibling_reconstruct(hist_small, parent_hist, small_is_left, Ld: int):
     return hist
 
 
+@jax.named_scope("ydf.gain")
 def scalar_candidates(hist, *, Fn: int, O: int, rule, rule_ctx):
     """Candidate left-stats for every cut of the scalar features:
     numerical prefix cumsums plus the sorted-order categorical prefixes
@@ -214,6 +216,7 @@ class LayerDecision(NamedTuple):
     num_nodes: jax.Array     # updated node count
 
 
+@jax.named_scope("ydf.gain")
 def layer_decide(
     left_all, ranks, sranks_dirs, parent, active, nid, num_nodes,
     k_gain, k_feat, dirs, rule_ctx=None, *,
@@ -395,6 +398,7 @@ def layer_decide(
     )
 
 
+@jax.named_scope("ydf.route")
 def sibling_next_state(
     hist, do_split, split_rank, left_stats, right_stats, *,
     Ld: int, L: int,
@@ -663,7 +667,8 @@ def _grow_tree_jit(
     # seam); every layer's histogram takes the transformed operand
     # directly (histogram() detects the dtype) instead of re-paying the
     # O(n·S) transform per layer.
-    hist_stats, qscale, total = prepare_stats_for_hist(stats, hist_quant)
+    with jax.named_scope("ydf.hist"):
+        hist_stats, qscale, total = prepare_stats_for_hist(stats, hist_quant)
     tree["leaf_stats"] = tree["leaf_stats"].at[0].set(total)
 
     # Frontier state, padded with one trash slot at index L.
@@ -980,34 +985,35 @@ def _grow_tree_jit(
                 )
                 leaf_id = new_leaf
         else:
-            split_e = pad(do_split, False)[slot]
-            rf_e = pad(route_f, 0)[slot]
-            if F > 0:
-                bin_e = jnp.take_along_axis(
-                    bins, rf_e[:, None].astype(i32), axis=1
-                )[:, 0].astype(i32)
-                # Flat 1-D gather — do NOT index [slot] then [bin]: that
-                # would materialize an [n, B] intermediate.
-                glb_flat = pad(go_left_bins, False).reshape(-1)
-                go_left_e = glb_flat[slot * B + bin_e]
-            else:
-                go_left_e = jnp.zeros((n,), jnp.bool_)
-            if Fs > 0:
-                go_left_e = jnp.where(is_set_e, set_go_left_e, go_left_e)
-            child_id_e = jnp.where(
-                go_left_e, pad(left_id, N)[slot], pad(right_id, N)[slot]
-            )
-            leaf_id = jnp.where(split_e, child_id_e, leaf_id)
-            if children_in_frontier:
-                child_slot_e = jnp.where(
-                    go_left_e,
-                    2 * pad(split_rank, 0)[slot],
-                    2 * pad(split_rank, 0)[slot] + 1,
+            with jax.named_scope("ydf.route"):
+                split_e = pad(do_split, False)[slot]
+                rf_e = pad(route_f, 0)[slot]
+                if F > 0:
+                    bin_e = jnp.take_along_axis(
+                        bins, rf_e[:, None].astype(i32), axis=1
+                    )[:, 0].astype(i32)
+                    # Flat 1-D gather — do NOT index [slot] then [bin]: that
+                    # would materialize an [n, B] intermediate.
+                    glb_flat = pad(go_left_bins, False).reshape(-1)
+                    go_left_e = glb_flat[slot * B + bin_e]
+                else:
+                    go_left_e = jnp.zeros((n,), jnp.bool_)
+                if Fs > 0:
+                    go_left_e = jnp.where(is_set_e, set_go_left_e, go_left_e)
+                child_id_e = jnp.where(
+                    go_left_e, pad(left_id, N)[slot], pad(right_id, N)[slot]
                 )
-                new_slot = jnp.where(split_e, child_slot_e, L)
-                hist_slot_e = (
-                    hmap[new_slot] if hmap is not None else new_slot
-                )
+                leaf_id = jnp.where(split_e, child_id_e, leaf_id)
+                if children_in_frontier:
+                    child_slot_e = jnp.where(
+                        go_left_e,
+                        2 * pad(split_rank, 0)[slot],
+                        2 * pad(split_rank, 0)[slot] + 1,
+                    )
+                    new_slot = jnp.where(split_e, child_slot_e, L)
+                    hist_slot_e = (
+                        hmap[new_slot] if hmap is not None else new_slot
+                    )
 
         if children_in_frontier:
             if fuse_route:

@@ -503,6 +503,7 @@ def resolve_hist_subtract(value=None) -> bool:
     )
 
 
+@jax.named_scope("ydf.hist")
 def histogram(
     bins: jax.Array,  # uint8/int32 [n, F] bin index per (example, feature)
     slot: jax.Array,  # int32 [n] frontier slot in [0, L]; L = inactive

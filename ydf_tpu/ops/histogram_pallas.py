@@ -402,6 +402,7 @@ def histogram_routed_pallas(
     grid = (Fp // Fb, n_pad // chunk)
     hist, new_slot, new_leaf = pl.pallas_call(
         kernel,
+        name="ydf_hist_routed",
         grid=grid,
         in_specs=[
             pl.BlockSpec((Fb, chunk), lambda fb, c: (fb, c)),
@@ -521,6 +522,7 @@ def histogram_pallas(
     grid = (Fp // Fb, n_pad // chunk)
     out = pl.pallas_call(
         kernel,
+        name="ydf_hist",
         grid=grid,
         in_specs=[
             pl.BlockSpec((Fb, chunk), lambda fb, c: (fb, c)),

@@ -102,6 +102,7 @@ def _scores_pallas(values, lengths, anchors, is_closer, block=128,
     ).astype(jnp.float32)
     out = pl.pallas_call(
         _vs_kernel,
+        name="ydf_vs_scores",
         grid=((n + pad) // BN,),
         in_specs=[
             pl.BlockSpec((BN, L, D), lambda i: (i, 0, 0)),

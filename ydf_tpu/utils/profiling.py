@@ -6,173 +6,270 @@ logs stage durations per iteration) and the usage/benchmark hooks
 (`utils/usage.h`, `utils/benchmark/inference.h:36-52`). The TPU build's
 training loop is one fused XLA program, so the honest decomposition is:
 
-* **Phase wall times** — ingestion/binning (host), mesh sharding +
-  device transfer, loss registration, the boosting/bagging loop (first
-  call includes XLA compile), post-processing (forest assembly, OOB,
-  clamping). Collected on every train() at ~zero cost and attached to
-  the model as ``model.training_profile``.
+* **Host spans** — `StageTimer.stage(name)` times every boundary of
+  train() (`TRAIN_SPANS`): the seconds land in
+  ``model.training_profile[name]`` on every train(), and the same
+  interval is a `jax.profiler.TraceAnnotation("ydf." + name)`, so
+  whenever anyone traces (the benchmark's `--trace 1`, an operator's
+  ``YDF_TPU_PROFILE_DIR``) the span sits on `/host:CPU` on the device's
+  clock. With telemetry armed the same name and interval go to its
+  JSONL.
+* **Device scopes** — the boosting scan's body carries
+  `jax.named_scope`s (`DEVICE_SCOPES`); they land in each device
+  operation's `tf_op` metadata, and `device_seconds_by_scope(dir)`
+  reduces a trace to seconds, flops and bytes per scope.
 * **An xprof trace** — set ``YDF_TPU_PROFILE_DIR=/path`` and every
-  train() wraps the device loop in ``jax.profiler.trace`` so the
-  per-op breakdown (histogram contraction, prefix scans, routing) can
-  be read in TensorBoard/xprof. This is the TPU-native replacement for
-  hand-timing stages the compiler has fused anyway.
+  train() runs inside ``jax.profiler.trace``.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import itertools
 import os
 import time
+from collections import defaultdict
 from typing import Dict, Iterator, Optional
+
+import jax
+
+from ydf_tpu.utils import telemetry
+
+# The host spans of train(), as the profiler's trace names them
+# (`training_profile` keys are the same without the "ydf." prefix).
+# Dots nest: `device_loop.*` lie inside `device_loop`.
+TRAIN_SPANS = tuple(
+    "ydf." + name
+    for name in (
+        "ingest_bin",
+        "split",
+        "device_loop",
+        "device_loop.h2d",
+        "device_loop.init",
+        "device_loop.dispatch",
+        "device_loop.wait",
+        "device_loop.fetch",
+        "device_loop.merge",
+        "finalize",
+    )
+)
+
+# The `jax.named_scope`s of the boosting scan's body, each a string
+# literal where the work is (learners/gbt.py, ops/grower.py,
+# ops/histogram.py).
+DEVICE_SCOPES = (
+    "ydf.grad",
+    "ydf.hist",
+    "ydf.sibling",
+    "ydf.gain",
+    "ydf.route",
+    "ydf.leaf",
+    "ydf.valid",
+    "ydf.loss",
+)
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@functools.lru_cache(maxsize=None)
+def _compile_clock() -> list:
+    """[seconds this process has spent in XLA's compile path, cache
+    loads included, since the first call], kept by one listener
+    (jax.monitoring has no public way to take a listener off again)."""
+    total = [0.0]
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        if event == _COMPILE_EVENT:
+            total[0] += duration
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return total
 
 
 class StageTimer:
-    """Accumulates named wall-time phases for one train() call."""
+    """Named wall-time spans of one train() call: seconds per name for
+    `training_profile`, each also a TraceAnnotation `ydf.<name>`."""
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter_ns()
+        self._compiled = _compile_clock()
+        self._compiled0 = self._compiled[0]
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
-        t = time.perf_counter()
+        t = time.perf_counter_ns()
         try:
-            yield
+            with jax.profiler.TraceAnnotation("ydf." + name):
+                yield
         finally:
-            self.seconds[name] = self.seconds.get(name, 0.0) + (
-                time.perf_counter() - t
-            )
+            dur = time.perf_counter_ns() - t
+            self.seconds[name] = self.seconds.get(name, 0.0) + dur / 1e9
+            telemetry.emit_span("ydf." + name, t, dur)  # no-op unless armed
 
     def finish(self) -> Dict[str, float]:
+        """The profile: every span's seconds, `device_loop.compile`
+        (XLA compile seconds that fell inside this train(); they lie
+        inside `device_loop.init` and `.dispatch`, 0.0 on a warm call),
+        `total`, and `other` = total less the top-level (undotted)
+        spans."""
         out = dict(self.seconds)
-        out["total"] = time.perf_counter() - self._t0
-        accounted = sum(self.seconds.values())
-        out["other"] = max(out["total"] - accounted, 0.0)
+        out["device_loop.compile"] = self._compiled[0] - self._compiled0
+        out["total"] = (time.perf_counter_ns() - self._t0) / 1e9
+        top_level = sum(v for k, v in self.seconds.items() if "." not in k)
+        out["other"] = max(out["total"] - top_level, 0.0)
         return out
 
 
 @contextlib.contextmanager
 def maybe_trace(label: str = "train") -> Iterator[None]:
-    """jax.profiler trace around the device loop when
+    """jax.profiler trace around the whole of train() when
     YDF_TPU_PROFILE_DIR is set; no-op (and no overhead) otherwise."""
     trace_dir = os.environ.get("YDF_TPU_PROFILE_DIR")
     if not trace_dir:
         yield
         return
-    import jax
-
     path = os.path.join(trace_dir, label)
     os.makedirs(path, exist_ok=True)
     with jax.profiler.trace(path):
         yield
 
 
+# Field numbers are interface facts of tsl/profiler/protobuf/
+# xplane.proto: XSpace.planes=1; XPlane.name=2, .lines=3,
+# .event_metadata=4, .stat_metadata=5 (map entries key=1/value=2);
+# XLine.name=2, .timestamp_ns=3, .events=4; XEvent.metadata_id=1,
+# .offset_ps=2, .duration_ps=3; XEventMetadata.name=2, .stats=5;
+# XStatMetadata.name=2; XStat.metadata_id=1, .uint64_value=3,
+# .int64_value=4, .str_value=5, .ref_value=7.
+
+
+def _walk_xplanes(trace_dir: str):
+    """Yields (plane name, line name, events) for every line of every
+    `*.xplane.pb` under `trace_dir`, events as (start_ps, duration_ps,
+    name, metadata stats {stat name: int or str}). Read with the
+    schema-less protowire reader (utils/protowire.py): no tensorflow or
+    tensorboard dependency, and, unlike jax.profiler.ProfileData, the
+    event METADATA's stats (`tf_op`, `flops`, `bytes_accessed`)."""
+    import pathlib
+
+    from ydf_tpu.utils import protowire as pw
+
+    def table(plane, field):
+        out = {}
+        for entry_b in plane.get(field, []):
+            entry = pw.decode(bytes(entry_b))
+            if entry.get(2):
+                out[pw.get_int(entry, 1)] = pw.decode(bytes(entry[2][-1]))
+        return out
+
+    for path in sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb")):
+        try:
+            space = pw.decode(path.read_bytes())
+        except Exception:
+            continue  # partial/foreign file: skip, never fail the caller
+        for plane_b in space.get(1, []):
+            plane = pw.decode(bytes(plane_b))
+            stat_names = {
+                k: pw.get_str(md, 2) for k, md in table(plane, 5).items()
+            }
+            events_md = {}
+            for mid, md in table(plane, 4).items():
+                stats = {}
+                for st_b in md.get(5, []):
+                    st = pw.decode(bytes(st_b))
+                    if 5 in st:
+                        value = pw.get_str(st, 5)
+                    elif 7 in st:
+                        value = stat_names.get(pw.get_int(st, 7), "")
+                    else:
+                        value = pw.get_int(st, 3) or pw.get_int(st, 4)
+                    stats[stat_names.get(pw.get_int(st, 1), "")] = value
+                events_md[mid] = (pw.get_str(md, 2), stats)
+            for line_b in plane.get(3, []):
+                line = pw.decode(bytes(line_b))
+                t0_ps = pw.get_int(line, 3) * 1000
+                events = []
+                for ev_b in line.get(4, []):
+                    ev = pw.decode(bytes(ev_b))
+                    name, stats = events_md.get(
+                        pw.get_int(ev, 1), ("", {})
+                    )
+                    if name:
+                        events.append(
+                            (t0_ps + pw.get_int(ev, 2), pw.get_int(ev, 3),
+                             name, stats)
+                        )
+                yield pw.get_str(plane, 2), pw.get_str(line, 2), events
+
+
 def trace_event_seconds(
     trace_dir: str, substrings: Optional[tuple] = None
 ) -> Dict[str, float]:
-    """Aggregates per-op wall seconds from a jax.profiler trace directory.
-
-    Parses the xprof `*.xplane.pb` files with the schema-less protowire
-    reader (utils/protowire.py) — no tensorflow/tensorboard dependency —
-    summing XEvent durations per event-metadata name across all planes
-    and lines. `substrings` filters to event names containing any of the
-    given fragments (None keeps everything). Field numbers are interface
-    facts of tsl/profiler/protobuf/xplane.proto: XSpace.planes=1;
-    XPlane.lines=3, .event_metadata=4 (map entries key=1/value=2);
-    XLine.events=4; XEvent.metadata_id=1, .duration_ps=3;
-    XEventMetadata.id=1, .name=2.
-
-    This is the honest IN-LOOP per-op attribution: the boosting loop is
-    one fused jit scan, so re-measuring ops outside it (bench.py's
-    historical `hist_s`) is same-shape attribution, not measurement.
-    """
-    import pathlib
-
-    from ydf_tpu.utils import protowire as pw
-
-    out: Dict[str, float] = {}
-    for path in sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb")):
-        try:
-            space = pw.decode(path.read_bytes())
-        except Exception:
-            continue  # partial/foreign file: skip, never fail the bench
-        for plane_b in space.get(1, []):
-            plane = pw.decode(bytes(plane_b))
-            names: Dict[int, str] = {}
-            for entry_b in plane.get(4, []):
-                entry = pw.decode(bytes(entry_b))
-                md_b = entry.get(2)
-                if not md_b:
-                    continue
-                md = pw.decode(bytes(md_b[-1]))
-                names[pw.get_int(entry, 1)] = pw.get_str(md, 2)
-            if not names:
-                continue
-            for line_b in plane.get(3, []):
-                line = pw.decode(bytes(line_b))
-                for ev_b in line.get(4, []):
-                    ev = pw.decode(bytes(ev_b))
-                    name = names.get(pw.get_int(ev, 1))
-                    if not name:
-                        continue
-                    if substrings is not None and not any(
-                        s in name for s in substrings
-                    ):
-                        continue
-                    out[name] = out.get(name, 0.0) + (
-                        pw.get_int(ev, 3) / 1e12
-                    )
-    return out
+    """Seconds per event name, summed over all planes and lines of a
+    jax.profiler trace directory; `substrings` keeps the names that
+    contain any of the fragments (None keeps everything). bench.py reads
+    the CPU path's custom-call events with it."""
+    out: Dict[str, float] = defaultdict(float)
+    for _plane, _line, events in _walk_xplanes(trace_dir):
+        for _start, dur_ps, name, _stats in events:
+            if substrings is None or any(s in name for s in substrings):
+                out[name] += dur_ps / 1e12
+    return dict(out)
 
 
-def trace_event_counts(
-    trace_dir: str, substrings: Optional[tuple] = None
-) -> Dict[str, int]:
-    """Aggregates per-op EVENT COUNTS from a jax.profiler trace
-    directory — the same schema-less xplane walk as
-    trace_event_seconds, counting XEvent occurrences per metadata name
-    instead of summing durations. This is the trace-level cross-check
-    for the device loop's dispatch accounting: every XLA dispatch of
-    the boosting chunk shows up as one `jit_run_chunk`-family event on
-    the host runtime line, so
-    `trace_event_counts(dir, ("jit_",))` recovers dispatches-per-train
-    from the profiler's own record (ops/device_loop.py counts the same
-    quantity host-side without needing a trace armed)."""
-    import pathlib
+def device_op_times(trace_dir: str):
+    """Yields (own seconds, name, metadata stats) for every event on
+    the `XLA Ops` line of every `/device:TPU:*` plane under
+    `trace_dir`. An event's OWN time is its span less its children's (a
+    `while` spans its body), so the own times sum to the device's busy
+    time."""
+    for plane, line, events in _walk_xplanes(trace_dir):
+        if not plane.startswith("/device:TPU:") or line != "XLA Ops":
+            continue
+        # Open events, outermost first: [end_ps, start_ps, ps covered by
+        # children, name, stats]. The last pass closes what is open.
+        stack = []
+        events.sort(key=lambda e: (e[0], -e[1]))
+        for start, dur, name, stats in itertools.chain(
+            events, [(float("inf"), 0, "", {})]
+        ):
+            while stack and stack[-1][0] <= start:
+                end, begin, covered, *event = stack.pop()
+                yield (max(end - begin - covered, 0) / 1e12, *event)
+                if stack:
+                    stack[-1][2] += end - begin
+            end = min(start + dur, stack[-1][0]) if stack else start + dur
+            stack.append([end, start, 0, name, stats])
 
-    from ydf_tpu.utils import protowire as pw
 
-    out: Dict[str, int] = {}
-    for path in sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb")):
-        try:
-            space = pw.decode(path.read_bytes())
-        except Exception:
-            continue  # partial/foreign file: skip, never fail the bench
-        for plane_b in space.get(1, []):
-            plane = pw.decode(bytes(plane_b))
-            names: Dict[int, str] = {}
-            for entry_b in plane.get(4, []):
-                entry = pw.decode(bytes(entry_b))
-                md_b = entry.get(2)
-                if not md_b:
-                    continue
-                md = pw.decode(bytes(md_b[-1]))
-                names[pw.get_int(entry, 1)] = pw.get_str(md, 2)
-            if not names:
-                continue
-            for line_b in plane.get(3, []):
-                line = pw.decode(bytes(line_b))
-                for ev_b in line.get(4, []):
-                    ev = pw.decode(bytes(ev_b))
-                    name = names.get(pw.get_int(ev, 1))
-                    if not name:
-                        continue
-                    if substrings is not None and not any(
-                        s in name for s in substrings
-                    ):
-                        continue
-                    out[name] = out.get(name, 0) + 1
-    return out
+def scope_of(stats: Dict[str, object]) -> str:
+    """The innermost `ydf.*` component of the name stack in an event's
+    `tf_op` (`unscoped` where there is none)."""
+    for part in reversed(str(stats.get("tf_op", "")).split("/")):
+        if part.startswith("ydf."):
+            return part.rstrip(":")
+    return "unscoped"
+
+
+def device_seconds_by_scope(trace_dir: str) -> Dict[str, Dict[str, float]]:
+    """{scope: {"seconds", "events", "flops", "bytes"}} of a trace
+    directory's device operations by `DEVICE_SCOPES`, largest first:
+    own seconds (`device_op_times`), and the compiler's own counts from
+    the event metadata (`flops`, `bytes_accessed`) summed over
+    occurrences, which is what a roofline share of the scope divides
+    by."""
+    out: Dict[str, Dict[str, float]] = defaultdict(
+        lambda: {"seconds": 0.0, "events": 0, "flops": 0, "bytes": 0}
+    )
+    for seconds, _name, stats in device_op_times(trace_dir):
+        row = out[scope_of(stats)]
+        row["seconds"] += seconds
+        row["events"] += 1
+        row["flops"] += stats.get("flops", 0)
+        row["bytes"] += stats.get("bytes_accessed", 0)
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]["seconds"]))
 
 
 def device_loop_metrics() -> Dict[str, float]:

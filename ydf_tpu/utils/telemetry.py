@@ -759,15 +759,15 @@ def emit_span(
     name: str, start_ns: int, dur_ns: int,
     args: Optional[dict] = None, tid: Optional[int] = None,
 ) -> None:
-    """Records a complete span with EXPLICIT timestamps — used for
-    post-hoc attribution of host-opaque device work (the fused boosting
-    scan's per-tree/per-layer subdivision, gbt.py). Attributed spans
-    carry `{"attributed": true}` in args by convention."""
+    """Records a complete span with EXPLICIT timestamps: an interval
+    measured elsewhere (profiling.StageTimer's `ydf.*` spans, gbt.py's
+    `train.chunk` and `train`). A span that is computed and not
+    measured carries `{"attributed": true}` in args by convention."""
     if not ENABLED:
         return
     _record_event(name, start_ns, dur_ns, args, tid=tid)
     if MEM_SAMPLE:
-        # Attributed spans are sample points too: the fused single-scan
+        # Emitted spans are sample points too: the fused single-scan
         # driver emits ONLY these, and its train must still feed the
         # sampled RSS watermark (throttled like the span-exit hook).
         _STATE["ledger"].note_rss(time.perf_counter_ns())
