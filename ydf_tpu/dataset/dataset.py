@@ -132,16 +132,24 @@ class Dataset:
         # consumer side.
         self._binner_cache: Dict = {}
         self._bin_cache: Dict = {}
+        # This Dataset under column types a learner forces (a
+        # classification label is CATEGORICAL whatever its dtype), keyed
+        # by what was forced: the same memo, one level up. Without it
+        # every train() on a Dataset ingested with a numerical label
+        # infers, bins and encodes all of it again (14.5 s a job at
+        # 40M x 28, PERF.md section 6).
+        self._retyped: Dict = {}
         _LIVE_DATASETS.add(self)  # memory-ledger "bin_matrix" source
 
     def bin_cache_bytes(self) -> int:
         """Bytes held by this Dataset's cached bin matrices / encodings
         (the tuner/CV memo) — its share of the memory ledger's
         "bin_matrix" row."""
-        total = 0
+        held = {}  # by identity: an aux entry names the matrix it is of
         for v in self._bin_cache.values():
-            total += int(getattr(v, "nbytes", 0))
-        return total
+            for a in v if isinstance(v, tuple) else (v,):
+                held[id(a)] = int(getattr(a, "nbytes", 0))
+        return sum(held.values())
 
     # ---- binning memo (see dataset/binning.py) ----------------------- #
 
@@ -208,14 +216,23 @@ class Dataset:
                 ]
                 if mismatched:
                     # Re-infer with the forced types (notably: classification
-                    # labels must be CATEGORICAL whatever the raw dtype).
-                    return Dataset.from_data(
-                        dict(data.data),
-                        label=label,
-                        max_vocab_count=max_vocab_count,
-                        min_vocab_frequency=min_vocab_frequency,
-                        column_types=column_types,
+                    # labels must be CATEGORICAL whatever the raw dtype),
+                    # once per Dataset and set of forced types.
+                    key = (
+                        label, max_vocab_count, min_vocab_frequency,
+                        tuple(sorted(
+                            (k, t.value) for k, t in column_types.items()
+                        )),
                     )
+                    if key not in data._retyped:
+                        data._retyped[key] = Dataset.from_data(
+                            dict(data.data),
+                            label=label,
+                            max_vocab_count=max_vocab_count,
+                            min_vocab_frequency=min_vocab_frequency,
+                            column_types=column_types,
+                        )
+                    return data._retyped[key]
             return data
         if isinstance(data, str):
             fmt, raw_path = _split_typed_path(data)
@@ -313,6 +330,18 @@ class Dataset:
         raw = self.data[name]
         assert col.vocabulary is not None
         lookup = {item: i for i, item in enumerate(col.vocabulary)}
+        if np.issubdtype(raw.dtype, np.integer) and raw.size:
+            # Whole numbers in a narrow range (a class label): a table
+            # over the range, one pass and no sort. np.unique's sort of
+            # 40M labels was 8.9 s of every classification job.
+            lo, hi = int(raw.min()), int(raw.max())
+            if hi - lo < 1 << 16:
+                table = np.array(
+                    [lookup.get(str(v), 0) for v in range(lo, hi + 1)],
+                    dtype=np.int32,
+                )
+                # int64: `raw - lo` wraps in a narrow dtype (int8 -100..100)
+                return table[raw.astype(np.int64) - lo]
         if np.issubdtype(raw.dtype, np.number) and raw.dtype != np.bool_:
             # Vectorized via unique+inverse: the stringify/lookup loop
             # runs over the DISTINCT values (2 for a binary label)

@@ -169,6 +169,11 @@ _PARAM_INFO: Dict[str, _Info] = {
     "l2_regularization": _Info(
         "L2 penalty on leaf values in the gain and leaf output.",
         min_value=0.0),
+    "use_hessian_gain": _Info(
+        "Split gain from the loss's hessians (XGBoost's criterion) and "
+        "not from the variance of the gradients. True is the only gain "
+        "implemented and the default here; upstream YDF defaults to "
+        "false. False raises NotImplementedError."),
     "loss": _Info(
         "Loss function. DEFAULT selects by task (binomial log-likelihood "
         "for binary classification, multinomial for multiclass, MSE for "

@@ -40,6 +40,12 @@ eliminating per-iteration host round trips:
   host-sync points instead of hand-waving them. The same boundaries
   are spans (`ydf.device_loop.*`, utils/profiling.py): a span gives
   the time, a counter the count or the bytes.
+* **The program's build** — the first dispatch of a loop shape traces,
+  lowers and compiles it (or loads it from the persistent cache):
+  `dispatch` makes that call the span `device_loop.compile` and
+  remembers its seconds with the compiled function, so that every
+  later job can say what the program it ran cost
+  (`training_profile["device_loop.program_build_s"]`).
 
 The scan body itself (gradient recompute, per-tree quantization grid,
 routing, histogram, gain/argmax via the shared grower seams
@@ -50,6 +56,7 @@ only owns HOW that body is dispatched.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import weakref
@@ -64,6 +71,7 @@ from ydf_tpu.utils.profiling import StageTimer
 __all__ = [
     "trees_per_dispatch",
     "chunk_fn",
+    "dispatch",
     "run_chunk",
     "count_dispatch",
     "count_host_sync",
@@ -134,6 +142,48 @@ def chunk_fn(run):
         return fn
 
 
+@functools.lru_cache(maxsize=None)
+def _cache_hits() -> list:
+    """[times JAX's persistent compile cache has answered since the
+    first call], kept by one listener (jax.monitoring has no public way
+    to take a listener off again)."""
+    hits = [0]
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            hits[0] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return hits
+
+
+def dispatch(fn, timer: StageTimer, *args, **kwargs):
+    """Calls `fn`, a jitted boosting program (a `run`, or the donated
+    chunk function of one), and returns what it returns. The first call
+    with a signature builds the program: that call is the span
+    `device_loop.compile`, and its seconds, and whether the persistent
+    cache answered, are remembered with `fn` for as long as it lives.
+    Every call notes on `timer` which program the job ran."""
+    signature = tuple(
+        (x.shape, str(x.dtype)) if hasattr(x, "shape") else x
+        for x in jax.tree.leaves((args, kwargs))
+    )
+    builds = fn.__dict__.setdefault("_program_builds", {})
+    if signature in builds:
+        out = fn(*args, **kwargs)
+    else:
+        hits = _cache_hits()
+        hits0 = hits[0]
+        spent0 = timer.seconds.get("device_loop.compile", 0.0)
+        with timer.stage("device_loop.compile"):
+            out = fn(*args, **kwargs)
+        builds[signature] = (
+            timer.seconds["device_loop.compile"] - spent0, hits[0] > hits0
+        )
+    timer.programs[(id(fn), signature)] = builds[signature]
+    return out
+
+
 def run_chunk(run, carry, start, chunk_len, *data_args,
               timer: Optional[StageTimer] = None, **data_kwargs):
     """One device dispatch growing `chunk_len` trees: iterations
@@ -149,9 +199,11 @@ def run_chunk(run, carry, start, chunk_len, *data_args,
     state only AFTER each chunk). `timer` is the calling train()'s: the
     host's time to enqueue the chunk is its `device_loop.dispatch`."""
     fn = chunk_fn(run)
-    with (timer or StageTimer()).stage("device_loop.dispatch"):
-        new_carry, ys = fn(
-            carry, jnp.asarray(start), chunk_len, *data_args, **data_kwargs
+    timer = timer or StageTimer()
+    with timer.stage("device_loop.dispatch"):
+        new_carry, ys = dispatch(
+            fn, timer, carry, jnp.asarray(start), chunk_len, *data_args,
+            **data_kwargs
         )
     count_dispatch(chunk_len)
     return new_carry, ys

@@ -157,12 +157,24 @@ def scalar_candidates(hist, *, Fn: int, O: int, rule, rule_ctx):
     """Candidate left-stats for every cut of the scalar features:
     numerical prefix cumsums plus the sorted-order categorical prefixes
     (O orderings per categorical feature). Returns (left_all
-    [Ld, Fn + Fc·O, B, S], ranks [Ld, Fc, O, B] or None)."""
+    [Ld, Fn + Fc·O, B, S], ranks [Ld, Fc, O, B] or None, right_all):
+    right_all[..., t, :] sums the cells past cut t themselves, for the
+    nodes whose `parent - left` cannot be trusted (layer_decide)."""
     Ld, F, B, S = hist.shape
     Fc = F - Fn
+    def sums_past(cells, axis):
+        # [.., t, ..] = sum of cells t+1.. along `axis`: a cumsum from
+        # the far end, moved down by one cut.
+        back = jnp.flip(jnp.cumsum(jnp.flip(cells, axis), axis), axis)
+        zero = jnp.zeros_like(jax.lax.slice_in_dim(back, 0, 1, axis=axis))
+        return jnp.concatenate(
+            [jax.lax.slice_in_dim(back, 1, None, axis=axis), zero], axis
+        )
+
     csum_num = jnp.cumsum(hist[:, :Fn], axis=2)  # [Ld, Fn, B, S]
+    past_num = sums_past(hist[:, :Fn], 2)
     if Fc == 0:
-        return csum_num, None
+        return csum_num, None, past_num
     hist_cat = hist[:, Fn:]  # [Ld, Fc, B, S]
     # O orderings per categorical feature (reference
     # FindSplitLabelClassificationFeatureCategorical,
@@ -187,7 +199,12 @@ def scalar_candidates(hist, *, Fn: int, O: int, rule, rule_ctx):
     csum_cat = jnp.cumsum(sorted_hist, axis=3).reshape(
         Ld, Fc * O, B, S
     )
-    return jnp.concatenate([csum_num, csum_cat], axis=1), ranks
+    past_cat = sums_past(sorted_hist, 3).reshape(Ld, Fc * O, B, S)
+    return (
+        jnp.concatenate([csum_num, csum_cat], axis=1),
+        ranks,
+        jnp.concatenate([past_num, past_cat], axis=1),
+    )
 
 
 class LayerDecision(NamedTuple):
@@ -223,13 +240,15 @@ def layer_decide(
     rule, L: int, B: int, N: int, Fn: int, Fc: int, O: int, Fs: int,
     W: int, min_examples: int, min_split_gain: float,
     candidate_features: int, num_valid_features, children_in_frontier,
+    right_scalar,
 ):
     """One layer's split search: gain → validity/sampling masks →
     per-slot argmax → frontier-overflow cap → child allocation → chosen
     stats + routing tables. Pure function of its inputs; shared by the
     single-machine grower (traced into its program) and the distributed
     manager's reduction (jitted per layer over the histogram assembled
-    from worker feature slices)."""
+    from worker feature slices). `right_scalar` is scalar_candidates'
+    third value, None where the layer has no scalar feature."""
     i32 = jnp.int32
     Ld = left_all.shape[0]
     F = Fn + Fc
@@ -238,6 +257,28 @@ def layer_decide(
 
     Fa = Fcand + 2 * Fs  # total candidate columns
     right_all = parent[:, None, None, :] - left_all  # [Ld, Fa, B, S]
+    if right_scalar is not None:
+        # A node of 2**24 rows or more: f32 no longer holds every whole
+        # number, the prefix sums round by more than a row, and
+        # `parent - left` carries the rounding of both. Where the right
+        # side of a cut is empty that difference can read 6 rows of
+        # nothing, pass min_examples, and win on a gain of g^2 / h over
+        # two residues (a leaf of 2.6e11 on the chip, PERF.md section
+        # 6); where it is small, its sums are mostly the parent's error,
+        # and every `parent - left` below hands that on. The cells past
+        # the cut, summed themselves (scalar_candidates), are exact for
+        # counts below 2**24 and as good as the side is small. Below
+        # 2**24 rows the old form stays, bit for bit; so do set splits,
+        # whose sides are no union of cells.
+        large = (parent[:, -1] >= float(1 << 24))[:, None, None, None]
+        Fr = right_scalar.shape[1]
+        right_all = jnp.concatenate(
+            [
+                jnp.where(large, right_scalar, right_all[:, :Fr]),
+                right_all[:, Fr:],
+            ],
+            axis=1,
+        )
 
     gain = rule.gain(left_all, right_all, parent[:, None, None, :],
                      k_gain, rule_ctx)  # [Ld, F, B]
@@ -329,7 +370,12 @@ def layer_decide(
     left_stats = jnp.take_along_axis(
         chosen, best_t[:, None, None], axis=1
     )[:, 0]  # [Ld, S]
-    right_stats = parent - left_stats
+    right_stats = jnp.take_along_axis(
+        jnp.take_along_axis(
+            right_all, best_f[:, None, None, None], axis=1
+        )[:, 0],
+        best_t[:, None, None], axis=1,
+    )[:, 0]  # [Ld, S]: parent - left_stats, or the cells past the cut
 
     is_set_split = best_f >= Fcand
     # Direction column → (direction, real set-feature index).
@@ -794,8 +840,9 @@ def _grow_tree_jit(
                 bins, slot, hist_stats, num_slots=Ld, num_bins=B,
                 impl=hist_impl, quant=hist_quant, quant_scale=qscale,
             )  # [Ld, F, B, S]
+        right_scalar = None
         if F > 0:
-            left_all, ranks = scalar_candidates(
+            left_all, ranks, right_scalar = scalar_candidates(
                 hist, Fn=Fn, O=O, rule=rule, rule_ctx=rule_ctx
             )
 
@@ -878,6 +925,7 @@ def _grow_tree_jit(
             candidate_features=candidate_features,
             num_valid_features=num_valid_features,
             children_in_frontier=children_in_frontier,
+            right_scalar=right_scalar,
         )
         do_split, split_rank = dec.do_split, dec.split_rank
         wid, left_id, right_id = dec.wid, dec.left_id, dec.right_id

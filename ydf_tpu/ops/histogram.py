@@ -204,16 +204,14 @@ def _histogram_matmul(
     S = stats.shape[1]
     L, B = num_slots, num_bins
     chunk = min(chunk, max(n, 1))
-
-    n_pad = ((n + chunk - 1) // chunk) * chunk
-    if n_pad != n:
-        bins = jnp.pad(bins, ((0, n_pad - n), (0, 0)))
-        # Padded examples land in the trash slot L and are dropped below.
-        slot = jnp.pad(slot, (0, n_pad - n), constant_values=L)
-        stats = jnp.pad(stats, ((0, n_pad - n), (0, 0)))
-    bins_c = bins.reshape(n_pad // chunk, chunk, F)
-    slot_c = slot.reshape(n_pad // chunk, chunk)
-    stats_c = stats.reshape(n_pad // chunk, chunk, S)
+    # Whole chunks are sliced out of the operands as they come, inside
+    # the loop; the ragged tail is one more call of the same body on its
+    # rows padded to a chunk, after the loop. No operand is reshaped to
+    # [chunks, chunk, ...]: that reshape is a relayout of the whole
+    # array whose compile time on a TPU depends on the chunk COUNT (62
+    # chunks x 28 features: over 400 s where 64 take 2, PERF.md
+    # section 6), and a pad of the whole array besides.
+    n_full, tail = divmod(n, chunk)
 
     bvals = jnp.arange(B, dtype=jnp.int32)
     # int8 stats (quant mode) contract on integer operands with an int32
@@ -232,8 +230,8 @@ def _histogram_matmul(
     # f32 operand had). Measured on a v5e (PERF.md section 5).
     per_dot = min(pieces, max(1, _MXU_COLUMNS // (L * S)))
 
-    def one_chunk(carry, xs):
-        b_chunk, s_chunk, st_chunk = xs  # [chunk, F], [chunk], [chunk, S]
+    def one_chunk(carry, b_chunk, s_chunk, st_chunk):
+        # [F, B, pieces*S*L] += sums over [chunk, F], [chunk], [chunk, S]
         if pieces > 1:
             st_chunk = split_bf16(st_chunk, pieces)  # bf16 [chunk, pieces*S]
         # Both operands are written with the rows on the minor,
@@ -273,11 +271,28 @@ def _histogram_matmul(
             )  # [B, pieces*S*L]
             return acc.at[f].add(h)
 
-        carry = jax.lax.fori_loop(0, F, per_feature, carry)
-        return carry, None
+        return jax.lax.fori_loop(0, F, per_feature, carry)
 
-    init = jnp.zeros((F, B, pieces * S * L), dtype=acc_dtype)
-    hist, _ = jax.lax.scan(one_chunk, init, (bins_c, slot_c, stats_c))
+    def whole_chunk(i, carry):
+        rows = [
+            jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, axis=0)
+            for x in (bins, slot, stats)
+        ]
+        return one_chunk(carry, *rows)
+
+    hist = jnp.zeros((F, B, pieces * S * L), dtype=acc_dtype)
+    hist = jax.lax.fori_loop(0, n_full, whole_chunk, hist)
+    if tail:
+        # Padded examples land in the trash slot L and add exact zeros,
+        # so the partial sums are those of a table padded to whole
+        # chunks, in the same order.
+        pad = chunk - tail
+        hist = one_chunk(
+            hist,
+            jnp.pad(bins[n - tail:], ((0, pad), (0, 0))),
+            jnp.pad(slot[n - tail:], (0, pad), constant_values=L),
+            jnp.pad(stats[n - tail:], ((0, pad), (0, 0))),
+        )
     # Smallest piece first: the f32 sums of the three slabs lose no
     # more in this fold than one accumulator of whole values would.
     hist = hist.reshape(F, B, pieces, S, L)

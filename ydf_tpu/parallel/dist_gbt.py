@@ -192,7 +192,7 @@ def _j_layer_step(
     from ydf_tpu.ops import grower
 
     Ld = hist.shape[0]
-    left_all, ranks = grower.scalar_candidates(
+    left_all, ranks, right_scalar = grower.scalar_candidates(
         hist, Fn=Fn, O=O, rule=rule, rule_ctx=None
     )
     dec = grower.layer_decide(
@@ -204,6 +204,7 @@ def _j_layer_step(
         candidate_features=candidate_features,
         num_valid_features=num_valid_features,
         children_in_frontier=children,
+        right_scalar=right_scalar,
     )
     out = {"dec": dec, "mask": grower._pack_mask(dec.store_mask)}
     if children and subtract and min(Ld, L // 2) >= 1:

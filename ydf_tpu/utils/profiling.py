@@ -25,12 +25,11 @@ training loop is one fused XLA program, so the honest decomposition is:
 from __future__ import annotations
 
 import contextlib
-import functools
 import itertools
 import os
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, Optional, Tuple
 
 import jax
 
@@ -48,6 +47,7 @@ TRAIN_SPANS = tuple(
         "device_loop.h2d",
         "device_loop.init",
         "device_loop.dispatch",
+        "device_loop.compile",
         "device_loop.wait",
         "device_loop.fetch",
         "device_loop.merge",
@@ -69,33 +69,17 @@ DEVICE_SCOPES = (
     "ydf.loss",
 )
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-@functools.lru_cache(maxsize=None)
-def _compile_clock() -> list:
-    """[seconds this process has spent in XLA's compile path, cache
-    loads included, since the first call], kept by one listener
-    (jax.monitoring has no public way to take a listener off again)."""
-    total = [0.0]
-
-    def on_duration(event: str, duration: float, **_kw) -> None:
-        if event == _COMPILE_EVENT:
-            total[0] += duration
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    return total
-
-
 class StageTimer:
     """Named wall-time spans of one train() call: seconds per name for
     `training_profile`, each also a TraceAnnotation `ydf.<name>`."""
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
+        # The compiled boosting programs this call dispatched, each once:
+        # program -> (seconds its build took, whenever that was; whether
+        # the persistent cache answered). ops/device_loop.dispatch fills it.
+        self.programs: Dict[object, Tuple[float, bool]] = {}
         self._t0 = time.perf_counter_ns()
-        self._compiled = _compile_clock()
-        self._compiled0 = self._compiled[0]
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
@@ -109,13 +93,22 @@ class StageTimer:
             telemetry.emit_span("ydf." + name, t, dur)  # no-op unless armed
 
     def finish(self) -> Dict[str, float]:
-        """The profile: every span's seconds, `device_loop.compile`
-        (XLA compile seconds that fell inside this train(); they lie
-        inside `device_loop.init` and `.dispatch`, 0.0 on a warm call),
-        `total`, and `other` = total less the top-level (undotted)
-        spans."""
+        """The profile: every span's seconds (`device_loop.compile`,
+        the build of a boosting program inside `device_loop.dispatch`,
+        is 0.0 on a warm call), `total`, and `other` = total less the
+        top-level (undotted) spans. A call that dispatched a boosting
+        program also says what that program's build cost, whichever
+        call paid it: `device_loop.program_build_s`, and
+        `device_loop.program_from_cache` (1.0 where the persistent
+        compile cache answered)."""
         out = dict(self.seconds)
-        out["device_loop.compile"] = self._compiled[0] - self._compiled0
+        out.setdefault("device_loop.compile", 0.0)
+        if self.programs:
+            builds = list(self.programs.values())
+            out["device_loop.program_build_s"] = sum(s for s, _ in builds)
+            out["device_loop.program_from_cache"] = float(
+                all(hit for _, hit in builds)
+            )
         out["total"] = (time.perf_counter_ns() - self._t0) / 1e9
         top_level = sum(v for k, v in self.seconds.items() if "." not in k)
         out["other"] = max(out["total"] - top_level, 0.0)
