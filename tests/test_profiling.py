@@ -165,6 +165,43 @@ def test_chunk_program_names_every_device_scope(monkeypatch):
     assert [s for s in DEVICE_SCOPES if f"/{s}/" not in text] == []
 
 
+@pytest.mark.parametrize("backend", ["cpu", "as_tpu"])
+@pytest.mark.parametrize("driver", ["single_scan", "early_stop"])
+def test_route_lookups_are_counted_with_the_program(driver, backend,
+                                                    monkeypatch):
+    """`device_loop.route_select` and `.route_gather`: the per-row
+    look-ups of the traced boosting program that are compare-and-select
+    passes and that are gathers (ops/lookup.py), kept with the compiled
+    program, so a job that reuses it says the same. A CPU on the XLA
+    chain gathers them all; traced as a TPU would, a table of 7 nodes
+    and a row of 4 bins select them all."""
+    from ydf_tpu.ops import lookup
+
+    monkeypatch.setenv("YDF_TPU_ROUTE_IMPL", "xla")
+    if backend == "as_tpu":
+        monkeypatch.setattr(lookup, "is_tpu_backend", lambda: True)
+    kw = {"single_scan": {"early_stopping": "NONE"},
+          "early_stop": {"num_trees": 6,
+                         "early_stopping_num_trees_look_ahead": 3}}[driver]
+    # A seed of its own a case: the boosting function is cached by its
+    # configuration, which does not know how its look-ups were traced.
+    kw["random_seed"] = 2900 + 2 * (backend == "cpu") + (driver == "early_stop")
+    data = _regression_data()
+    first = _regression_learner(**kw).train(data).training_profile
+    second = _regression_learner(**kw).train(data).training_profile
+    # A tree of depth 3: a level looks up split, column, bin, cut, left
+    # and right, and all but the last the rank and the histogram slot;
+    # then the leaf's value. Validation rows (without early stopping
+    # there are none): three depths of feature, bin, cut, left, right
+    # and is_leaf, then the leaf's value.
+    lookups = (3 * 6 + 2 * 2 + 1) + (3 * 6 + 1) * (driver == "early_stop")
+    want = (lookups, 0) if backend == "as_tpu" else (0, lookups)
+    for profile in (first, second):
+        assert (profile["device_loop.route_select"],
+                profile["device_loop.route_gather"]) == want
+    assert second["device_loop.compile"] == 0.0
+
+
 def test_h2d_bytes_counts_what_a_job_sends():
     from ydf_tpu.ops import device_loop
 

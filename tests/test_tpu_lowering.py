@@ -85,6 +85,34 @@ def test_f32_matmul_histogram_is_one_bf16_pass(export):
     assert widths == {9, 18, 36}, widths
 
 
+@pytest.mark.parametrize("F", [28, 100])
+@pytest.mark.parametrize("export", ["grow_tree", "train_step"])
+def test_routing_has_no_row_gather(export, F):
+    """Routing looks nothing up by the row through a gather (12 to 18 ns
+    a row on the chip, PERF.md section 6, PR 29): the row's bin, its
+    slot's decision, the validation rows' nodes and the leaf's value
+    come from compare-and-select passes (ops/lookup.py). The train step
+    holds the grower, the validation route and the leaf values. A later
+    refactor that brings a gather with one index a row back fails here."""
+    n, nv = 3000, 700
+    if export == "grow_tree":
+        exp = tl.export_grow_tree(n=n, F=F, max_depth=6, hist_impl="matmul")
+    else:
+        exp = tl.export_train_step(
+            hist_impl="matmul", n=n, F=F, num_trees=2, max_depth=6, nv=nv
+        )
+    gathers = [
+        line for line in exp.mlir_module().splitlines()
+        if "stablehlo.gather" in line or "dynamic_gather" in line
+    ]
+    assert gathers, "the split search still gathers over slots and cuts"
+    by_row = [
+        line for line in gathers
+        if f"tensor<{n}x" in line or f"tensor<{nv}x" in line
+    ]
+    assert not by_row, by_row[0]
+
+
 def test_binning_kernel_lowers_to_mosaic():
     """The fused-ingestion quantile-binning kernel
     (ops/binning_pallas.py) compiles through Pallas→Mosaic for platform

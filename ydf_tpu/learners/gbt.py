@@ -43,7 +43,7 @@ from ydf_tpu.learners.generic import GenericLearner
 from ydf_tpu.learners.losses import make_loss
 from ydf_tpu.models.forest import forest_from_stacked_trees
 from ydf_tpu.models.gbt_model import GradientBoostedTreesModel
-from ydf_tpu.ops import device_loop, grower
+from ydf_tpu.ops import device_loop, grower, lookup
 from ydf_tpu.ops.routing import apply_leaf_values, route_tree_bins
 from ydf_tpu.ops.split_rules import HessianGainRule
 
@@ -1561,6 +1561,24 @@ def _make_boost_fn(
                 grow_mono_dirs = jnp.concatenate(parts)
                 grow_monotone = None
 
+            def leaf_value_of(lv, leaf):
+                """lv[leaf, 0] by the rule of the routing's look-ups
+                (ops/lookup.py): a select copies the value's bits."""
+                dense = lookup.resolve_dense()
+                if dense is False:
+                    # As it always was: the native kernels replicate
+                    # what XLA:CPU makes of this very expression
+                    # (docs/row_routing.md, the FMA contraction).
+                    lookup.count(0, 1)
+                    return lv[leaf, 0]
+                # The barrier keeps the selects out of the fusion that
+                # adds the [n, 1] predictions, whose tiles hold an
+                # eighth of a register (the TPU compiler's estimate:
+                # 748M cycles a tree at 50.4M rows fused, 92M apart).
+                return jax.lax.optimization_barrier(
+                    lookup.lookup_small(lv[:, 0], leaf, N, 0.0, dense)
+                )
+
             trees_k, leaves_k = [], []
             fused = fuse_update or fuse_grad  # K == 1, non-DART
             stats_next = None
@@ -1635,7 +1653,7 @@ def _make_boost_fn(
                         )
                     else:
                         new_contrib = new_contrib.at[:, k].set(
-                            lv[res.leaf_id, 0]
+                            leaf_value_of(lv, res.leaf_id)
                         )
                 if nv > 0:
                     with jax.named_scope("ydf.valid"):
@@ -1647,6 +1665,7 @@ def _make_boost_fn(
                             # best_f_store).
                             num_scalar=grow_num_valid,
                             impl=route_impl,
+                            num_numerical=grow_num_numerical,
                         )
                         if fused:
                             vp_col = apply_leaf_values(
@@ -1659,7 +1678,7 @@ def _make_boost_fn(
                             )
                         else:
                             new_vcontrib = new_vcontrib.at[:, k].set(
-                                lv[vleaves, 0]
+                                leaf_value_of(lv, vleaves)
                             )
                 trees_k.append(res.tree)
                 leaves_k.append(lv)

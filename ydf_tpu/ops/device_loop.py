@@ -45,7 +45,9 @@ eliminating per-iteration host round trips:
   `dispatch` makes that call the span `device_loop.compile` and
   remembers its seconds with the compiled function, so that every
   later job can say what the program it ran cost
-  (`training_profile["device_loop.program_build_s"]`).
+  (`training_profile["device_loop.program_build_s"]`) and how its
+  routing looks its tables up (`device_loop.route_select`,
+  `device_loop.route_gather`).
 
 The scan body itself (gradient recompute, per-tree quantization grid,
 routing, histogram, gain/argmax via the shared grower seams
@@ -65,6 +67,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ydf_tpu.ops import lookup
 from ydf_tpu.utils import telemetry
 from ydf_tpu.utils.profiling import StageTimer
 
@@ -161,9 +164,12 @@ def dispatch(fn, timer: StageTimer, *args, **kwargs):
     """Calls `fn`, a jitted boosting program (a `run`, or the donated
     chunk function of one), and returns what it returns. The first call
     with a signature builds the program: that call is the span
-    `device_loop.compile`, and its seconds, and whether the persistent
-    cache answered, are remembered with `fn` for as long as it lives.
-    Every call notes on `timer` which program the job ran."""
+    `device_loop.compile`, and its seconds, whether the persistent
+    cache answered, and how many of the routing's per-row look-ups were
+    traced as compare-and-select passes and how many as gathers
+    (ops/lookup.py counts them where they are traced) are remembered
+    with `fn` for as long as it lives. Every call notes on `timer`
+    which program the job ran."""
     signature = tuple(
         (x.shape, str(x.dtype)) if hasattr(x, "shape") else x
         for x in jax.tree.leaves((args, kwargs))
@@ -175,10 +181,12 @@ def dispatch(fn, timer: StageTimer, *args, **kwargs):
         hits = _cache_hits()
         hits0 = hits[0]
         spent0 = timer.seconds.get("device_loop.compile", 0.0)
+        lookups0 = lookup.counts()
         with timer.stage("device_loop.compile"):
             out = fn(*args, **kwargs)
         builds[signature] = (
-            timer.seconds["device_loop.compile"] - spent0, hits[0] > hits0
+            timer.seconds["device_loop.compile"] - spent0, hits[0] > hits0,
+            *lookup.counts(since=lookups0),
         )
     timer.programs[(id(fn), signature)] = builds[signature]
     return out

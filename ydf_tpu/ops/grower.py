@@ -39,6 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ydf_tpu.ops.histogram import histogram
+from ydf_tpu.ops import lookup
+from ydf_tpu.ops.lookup import lookup_mask_bit, lookup_small, pick_column
 
 
 class TreeArrays(NamedTuple):
@@ -487,12 +489,16 @@ def sibling_next_state(
     return parent_next, small_is_left_next, Lh_next, hmap
 
 
+# grow_tree's signature -> the look-ups its trace counted (select, gather).
+_TRACED_LOOKUPS: dict = {}
+
+
 def grow_tree(
     bins, stats, key, *, hist_impl: str = "auto",
     hist_subtract: Optional[bool] = None,
     hist_quant: Optional[str] = None,
     route_impl: str = "auto", route_fuse: Optional[bool] = None,
-    bins_t=None, **kw,
+    bins_t=None, dense_lookups="auto", **kw,
 ):
     """Thin wrapper resolving hist_impl="auto" (plus the
     sibling-subtraction, gradient-quantization and routing-impl
@@ -504,7 +510,12 @@ def grow_tree(
     FEATURE-major u8 [F, n] copy of `bins` for the fused route kernel's
     column-stream gather. Callers growing many trees over the SAME bins
     matrix should pass it (learners/gbt.py hoists the transpose out of
-    the boosting scan); when absent the grower transposes in-trace."""
+    the boosting scan); when absent the grower transposes in-trace.
+
+    `dense_lookups`: how the XLA chain's per-row look-ups run
+    (ops/lookup.py resolve_dense: "auto" is by size on a TPU and the
+    gather elsewhere; None, True and False are a test's or an export's
+    choice)."""
     from ydf_tpu.ops.histogram import (
         resolve_hist_impl,
         resolve_hist_quant,
@@ -529,16 +540,30 @@ def grow_tree(
             # The fused kernel is a CPU custom call; on TPU the XLA
             # chain is the (fused-by-XLA) path.
             route = "xla"
-    return _grow_tree_jit(
-        bins, stats, key,
+    kw.update(
         hist_impl=resolve_hist_impl(hist_impl),
         hist_subtract=resolve_hist_subtract(hist_subtract),
         hist_quant=resolve_hist_quant(hist_quant),
         route_impl=route,
         route_fuse=route_fuse,
         bins_t=bins_t if route == "native" else None,
-        **kw,
+        dense_lookups=lookup.resolve_dense(dense_lookups),
     )
+    # The look-ups are counted where they are traced (ops/lookup.py).
+    # JAX keeps this function's trace: a program that reuses it runs no
+    # line of the body, so the count is kept beside it, by the same key.
+    signature = tuple(
+        (x.shape, str(x.dtype)) if hasattr(x, "shape") else x
+        for x in jax.tree.leaves((bins, stats, key, kw))
+    ) + (jax.tree.structure(kw),)
+    before = lookup.counts()
+    out = _grow_tree_jit(bins, stats, key, **kw)
+    traced = lookup.counts(since=before)
+    if any(traced):
+        _TRACED_LOOKUPS[signature] = traced
+    else:
+        lookup.count(*_TRACED_LOOKUPS.get(signature, (0, 0)))
+    return out
 
 
 @functools.partial(
@@ -548,7 +573,7 @@ def grow_tree(
         "num_numerical", "min_examples", "min_split_gain",
         "candidate_features", "num_valid_features", "hist_impl",
         "hist_subtract", "hist_quant", "route_impl", "route_fuse",
-        "monotone",
+        "dense_lookups", "monotone",
     ),
 )
 def _grow_tree_jit(
@@ -606,6 +631,11 @@ def _grow_tree_jit(
     # layer — same bits either way, measurably different wall on hosts
     # whose LLC hides XLA's inter-pass traffic (docs/row_routing.md).
     route_fuse: bool = True,
+    # How the XLA chain's per-row look-ups run (ops/lookup.py): None
+    # chooses by each table's static size, True and False force the
+    # compare-and-select form and the gather. Resolved by the grow_tree
+    # wrapper; the forests are the same bit for bit either way.
+    dense_lookups: Optional[bool] = None,
     # Pre-transposed feature-major u8 [F, n] copy of `bins` for the
     # native route kernel (see the grow_tree wrapper docstring);
     # ignored unless route_impl == "native".
@@ -985,14 +1015,20 @@ def _grow_tree_jit(
         if Fs > 0:
             # Per-example set-split decision (shared by both routing
             # impls): not-contains (min rank beyond the cut) → LEFT.
-            is_set_e = pad(is_set_split, False)[slot]
-            fset_e = jnp.clip(pad(fset, 0)[slot], 0, Fs - 1)[:, None]
-            dir_e = pad(set_dir, False)[slot]
-            rm0 = jnp.take_along_axis(rank_min_dirs[0], fset_e, axis=1)[:, 0]
-            rm1 = jnp.take_along_axis(rank_min_dirs[1], fset_e, axis=1)[:, 0]
-            rm_e = jnp.where(dir_e, rm1, rm0)
-            t_e = pad(best_t, 0)[slot]
-            set_go_left_e = rm_e > t_e
+            with jax.named_scope("ydf.route"):
+                is_set_e = lookup_small(
+                    is_set_split, slot, Ld, False, dense_lookups
+                )
+                fset_e = jnp.clip(
+                    lookup_small(fset, slot, Ld, 0, dense_lookups),
+                    0, Fs - 1,
+                )
+                dir_e = lookup_small(set_dir, slot, Ld, False, dense_lookups)
+                rm0 = pick_column(rank_min_dirs[0], fset_e, dense_lookups)
+                rm1 = pick_column(rank_min_dirs[1], fset_e, dense_lookups)
+                rm_e = jnp.where(dir_e, rm1, rm0)
+                t_e = lookup_small(best_t, slot, Ld, 0, dense_lookups)
+                set_go_left_e = rm_e > t_e
 
         if route_impl == "native" and F > 0:
             # Native routing. The per-slot decision tables follow one
@@ -1034,33 +1070,54 @@ def _grow_tree_jit(
                 leaf_id = new_leaf
         else:
             with jax.named_scope("ydf.route"):
-                split_e = pad(do_split, False)[slot]
-                rf_e = pad(route_f, 0)[slot]
+                # Every per-row look-up is a compare-and-select pass
+                # (ops/lookup.py), a gather only past its size limit.
+                # Rows hold a slot in [0, Ld) or the retired slot L,
+                # which reads each table's pad value.
+                at_slot = lambda table, fill: lookup_small(
+                    table, slot, Ld, fill, dense_lookups
+                )
+                split_e = at_slot(do_split, False)
                 if F > 0:
-                    bin_e = jnp.take_along_axis(
-                        bins, rf_e[:, None].astype(i32), axis=1
-                    )[:, 0].astype(i32)
-                    # Flat 1-D gather — do NOT index [slot] then [bin]: that
-                    # would materialize an [n, B] intermediate.
-                    glb_flat = pad(go_left_bins, False).reshape(-1)
-                    go_left_e = glb_flat[slot * B + bin_e]
+                    bin_e = pick_column(
+                        bins, at_slot(route_f, 0), dense_lookups
+                    ).astype(i32)
+                    if Fc == 0:
+                        # go_left_bins is `bin <= best_t` and is never
+                        # indexed. (A retired row reads a cut of 0; its
+                        # side is dropped by split_e below.)
+                        go_left_e = bin_e <= at_slot(best_t, 0)
+                    else:
+                        # The slot's mask as packed words: never an
+                        # [n, B] intermediate.
+                        words = _pack_mask(
+                            jnp.pad(go_left_bins, ((0, 0), (0, -B % 32)))
+                        )
+                        go_left_e = lookup_mask_bit(
+                            words, slot, Ld, bin_e, dense_lookups
+                        )
                 else:
                     go_left_e = jnp.zeros((n,), jnp.bool_)
                 if Fs > 0:
                     go_left_e = jnp.where(is_set_e, set_go_left_e, go_left_e)
                 child_id_e = jnp.where(
-                    go_left_e, pad(left_id, N)[slot], pad(right_id, N)[slot]
+                    go_left_e, at_slot(left_id, N), at_slot(right_id, N)
                 )
                 leaf_id = jnp.where(split_e, child_id_e, leaf_id)
                 if children_in_frontier:
+                    rank_e = at_slot(split_rank, 0)
                     child_slot_e = jnp.where(
-                        go_left_e,
-                        2 * pad(split_rank, 0)[slot],
-                        2 * pad(split_rank, 0)[slot] + 1,
+                        go_left_e, 2 * rank_e, 2 * rank_e + 1
                     )
                     new_slot = jnp.where(split_e, child_slot_e, L)
+                    # Children sit below 2 * Lh_next; every other entry
+                    # of hmap, L's too, is the trash slot Lh_next.
                     hist_slot_e = (
-                        hmap[new_slot] if hmap is not None else new_slot
+                        lookup_small(
+                            hmap, new_slot, 2 * Lh_next, Lh_next,
+                            dense_lookups,
+                        )
+                        if hmap is not None else new_slot
                     )
 
         if children_in_frontier:

@@ -25,7 +25,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ydf_tpu.ops import lookup
 from ydf_tpu.ops.grower import TreeArrays, unpack_mask_bit
+from ydf_tpu.ops.lookup import lookup_mask_bit, lookup_small, pick_column
 
 i32 = jnp.int32
 
@@ -49,6 +51,8 @@ def route_tree_bins(
     x_set: Optional[jax.Array] = None,
     num_scalar: Optional[int] = None,
     impl: str = "xla",
+    num_numerical: Optional[int] = None,
+    dense_lookups="auto",
 ) -> jax.Array:
     """Leaf node id per example. tree: TreeArrays-like (single tree).
     `x_set`: packed multi-hot set features uint32 [n, Fs, W]. Set features
@@ -62,9 +66,16 @@ def route_tree_bins(
     passes `grow_num_valid`; tests/test_routing_native.py has the
     trailing-pad-columns regression).
 
+    `num_numerical` is the caller's count of numerical columns among
+    the scalar ones: where it says that no column is categorical, no
+    node's mask is fetched (None: one may be). `dense_lookups` is
+    ops/lookup.py's seam, as grow_tree takes it ("auto": each look-up by
+    its table's size on a TPU, a gather elsewhere).
+
     `impl` selects the formulation: "xla" (default — the fori_loop of
-    whole-array gathers below) or "native" (the fused one-pass tree-walk
-    kernel native/routing_ffi.cc:ydf_route_tree, bit-identical; CPU
+    whole-array look-ups below, ops/lookup.py) or "native" (the fused
+    one-pass tree-walk kernel native/routing_ffi.cc:ydf_route_tree,
+    bit-identical; CPU
     only, resolved by the caller via
     ops/routing_native.py:resolve_route_impl).
 
@@ -95,32 +106,48 @@ def route_tree_bins(
             max_depth, x_set=x_set, num_scalar=num_scalar,
         )
 
+    dense_lookups = lookup.resolve_dense(dense_lookups)
+    N = tree.feature.shape[0]
+    is_set = getattr(tree, "is_set", None)
+    has_set = is_set is not None and x_set is not None and x_set.size > 0
+    Fscalar = Fb if num_scalar is None else num_scalar
+    has_cat = num_numerical is None or num_numerical < Fscalar
+
     def body(_, node):
-        f = jnp.maximum(tree.feature[node], 0)
-        b = jnp.take_along_axis(
-            bins, jnp.clip(f, 0, Fb - 1)[:, None].astype(i32), axis=1
-        )[:, 0]
-        b = b.astype(i32)
-        go_left = jnp.where(
-            tree.is_cat[node],
-            unpack_mask_bit(tree.cat_mask[node], b),
-            b <= tree.threshold_bin[node],
+        at_node = lambda table, fill: lookup_small(
+            table, node, N, fill, dense_lookups
         )
-        is_set = getattr(tree, "is_set", None)
-        if is_set is not None and x_set is not None and x_set.size:
-            offset = Fb if num_scalar is None else num_scalar
+        f = jnp.maximum(at_node(tree.feature, 0), 0)
+        b = pick_column(
+            bins, jnp.clip(f, 0, Fb - 1), dense_lookups
+        ).astype(i32)
+        go_left = b <= at_node(tree.threshold_bin, 0)
+        if has_cat:
             go_left = jnp.where(
-                is_set[node],
-                ~_set_intersects(tree, node, x_set, f - offset),
+                at_node(tree.is_cat, False),
+                lookup_mask_bit(tree.cat_mask, node, N, b, dense_lookups),
                 go_left,
             )
-        nxt = jnp.where(go_left, tree.left[node], tree.right[node])
-        return jnp.where(tree.is_leaf[node], node, nxt)
+        if has_set:
+            go_left = jnp.where(
+                at_node(is_set, False),
+                ~_set_intersects(tree, node, x_set, f - Fscalar),
+                go_left,
+            )
+        nxt = jnp.where(
+            go_left, at_node(tree.left, 0), at_node(tree.right, 0)
+        )
+        return jnp.where(at_node(tree.is_leaf, True), node, nxt)
 
     # fori_loop (not a Python loop): the body is traced once, keeping the
     # graph size independent of depth — best-first-grown trees can be
-    # 50+ deep, which would explode an unrolled trace.
-    return jax.lax.fori_loop(0, max_depth, body, jnp.zeros((n,), i32))
+    # 50+ deep, which would explode an unrolled trace. Its look-ups run
+    # max_depth times and are counted so.
+    before = lookup.counts()
+    leaves = jax.lax.fori_loop(0, max_depth, body, jnp.zeros((n,), i32))
+    select, gather = lookup.counts(since=before)
+    lookup.count(select * (max_depth - 1), gather * (max_depth - 1))
+    return leaves
 
 
 def apply_leaf_values(

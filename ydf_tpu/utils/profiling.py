@@ -77,8 +77,9 @@ class StageTimer:
         self.seconds: Dict[str, float] = {}
         # The compiled boosting programs this call dispatched, each once:
         # program -> (seconds its build took, whenever that was; whether
-        # the persistent cache answered). ops/device_loop.dispatch fills it.
-        self.programs: Dict[object, Tuple[float, bool]] = {}
+        # the persistent cache answered; the routing look-ups its trace
+        # made as selects; as gathers). ops/device_loop.dispatch fills it.
+        self.programs: Dict[object, Tuple[float, bool, int, int]] = {}
         self._t0 = time.perf_counter_ns()
 
     @contextlib.contextmanager
@@ -100,15 +101,21 @@ class StageTimer:
         program also says what that program's build cost, whichever
         call paid it: `device_loop.program_build_s`, and
         `device_loop.program_from_cache` (1.0 where the persistent
-        compile cache answered)."""
+        compile cache answered), and how that program's routing looks
+        its small tables up: `device_loop.route_select` and
+        `device_loop.route_gather`, the per-row look-ups of its trace
+        that are compare-and-select passes and that are gathers
+        (ops/lookup.py)."""
         out = dict(self.seconds)
         out.setdefault("device_loop.compile", 0.0)
         if self.programs:
             builds = list(self.programs.values())
-            out["device_loop.program_build_s"] = sum(s for s, _ in builds)
+            out["device_loop.program_build_s"] = sum(b[0] for b in builds)
             out["device_loop.program_from_cache"] = float(
-                all(hit for _, hit in builds)
+                all(b[1] for b in builds)
             )
+            out["device_loop.route_select"] = sum(b[2] for b in builds)
+            out["device_loop.route_gather"] = sum(b[3] for b in builds)
         out["total"] = (time.perf_counter_ns() - self._t0) / 1e9
         top_level = sum(v for k, v in self.seconds.items() if "." not in k)
         out["other"] = max(out["total"] - top_level, 0.0)

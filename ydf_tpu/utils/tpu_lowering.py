@@ -93,6 +93,21 @@ def _hist_impl_env(impl: str):
             os.environ["YDF_TPU_HIST_IMPL"] = old
 
 
+@contextlib.contextmanager
+def _tpu_lookups():
+    """Traces the routing's look-ups as a TPU would for the duration of
+    a trace on a host that has none: ops/lookup.py `resolve_dense`
+    chooses by size there and keeps a CPU on the gather."""
+    from ydf_tpu.ops import lookup
+
+    old = lookup.is_tpu_backend
+    lookup.is_tpu_backend = lambda: True
+    try:
+        yield
+    finally:
+        lookup.is_tpu_backend = old
+
+
 def build_train_step(
     n: int = 500_000,
     F: int = 28,
@@ -144,7 +159,7 @@ def build_train_step(
 def export_train_step(hist_impl: str = "matmul", platforms=("tpu",), **kw):
     """jax.export of the full boosting loop for `platforms`."""
     run, args = build_train_step(**kw)
-    with _hist_impl_env(hist_impl):
+    with _hist_impl_env(hist_impl), _tpu_lookups():
         return jax.export.export(run, platforms=tuple(platforms))(*args)
 
 
@@ -168,12 +183,13 @@ def export_grow_tree(
     def one_tree(bins, stats, key):
         # route_impl pinned to the XLA chain: the native fused route is a
         # CPU custom call (the ambient default since the many-core round),
-        # which cannot serialize into a TPU export.
+        # which cannot serialize into a TPU export. Its look-ups go by
+        # size, as on a TPU (dense_lookups=None; "auto" asks the host).
         return grow_tree(
             bins, stats, key,
             rule=rule, max_depth=max_depth, frontier=cfg.frontier,
             max_nodes=cfg.max_nodes, num_bins=num_bins, num_numerical=F,
-            hist_impl=hist_impl, route_impl="xla",
+            hist_impl=hist_impl, route_impl="xla", dense_lookups=None,
         )
 
     args = (
@@ -413,7 +429,7 @@ def grow_tree_cost(
             bins, stats, key,
             rule=rule, max_depth=max_depth, frontier=cfg.frontier,
             max_nodes=cfg.max_nodes, num_bins=num_bins, num_numerical=F,
-            hist_impl=hist_impl, route_impl="xla",
+            hist_impl=hist_impl, route_impl="xla", dense_lookups=None,
         )
 
     lowered = jax.jit(one_tree).lower(
