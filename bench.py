@@ -296,65 +296,6 @@ def measure_in_loop_hist(train, record):
         shutil.rmtree(td, ignore_errors=True)
 
 
-def measure_device_loop_family(train, trees, record):
-    """Paired A/B for the device-resident boosting loop (ISSUE 18
-    tentpole, measurement half): the SAME data/learner trained with
-    YDF_TPU_TREES_PER_DISPATCH=1 (per-tree dispatch baseline — the
-    pre-round-20 host-driven loop) vs trees-per-dispatch=min(25, trees)
-    (the donated-carry multi-tree scan). Per variant: one train to
-    compile the chunked driver at that static chunk length, then a
-    stats-bracketed steady train. On the CPU XLA backend the wall gap
-    is pure per-tree Python+dispatch overhead — the quantity the
-    device loop removes; `dispatch_reduction` is the acceptance
-    number (target >= 10x). Never fatal; the env knob is restored
-    even on failure. Skipped for trees < 2 (no pairing possible)."""
-    if trees < 2:
-        return
-    from ydf_tpu.ops import device_loop
-
-    prev = os.environ.get("YDF_TPU_TREES_PER_DISPATCH")
-    try:
-        ab = {}
-        for name, tpd in (
-            ("per_tree", 1),
-            ("device_loop", min(25, trees)),
-        ):
-            os.environ["YDF_TPU_TREES_PER_DISPATCH"] = str(tpd)
-            train()  # compile at this static chunk length
-            device_loop.reset_stats()
-            _, wall, _ = train()
-            snap = device_loop.stats_snapshot()
-            ab[name] = {
-                "trees_per_dispatch": tpd,
-                "dispatches": snap["dispatches"],
-                "dispatches_per_tree": snap["dispatches_per_tree"],
-                "host_sync_bytes_per_tree": snap[
-                    "host_sync_bytes_per_tree"
-                ],
-                "train_wall_s": round(wall, 3),
-            }
-        a, b = ab["per_tree"], ab["device_loop"]
-        if b["dispatches_per_tree"] > 0:
-            ab["dispatch_reduction"] = round(
-                a["dispatches_per_tree"] / b["dispatches_per_tree"], 1
-            )
-        # Host-loop overhead the multi-tree scan removed, per tree —
-        # on the CPU XLA backend both variants run identical math, so
-        # the wall delta is dispatch + carry-shuffling cost.
-        ab["per_tree_overhead_removed_s"] = round(
-            (a["train_wall_s"] - b["train_wall_s"]) / trees, 4
-        )
-        record["device_loop_ab"] = ab
-    except Exception as e:
-        record["device_loop_ab_error"] = f"{type(e).__name__}: {e}"
-    finally:
-        if prev is None:
-            os.environ.pop("YDF_TPU_TREES_PER_DISPATCH", None)
-        else:
-            os.environ["YDF_TPU_TREES_PER_DISPATCH"] = prev
-        device_loop.reset_stats()
-
-
 def measure_hist_attribution(rows, features, depth, trees, record):
     """Same-shape per-layer histogram wall OUTSIDE the fused scan,
     emitted as `hist_attrib_s` (sibling-subtraction slot counts — what
@@ -1676,14 +1617,9 @@ def run_bench(rows, trees, depth, features, with_baseline):
         "train_peak_rss_bytes": train_peak_rss,
         # Device-resident loop accounting (ops/device_loop.py window
         # around the steady train): XLA dispatches and host-materialized
-        # bytes per boosting tree. `device_loop` is the ACTIVE
-        # trees-per-dispatch override (YDF_TPU_TREES_PER_DISPATCH; 0 =
-        # unset, the driver's own chunking) — a SHAPE field in
-        # bench_diff so knob-driven runs never pair against default
-        # ones.
+        # bytes per boosting tree.
         "dispatches_per_tree": dl_snap["dispatches_per_tree"],
         "host_sync_bytes_per_tree": dl_snap["host_sync_bytes_per_tree"],
-        "device_loop": device_loop.trees_per_dispatch(0),
         "vs_ydf64_estimate": round(
             value / BASELINE_YDF64_ESTIMATE_ROWS_TREES_PER_SEC, 3
         ),
@@ -1702,10 +1638,6 @@ def run_bench(rows, trees, depth, features, with_baseline):
     # records, where this field was named hist_s).
     measure_in_loop_hist(train, record)
     measure_hist_attribution(rows, features, depth, trees, record)
-    # Device-loop A/B (dispatches-per-tree reduction + host-loop
-    # overhead removed) — paired per-tree vs multi-tree-scan trains on
-    # the same Dataset.
-    measure_device_loop_family(train, trees, record)
     try:
         # Batched inference throughput on the same model (reference
         # benchmark_inference.cc's ns/example) — any backend; reuses the

@@ -73,20 +73,12 @@ from typing import Dict, List, Optional, Tuple
 #: and with explicit single-threaded rounds.
 THREAD_SHAPE_FIELDS = ("hist_threads", "bin_threads", "route_threads",
                        "serve_threads")
-#: `device_loop` is the active YDF_TPU_TREES_PER_DISPATCH override on
-#: the record (bench.py headline; 0 = knob unset, the driver's own
-#: chunking). It is a SHAPE field because a knob-forced chunking
-#: changes what dispatches_per_tree / train_wall_s mean — a tpd=1
-#: per-tree-baseline record must never pair against a default or
-#: tpd=25 one. DEFAULTS TO 0 when absent so every historical record
-#: (all measured before the knob existed, i.e. knob unset) keeps
-#: pairing with new default-driver records. `fleet_elastic` rides the
-#: same default-0 discipline: an elastic fleet record (the closed loop
-#: spans a live add_replica/remove_replica — YDF_TPU_BENCH_FLEET_ELASTIC)
-#: must never pair with a static one (the scale ops perturb the run's
-#: tail and capacity), and every historical fleet record predates the
-#: mode, i.e. was static.
-LOOP_SHAPE_FIELDS = ("device_loop", "fleet_elastic")
+#: `fleet_elastic` DEFAULTS TO 0 when absent: an elastic fleet record
+#: (the closed loop spans a live add_replica/remove_replica —
+#: YDF_TPU_BENCH_FLEET_ELASTIC) must never pair with a static one (the
+#: scale ops perturb the run's tail and capacity), and every historical
+#: fleet record predates the mode, i.e. was static.
+LOOP_SHAPE_FIELDS = ("fleet_elastic",)
 SHAPE_FIELDS = ("metric", "backend", "rows", "trees", "depth",
                 "dist_mode", "load_mode",
                 "fleet_replicas") + THREAD_SHAPE_FIELDS \
@@ -272,8 +264,8 @@ def shape_key(rec: dict) -> Tuple:
 
 
 def shape_str(key: Tuple) -> str:
-    # Thread caps at their default (1) and the dispatch-chunk knob at
-    # its default (0 = unset) stay out of the label: every historical
+    # Thread caps at their default (1) and the elastic-fleet mode at
+    # its default (0 = static) stay out of the label: every historical
     # record would otherwise carry the noise terms.
     return ", ".join(
         f"{name}={val}" for name, val in zip(SHAPE_FIELDS, key)

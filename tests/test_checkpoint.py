@@ -133,9 +133,10 @@ def test_inloop_early_stopping_without_working_dir():
 
 
 def test_inloop_early_stop_matches_full_run():
-    """The chunked in-memory path is bit-identical to the single-scan run
-    truncated at the same validation-loss argmin (chunk boundaries must be
-    invisible: RNG keys derive from absolute iteration indices)."""
+    """A train that stops between chunks is bit-identical to one chunk of
+    all the trees truncated at the same validation-loss argmin (chunk
+    boundaries must be invisible: RNG keys derive from absolute iteration
+    indices)."""
     rng = np.random.RandomState(5)
     n = 600
     x = rng.normal(size=n)

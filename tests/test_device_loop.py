@@ -1,10 +1,12 @@
 """Device-resident boosting loop (ops/device_loop.py): multi-tree
-donated-carry dispatch must be INVISIBLE in the results — any
-YDF_TPU_TREES_PER_DISPATCH chunking produces the same model arrays and
-per-iteration losses as the single fused scan, early stopping fires at
-the same iteration, snapshot/resume at a chunk boundary is
-bit-identical — while the host-sync accounting counts what the driver
-actually dispatched (docs/device_loop.md)."""
+donated-carry dispatch must be INVISIBLE in the results — any chunk
+length (forced here through the snapshot interval under a working_dir)
+produces the same model arrays and per-iteration losses as one dispatch
+of all the trees, early stopping fires at the same iteration,
+snapshot/resume at a chunk boundary is bit-identical — while the
+host-sync accounting counts what the loop actually dispatched, and the
+chunk length follows from what can end the loop
+(docs/device_loop.md)."""
 
 import numpy as np
 import pytest
@@ -29,17 +31,15 @@ def _data(n=900, seed=3, nan_cat=False):
     return d
 
 
-def _train(data, tpd, monkeypatch, **kw):
-    if tpd is None:
-        monkeypatch.delenv("YDF_TPU_TREES_PER_DISPATCH", raising=False)
-    else:
-        monkeypatch.setenv("YDF_TPU_TREES_PER_DISPATCH", str(tpd))
-    try:
-        return ydf.GradientBoostedTreesLearner(label="y", **kw).train(
-            data
+def _train(data, chunk, tmp_path, **kw):
+    """Trains in chunks of `chunk` trees (None: as the learner decides,
+    which for _KW is one dispatch of all the trees)."""
+    if chunk is not None:
+        kw = dict(
+            kw, working_dir=str(tmp_path / f"chunks_of_{chunk}"),
+            resume_training_snapshot_interval_trees=chunk,
         )
-    finally:
-        monkeypatch.delenv("YDF_TPU_TREES_PER_DISPATCH", raising=False)
+    return ydf.GradientBoostedTreesLearner(label="y", **kw).train(data)
 
 
 def _assert_identical(a, b, data):
@@ -57,39 +57,39 @@ _KW = dict(num_trees=11, max_depth=3, random_seed=7,
 
 
 @pytest.mark.parametrize("quant", ["f32", "bf16x2", "int8"])
-def test_chunked_equals_single_scan_per_quant(quant, monkeypatch):
-    """Single fused scan (knob unset) vs per-tree dispatch (tpd=1) vs
-    a chunk length that does not divide num_trees (tpd=4 on 11 trees):
-    model arrays AND per-iteration losses bit-identical in every
+def test_chunked_equals_single_scan_per_quant(quant, monkeypatch, tmp_path):
+    """One dispatch of all the trees vs a dispatch per tree vs a chunk
+    length that does not divide num_trees (4 on 11 trees): model arrays
+    AND per-iteration losses bit-identical in every
     gradient-quantization mode."""
     monkeypatch.setenv("YDF_TPU_HIST_QUANT", quant)
     data = _data()
-    base = _train(data, None, monkeypatch, **_KW)
-    per_tree = _train(data, 1, monkeypatch, **_KW)
-    chunked = _train(data, 4, monkeypatch, **_KW)
+    base = _train(data, None, tmp_path, **_KW)
+    per_tree = _train(data, 1, tmp_path, **_KW)
+    chunked = _train(data, 4, tmp_path, **_KW)
     _assert_identical(base, per_tree, data)
     _assert_identical(base, chunked, data)
 
 
-def test_chunked_equals_single_scan_sampling(monkeypatch):
+def test_chunked_equals_single_scan_sampling(tmp_path):
     """Row subsampling + feature sampling draw from the carried PRNG
     key; per-iteration randomness folds the ABSOLUTE iteration index,
     so chunk boundaries must not move any draw."""
     data = _data(seed=5)
     kw = dict(_KW, subsample=0.7, num_candidate_attributes=1)
-    base = _train(data, None, monkeypatch, **kw)
-    chunked = _train(data, 3, monkeypatch, **kw)
+    base = _train(data, None, tmp_path, **kw)
+    chunked = _train(data, 3, tmp_path, **kw)
     _assert_identical(base, chunked, data)
 
 
-def test_chunked_equals_single_scan_nan_categorical(monkeypatch):
+def test_chunked_equals_single_scan_nan_categorical(tmp_path):
     data = _data(seed=6, nan_cat=True)
-    base = _train(data, None, monkeypatch, **_KW)
-    chunked = _train(data, 5, monkeypatch, **_KW)
+    base = _train(data, None, tmp_path, **_KW)
+    chunked = _train(data, 5, tmp_path, **_KW)
     _assert_identical(base, chunked, data)
 
 
-def test_early_stop_same_iteration(monkeypatch):
+def test_early_stop_same_iteration(tmp_path):
     """In-loop early stopping is decided from the per-iteration
     validation losses — identical across chunkings — so every chunk
     length keeps the SAME trees, whatever boundary the driver noticed
@@ -102,8 +102,8 @@ def test_early_stop_same_iteration(monkeypatch):
     kw = dict(num_trees=80, max_depth=3, random_seed=7,
               early_stopping="LOSS_INCREASE",
               early_stopping_num_trees_look_ahead=10)
-    a = _train(data, 1, monkeypatch, **kw)
-    b = _train(data, 7, monkeypatch, **kw)
+    a = _train(data, 1, tmp_path, **kw)
+    b = _train(data, 7, tmp_path, **kw)
     assert a.training_logs["num_trees"] < 80  # it actually stopped
     assert a.training_logs["num_trees"] == b.training_logs["num_trees"]
     assert a.num_trees() == b.num_trees()
@@ -115,16 +115,15 @@ def test_early_stop_same_iteration(monkeypatch):
     np.testing.assert_array_equal(a.predict(data), b.predict(data))
 
 
-def test_snapshot_resume_at_chunk_boundary(monkeypatch, tmp_path):
-    """Preemption at a fused-chunk boundary: kill after one 5-tree
-    dispatch, resume, and the final model is bit-identical to the
-    uninterrupted single-scan train (donated carries never leak into
-    the snapshot — it serializes the NEW carry)."""
+def test_snapshot_resume_at_chunk_boundary(tmp_path):
+    """Preemption at a chunk boundary: kill after one 5-tree dispatch,
+    resume, and the final model is bit-identical to the uninterrupted
+    one-dispatch train (donated carries never leak into the snapshot —
+    it serializes the NEW carry)."""
     data = _data()
     kw = dict(label="y", num_trees=12, max_depth=3, random_seed=7)
     base = ydf.GradientBoostedTreesLearner(**kw).train(data)
 
-    monkeypatch.setenv("YDF_TPU_TREES_PER_DISPATCH", "5")
     learner = ydf.GradientBoostedTreesLearner(
         working_dir=str(tmp_path),
         resume_training_snapshot_interval_trees=5, **kw,
@@ -172,13 +171,13 @@ def test_chunk_fn_cached_across_chunk_lengths():
     assert fn._cache_size() == 2
 
 
-def test_stats_accounting(monkeypatch):
+def test_stats_accounting(tmp_path):
     """12 trees at 5 trees/dispatch = dispatches at starts 0/5/10 (the
     tail overshoots by design — one executable serves every chunk);
     host-sync bytes count the per-chunk output fetches."""
     data = _data()
     device_loop.reset_stats()
-    _train(data, 5, monkeypatch, num_trees=12, max_depth=3,
+    _train(data, 5, tmp_path, num_trees=12, max_depth=3,
            random_seed=7, validation_ratio=0.0, early_stopping="NONE")
     snap = device_loop.stats_snapshot()
     assert snap["dispatches"] == 3
@@ -190,13 +189,76 @@ def test_stats_accounting(monkeypatch):
     assert device_loop.stats_snapshot()["dispatches"] == 0
 
 
-def test_env_validation(monkeypatch):
-    monkeypatch.setenv("YDF_TPU_TREES_PER_DISPATCH", "zero")
-    with pytest.raises(ValueError, match="YDF_TPU_TREES_PER_DISPATCH"):
-        device_loop.trees_per_dispatch(None)
-    monkeypatch.setenv("YDF_TPU_TREES_PER_DISPATCH", "0")
-    with pytest.raises(ValueError, match="YDF_TPU_TREES_PER_DISPATCH"):
-        device_loop.trees_per_dispatch(None)
-    monkeypatch.delenv("YDF_TPU_TREES_PER_DISPATCH", raising=False)
-    assert device_loop.trees_per_dispatch(None) is None
-    assert device_loop.trees_per_dispatch(25) == 25
+def test_one_dispatch_when_nothing_can_stop():
+    """The benchmark cells' shape of call: 4 trees, 10 % validation
+    rows, look-ahead 30. Nothing can end the loop before its last tree,
+    so it is one dispatch of 4 trees, and the job's profile has every
+    key the benchmark's metrics and its `[job]` line read."""
+    device_loop.reset_stats()
+    model = ydf.GradientBoostedTreesLearner(
+        label="y", num_trees=4, max_depth=3, random_seed=7,
+        validation_ratio=0.1, early_stopping="LOSS_INCREASE",
+        early_stopping_num_trees_look_ahead=30,
+    ).train(_data())
+    snap = device_loop.stats_snapshot()
+    assert snap["dispatches"] == 1
+    assert snap["device_loop"] == 4
+    assert snap["dispatches_per_tree"] == 0.25
+    for key in ("device_loop.init", "device_loop.dispatch",
+                "device_loop.wait", "device_loop.fetch",
+                "device_loop.merge", "device_loop.program_build_s",
+                "device_loop.route_select", "device_loop.route_gather"):
+        assert key in model.training_profile, key
+
+
+@pytest.mark.parametrize(
+    "case, kw, dispatches, chunk",
+    [
+        # Under a working_dir: the snapshot interval.
+        ("snapshot_interval",
+         dict(num_trees=12, resume_training_snapshot_interval_trees=5,
+              validation_ratio=0.0, early_stopping="NONE"), 3, 5),
+        # Early stopping that can fire (num_trees > look-ahead): the
+        # look-ahead window ...
+        ("lookahead",
+         dict(num_trees=20, validation_ratio=0.2,
+              early_stopping="LOSS_INCREASE",
+              early_stopping_num_trees_look_ahead=8), 3, 8),
+        # ... at most 25 trees,
+        ("lookahead_capped",
+         dict(num_trees=60, validation_ratio=0.2,
+              early_stopping="LOSS_INCREASE",
+              early_stopping_num_trees_look_ahead=40), 3, 25),
+        # and 25 trees under a deadline.
+        ("deadline",
+         dict(num_trees=30, validation_ratio=0.0, early_stopping="NONE",
+              maximum_training_duration=3600.0), 2, 25),
+        # Nothing can stop the loop: all the trees.
+        ("all_trees",
+         dict(num_trees=30, validation_ratio=0.0, early_stopping="NONE"),
+         1, 30),
+        # A look-ahead the loop cannot outlive stops nothing.
+        ("lookahead_not_outlived",
+         dict(num_trees=12, validation_ratio=0.2,
+              early_stopping="LOSS_INCREASE",
+              early_stopping_num_trees_look_ahead=12), 1, 12),
+    ],
+)
+def test_chunk_length_rule(case, kw, dispatches, chunk, tmp_path):
+    """The chunk length follows from what can end the loop
+    (learners/gbt.py:_trees_per_chunk); no train here stops early, so
+    the dispatch count is ceil(num_trees / chunk)."""
+    if case == "snapshot_interval":
+        kw = dict(kw, working_dir=str(tmp_path))
+    # x2 decides the label: the validation loss falls with every tree.
+    rng = np.random.RandomState(11)
+    x1, x2 = rng.normal(size=600), rng.normal(size=600)
+    data = {"x1": x1, "x2": x2, "y": (x2 > 0).astype(np.int64)}
+    device_loop.reset_stats()
+    model = ydf.GradientBoostedTreesLearner(
+        label="y", max_depth=2, shrinkage=0.02, random_seed=7, **kw
+    ).train(data)
+    snap = device_loop.stats_snapshot()
+    assert model.training_logs["num_trees_trained"] == kw["num_trees"]
+    assert snap["device_loop"] == chunk
+    assert snap["dispatches"] == dispatches

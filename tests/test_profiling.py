@@ -50,15 +50,10 @@ def _regression_learner(**kw):
         label="y", task=Task.REGRESSION, **kw)
 
 
-# The spans each GBT driver opens beside the ones every driver has.
-_EVERY_DRIVER = {"ingest_bin", "split", "device_loop", "device_loop.h2d",
-                 "device_loop.init", "device_loop.dispatch",
-                 "device_loop.compile", "device_loop.wait", "finalize"}
-_CHUNKED = {"device_loop.fetch", "device_loop.merge"}
-
-
 @pytest.mark.parametrize("driver", ["single_scan", "early_stop", "checkpointed"])
 def test_training_profile_holds_the_drivers_spans(driver, tmp_path):
+    """Every job has every span of TRAIN_SPANS, whether the one boosting
+    loop runs it as one chunk, as several, or with snapshots."""
     from ydf_tpu.utils.profiling import TRAIN_SPANS
 
     kw = {"single_scan": {},
@@ -67,10 +62,8 @@ def test_training_profile_holds_the_drivers_spans(driver, tmp_path):
           "checkpointed": {"working_dir": str(tmp_path),
                            "resume_training_snapshot_interval_trees": 2}}
     p = _regression_learner(**kw[driver]).train(_regression_data()).training_profile
-    spans = {name[len("ydf."):] for name in TRAIN_SPANS}
-    expected = _EVERY_DRIVER | (_CHUNKED if driver != "single_scan" else set())
-    assert expected <= spans
-    assert {k for k in p if k in spans} == expected, p
+    expected = {name[len("ydf."):] for name in TRAIN_SPANS}
+    assert expected <= set(p), p
     assert all(p[k] >= 0 for k in expected)
     # A nested span lies inside its parent; `device_loop.compile`, the
     # program's build, lies inside `.dispatch` and is not summed twice.

@@ -205,9 +205,6 @@ def resolved_env_config() -> dict:
 
     put("YDF_TPU_ROUTE_IMPL", lambda: _route().resolve_route_impl(None))
     put("YDF_TPU_ROUTE_FUSE", lambda: _route().resolve_route_fuse())
-    put("YDF_TPU_TREES_PER_DISPATCH", lambda: __import__(
-        "ydf_tpu.ops.device_loop",
-        fromlist=["trees_per_dispatch"]).trees_per_dispatch(None))
     put("YDF_TPU_ROUTE_THREADS",
         lambda: _route().resolved_route_threads())
     put("YDF_TPU_POOL_STATS", lambda: __import__(

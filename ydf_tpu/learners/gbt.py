@@ -30,7 +30,7 @@ import os
 import signal
 import threading
 import time
-from typing import Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -804,10 +804,6 @@ class GradientBoostedTreesLearner(GenericLearner):
             vs_tr = vs_va = None
         vs_Pv = (vs_Ac + vs_Ap) * binner.num_vs if vs_tr is not None else 0
 
-        # _flight_guard covers EVERY boosting driver (the in-memory
-        # single-scan and early-stop drivers used to run unguarded — an
-        # OOM there died without a flight-recorder post-mortem; the
-        # checkpointed/distributed drivers keep their inner guards).
         with timer.stage("device_loop"), _flight_guard():
             if self.distributed_workers:
                 # Feature-parallel manager–worker training: the bins
@@ -1115,6 +1111,17 @@ def _split_rows(dataset, bins_all, rng, seed, ratio):
     return rows
 
 
+class _BoostFns(NamedTuple):
+    """The jitted programs of one boosting configuration
+    (`_make_boost_fn`): `init_state(y_tr, w_tr)` gives the first carry
+    and the initial predictions, `run_chunk(carry, start, chunk_len,
+    *data)` grows trees [start, start + chunk_len)."""
+
+    init_state: Callable
+    run_chunk: Callable
+    use_dart: bool
+
+
 @functools.lru_cache(maxsize=16)
 def _make_boost_fn(
     loss_obj, rule, tree_cfg: TreeConfig, num_trees, shrinkage, subsample,
@@ -1125,7 +1132,8 @@ def _make_boost_fn(
     oblique_mode="SPARSE", mhld_max_attributes=4, num_label_classes=1,
     monotone=None, vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True,
 ):
-    """Builds (and caches) the jitted boosting loop for one static config.
+    """Builds (and caches) the jitted boosting loop for one static config,
+    as a `_BoostFns`.
 
     Caching the closure is what makes jax.jit's own cache effective across
     `train()` calls: a fresh closure per call would retrace + recompile the
@@ -1748,25 +1756,6 @@ def _make_boost_fn(
     def init_state(y_tr, w_tr):
         return _init(y_tr, w_tr)
 
-    @jax.jit
-    def run(bins_tr, y_tr, w_tr, bins_va, y_va, w_va,
-            x_tr_raw=None, x_va_raw=None, set_tr=None, set_va=None,
-            vs_tr=None, vs_va=None):
-        carry0, init_pred = _init(y_tr, w_tr)
-        step = _make_step(
-            bins_tr, y_tr, w_tr, bins_va, y_va, w_va, x_tr_raw, x_va_raw,
-            set_tr, set_va, vs_tr, vs_va,
-        )
-        carry_end, (trees, lvs, tls, vls, obl_ws, obl_bs, vs_as, vs_bs) = (
-            jax.lax.scan(step, carry0, jnp.arange(num_trees))
-        )
-        if use_dart:
-            # Bake each iteration's final DART weight into its stored leaf
-            # values so serving needs no extra state. lvs: [T, K, N, 1].
-            tree_scale = carry_end[5]
-            lvs = lvs * tree_scale[:, None, None, None]
-        return trees, lvs, tls, vls, init_pred, obl_ws, obl_bs, vs_as, vs_bs
-
     @functools.partial(jax.jit, static_argnames=("chunk_len",))
     def run_chunk(carry, start, chunk_len, bins_tr, y_tr, w_tr,
                   bins_va, y_va, w_va, x_tr_raw=None, x_va_raw=None,
@@ -1774,7 +1763,7 @@ def _make_boost_fn(
         """One checkpointable slice of the boosting loop: iterations
         [start, start + chunk_len). Chunking is invisible to the result —
         the per-iteration RNG folds the iteration index into the carried
-        key, so any chunk boundary reproduces the single-scan run."""
+        key, so every chunk boundary gives the same forest."""
         step = _make_step(
             bins_tr, y_tr, w_tr, bins_va, y_va, w_va, x_tr_raw, x_va_raw,
             set_tr, set_va, vs_tr, vs_va,
@@ -1783,18 +1772,15 @@ def _make_boost_fn(
             step, carry, start + jnp.arange(chunk_len)
         )
 
-    run.init_state = init_state
-    run.run_chunk = run_chunk
-    run.use_dart = use_dart
-    return run
+    return _BoostFns(init_state, run_chunk, use_dart)
 
 
 def _note_chunk(
     chunk_walls, start, clen, num_trees, t0_ns, chunk_arrays, nv_rows
 ):
-    """Per-chunk bookkeeping shared by the three boosting drivers:
-    records the chunk's host wall (the attribution source for the
-    per-iteration training logs and the `train.chunk` telemetry span),
+    """Per-chunk bookkeeping of the boosting loop: records the chunk's
+    host wall (the attribution source for the per-iteration training
+    logs and the `train.chunk` telemetry span),
     feeds the training metrics, and emits the per-chunk progress line
     at debug level (the reference manager's per-stage Monitoring log,
     distributed_gradient_boosted_trees.cc:832-836)."""
@@ -1864,8 +1850,8 @@ def _chunk_len(clen: int, start: int, num_trees: int, use_dart: bool) -> int:
 
 
 def _chunk_arrays_from_ys(ys, timer) -> dict:
-    """run_chunk outputs → the flat dict layout shared by the in-memory
-    early-stop path and the on-disk snapshot payloads."""
+    """run_chunk outputs → the flat dict of host arrays that the loop
+    keeps, in memory or as a chunk's payload file."""
     with timer.stage("device_loop.wait"):
         # The fetch below would block on the chunk anyway: waiting here
         # tells the host's wait for the device from the copies.
@@ -1880,7 +1866,7 @@ def _chunk_arrays_from_ys(ys, timer) -> dict:
         d["ob"] = np.asarray(ob_c)
         d["vsa"] = np.asarray(va_c)
         d["vsb"] = np.asarray(vb_c)
-    # This materialization is THE host-sync point of the chunked drivers:
+    # This materialization is THE host-sync point of the boosting loop:
     # everything else (carry, bin matrix, labels) stays device-resident.
     device_loop.count_host_sync(sum(a.nbytes for a in d.values()))
     return d
@@ -1898,8 +1884,8 @@ def _early_stop_hit(vls_seen, done: int, lookahead: int) -> bool:
 
 def _merge_chunk_parts(parts, num_trees, use_dart, carry):
     """Concatenates per-chunk payload dicts and slices off the tail
-    overshoot. Bakes final DART weights (the single-scan path does this
-    in-jit)."""
+    overshoot, on the host. Bakes each iteration's final DART weight into
+    its stored leaf values, so serving needs no extra state."""
     from ydf_tpu.ops.grower import TreeArrays
 
     n_tree_fields = sum(1 for k in parts[0] if k.startswith("trees_"))
@@ -1925,8 +1911,141 @@ def _merge_chunk_parts(parts, num_trees, use_dart, carry):
     if use_dart:
         tree_scale = np.asarray(jax.tree.leaves(carry)[5])
         lvs = lvs * tree_scale[: lvs.shape[0], None, None, None]
-    trees = TreeArrays(*[jnp.asarray(a) for a in trees_np])
-    return trees, jnp.asarray(lvs), tls, vls, obl_w, obl_b, vs_a, vs_b
+    return TreeArrays(*trees_np), lvs, tls, vls, obl_w, obl_b, vs_a, vs_b
+
+
+def _trees_per_chunk(
+    num_trees, *, snapshot_interval, early_stop_lookahead, deadline
+) -> int:
+    """How many trees one dispatch of the boosting loop grows: the
+    snapshot interval under a working_dir (None without one); the
+    early-stop look-ahead window (0: no early stopping), at most 25
+    trees, when the loop can stop between chunks; else all of them, one
+    dispatch. Never more than `num_trees`."""
+    if snapshot_interval is not None:
+        clen = snapshot_interval
+    elif deadline is not None or 0 < early_stop_lookahead < num_trees:
+        # (Early stopping can only ever fire when the loop outlives the
+        # look-ahead window.)
+        clen = min(early_stop_lookahead or 25, 25)
+    else:
+        clen = num_trees
+    return max(1, min(clen, num_trees))
+
+
+class _MemoryParts:
+    """Where a train() without a working_dir keeps its chunks' arrays:
+    a list. Nothing to restore, nothing to preempt."""
+
+    def __init__(self):
+        self._parts = []
+
+    def restore(self):
+        return None
+
+    def guard(self):
+        return contextlib.nullcontext()
+
+    def add(self, start, clen, part, carry, init_pred):
+        self._parts.append(part)
+
+    def parts(self):
+        return self._parts
+
+
+class _SnapshotParts:
+    """Where a train() under a working_dir keeps them: each chunk's
+    arrays in a payload file of its own (kept until training finishes,
+    so I/O stays linear in the tree count), and a snapshot that records
+    the carry and which chunks are done. The snapshot carries the
+    fingerprint of the config and data, so a resume against a different
+    dataset or hyperparameters fails fast instead of silently mixing
+    trees. (Reference CreateSnapshot / TryLoadSnapshotFromDisk,
+    gradient_boosted_trees.cc:345-427; index protocol utils/snapshot.h.)"""
+
+    def __init__(self, cache_dir, fingerprint, resume, timer):
+        from ydf_tpu.utils.snapshot import Snapshots
+
+        self._dir = cache_dir
+        self._fingerprint = fingerprint
+        self._resume = resume
+        self._timer = timer
+        self._snaps = Snapshots(cache_dir, max_kept=2)
+        self._starts = []  # carried across interrupted runs via the snapshot
+
+    def _chunk_path(self, start_it: int) -> str:
+        return os.path.join(self._dir, f"chunk_{start_it}.npz")
+
+    def restore(self):
+        """(iterations done, carry, initial predictions, their validation
+        losses) of the latest snapshot, or None to start afresh."""
+        state = self._snaps.latest() if self._resume else None
+        if state is None:
+            return None
+        _, arrays, meta = state
+        if meta.get("fingerprint") != self._fingerprint:
+            raise ValueError(
+                f"Snapshot in {self._dir!r} was created with different "
+                "data or hyperparameters; refusing to resume. Delete the "
+                "directory or disable resume_training."
+            )
+        carry = tuple(
+            jnp.asarray(arrays[f"carry_{i}"])
+            for i in range(meta["num_carry"])
+        )
+        self._starts = list(meta.get("chunk_starts", []))
+        # The validation-loss history of the completed chunks, so early
+        # stopping after a resume sees the true global minimum.
+        vls_seen = []
+        for st in self._starts:
+            try:
+                with np.load(self._chunk_path(st)) as z:
+                    vls_seen.append(np.asarray(z["vls"]))
+            except Exception:
+                pass
+        return (
+            meta["completed_iters"], carry, jnp.asarray(arrays["init_pred"]),
+            vls_seen,
+        )
+
+    def guard(self):
+        return _PreemptionGuard()
+
+    def add(self, start, clen, part, carry, init_pred):
+        from ydf_tpu.utils.snapshot import _durable_replace
+
+        tmp = self._chunk_path(start) + ".tmp"
+        with open(tmp, "wb") as f:
+            np.savez(f, **part)
+        # Durable before the snapshot that references it: the final
+        # merge reads chunk payloads back after a crash, so a torn
+        # chunk behind a durable snapshot would be unrecoverable.
+        _durable_replace(tmp, self._chunk_path(start))
+        with self._timer.stage("device_loop.fetch"):
+            arrays = {"init_pred": np.asarray(init_pred)}
+            for i, leaf in enumerate(jax.tree.leaves(carry)):
+                arrays[f"carry_{i}"] = np.asarray(leaf)
+        # The snapshot's copy of the carry is this store's host-sync
+        # point on top of the chunk payload fetch.
+        device_loop.count_host_sync(sum(a.nbytes for a in arrays.values()))
+        self._starts.append(start)
+        self._snaps.save(
+            start + clen,
+            arrays,
+            meta={
+                "completed_iters": start + clen,
+                "num_carry": len(jax.tree.leaves(carry)),
+                "fingerprint": self._fingerprint,
+                "chunk_starts": self._starts,
+            },
+        )
+
+    def parts(self):
+        out = []
+        for st in self._starts:
+            with np.load(self._chunk_path(st)) as z:
+                out.append({k: z[k] for k in z.files})
+        return out
 
 
 def _train_gbt(
@@ -1945,12 +2064,18 @@ def _train_gbt(
     abort_after_chunks=None, preempt_after_chunks=None,
     early_stop_lookahead=0, deadline=None, timer,
 ):
-    """The jitted boosting loop. Returns stacked trees [T, K, ...], leaf
-    values [T, K, N, 1] and per-iteration logs; `timer` is the calling
-    train()'s StageTimer (the `device_loop.*` spans). `deadline` is an
-    absolute time.monotonic() value: the chunked drivers stop within one
-    chunk of it and return the iterations finished so far (reference GBT
-    deadline check, gradient_boosted_trees.cc:1314-1325)."""
+    """The boosting loop: chunks of trees, each one dispatch of the
+    jitted scan with the carry donated. Returns stacked trees
+    [T, K, ...], leaf values [T, K, N, 1] and per-iteration logs, all on
+    the host; `timer` is the calling train()'s StageTimer (the
+    `device_loop.*` spans). The loop stops between chunks once the
+    validation loss has not improved for `early_stop_lookahead` trees
+    (reference early_stopping.h:29-66), or within one chunk of
+    `deadline`, an absolute time.monotonic() value, and returns the
+    iterations finished so far (reference GBT deadline check,
+    gradient_boosted_trees.cc:1314-1325). Under `cache_dir` every chunk
+    ends in a durable snapshot, and SIGTERM/SIGINT ends the train
+    resumable at the next one."""
     # Identity-hashed losses (LambdaMartNdcg carries per-dataset group
     # arrays) can never hit the cache — bypass it so dead entries don't pin
     # device memory or evict the reusable frozen-dataclass ones.
@@ -1963,7 +2088,7 @@ def _train_gbt(
         else _make_boost_fn.__wrapped__
     )
     with timer.stage("device_loop.init"):
-        run = builder(
+        boost = builder(
             loss_obj, rule, tree_cfg, num_trees, shrinkage, subsample,
             candidate_features, num_numerical, num_valid_features, seed,
             bins_tr.shape[0], bins_va.shape[0],
@@ -1986,289 +2111,111 @@ def _train_gbt(
     if vs_tr is not None:
         data_kwargs["vs_tr"] = vs_tr
         data_kwargs["vs_va"] = vs_va
-    trees_per_dispatch = device_loop.trees_per_dispatch(None)
+
+    can_early_stop = early_stop_lookahead > 0 and nv_rows > 0
     if cache_dir is None:
-        if (
-            early_stop_lookahead > 0
-            and nv_rows > 0
-            # Stopping can only ever fire when the loop outlives the
-            # look-ahead window; otherwise the fused single scan is cheaper.
-            and num_trees > early_stop_lookahead
-        ) or deadline is not None or trees_per_dispatch is not None:
-            # In-loop early STOPPING without a working_dir: drive the same
-            # run_chunk executable in memory and break once the validation
-            # loss has not improved for `early_stop_lookahead` trees — the
-            # reference stops its boosting loop the same way
-            # (early_stopping.h:29-66) instead of training all num_trees
-            # and truncating post-hoc. A deadline forces this chunked
-            # driver too (the fused single scan cannot stop mid-flight).
-            use_dart = getattr(run, "use_dart", False)
-            with timer.stage("device_loop.init"):
-                carry, init_pred = run.init_state(y_tr, w_tr)
-            # Trees grown per XLA dispatch: the env knob when set
-            # (YDF_TPU_TREES_PER_DISPATCH — the paired A/B in bench.py
-            # pins it), else the early-stop look-ahead window.
-            clen = trees_per_dispatch or max(
-                1, min(early_stop_lookahead or 25, 25)
-            )
-            parts = []
-            vls_seen = []
-            chunk_walls = []
-            start = 0
-            while start < num_trees:
-                c = _chunk_len(clen, start, num_trees, use_dart)
-                t0_ns = time.perf_counter_ns()
-                # Donated-carry dispatch: `carry` is dead after this call
-                # (its buffers were reused in place on device); everything
-                # below reads only the NEW carry / the fetched ys.
-                carry, ys = device_loop.run_chunk(
-                    run, carry, start, c, *data_args, timer=timer,
-                    **data_kwargs
+        store = _MemoryParts()
+    else:
+        import hashlib
+
+        fp = hashlib.sha1()
+        if hasattr(loss_obj, "fingerprint"):
+            fp.update(loss_obj.fingerprint())
+        fp.update(
+            repr(
+                (
+                    type(loss_obj).__name__, rule, tree_cfg, num_trees,
+                    shrinkage, subsample, candidate_features, num_numerical,
+                    num_valid_features, seed, sampling, goss_alpha,
+                    goss_beta, selgb_ratio, dart_dropout, oblique_P,
+                    oblique_density, oblique_weight_type, vs_Ac, vs_Ap,
+                    # The fused-gradient path changes the carry structure,
+                    # so a snapshot must never resume across routing impls.
+                    route_impl,
+                    route_fuse,
                 )
-                parts.append(_chunk_arrays_from_ys(ys, timer))
-                _note_chunk(
-                    chunk_walls, start, c, num_trees, t0_ns, parts[-1],
-                    nv_rows,
-                )
-                _oom_failpoint()
-                start += c
-                vls_seen.append(parts[-1]["vls"])
-                if nv_rows > 0 and _early_stop_hit(
-                    vls_seen, min(start, num_trees), early_stop_lookahead
-                ):
-                    break
-                if deadline is not None and time.monotonic() >= deadline:
-                    break
-            with timer.stage("device_loop.merge"):
-                trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = (
-                    _merge_chunk_parts(parts, num_trees, use_dart, carry)
-                )
-            logs = {
-                "train_loss": tls,
-                "valid_loss": vls,
-                "initial_predictions": init_pred,
-                "oblique_w": obl_w,
-                "oblique_b": obl_b,
-                "vs_a": vs_a,
-                "vs_b": vs_b,
-                "chunk_walls": chunk_walls,
-            }
-            return trees, lvs, logs
-        t0_ns = time.perf_counter_ns()
-        with timer.stage("device_loop.dispatch"):
-            trees, lvs, tls, vls, init_pred, obl_w, obl_b, vs_a, vs_b = (
-                device_loop.dispatch(run, timer, *data_args, **data_kwargs)
-            )
-        # Block before reading the clock: the jit call returns futures,
-        # and every output is materialized a few lines later anyway —
-        # this just keeps the single "chunk" wall honest.
-        with timer.stage("device_loop.wait"):
-            jax.block_until_ready(tls)
-        device_loop.count_dispatch(num_trees)
-        device_loop.count_host_sync(
-            sum(
-                leaf.nbytes
-                for leaf in jax.tree.leaves(
-                    (trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b)
-                )
-            )
+            ).encode()
         )
-        _oom_failpoint()
-        single_wall = [(0, num_trees, t0_ns, time.perf_counter_ns() - t0_ns)]
-        logs = {
-            "train_loss": tls,
-            "valid_loss": vls,
-            "initial_predictions": init_pred,
-            "oblique_w": obl_w,
-            "oblique_b": obl_b,
-            "vs_a": vs_a,
-            "vs_b": vs_b,
-            "chunk_walls": single_wall,
-        }
-        if telemetry.ENABLED or log.is_debug():
-            _note_chunk(
-                [], 0, num_trees, num_trees, t0_ns,
-                {"tls": np.asarray(tls), "vls": np.asarray(vls)}, nv_rows,
+        fp.update(np.asarray(bins_tr.shape, np.int64).tobytes())
+        fp.update(np.asarray(bins_va.shape, np.int64).tobytes())
+        if set_tr is not None:
+            fp.update(np.asarray(set_tr.shape, np.int64).tobytes())
+            fp.update(
+                np.asarray(set_tr[: min(1000, set_tr.shape[0])]).tobytes()
             )
-        return trees, lvs, logs
-
-    # --- checkpointed training: the boosting loop runs in chunks of
-    # `snapshot_interval` iterations. Each chunk's outputs go to their own
-    # payload file (kept until training finishes — I/O stays linear in the
-    # tree count); the snapshot index records the carry + progress. The
-    # snapshot fingerprints the config and data so a resume against a
-    # different dataset or hyperparameters fails fast instead of silently
-    # mixing trees. (Reference CreateSnapshot / TryLoadSnapshotFromDisk,
-    # gradient_boosted_trees.cc:345-427; index protocol utils/snapshot.h.)
-    import hashlib
-
-    from ydf_tpu.utils.snapshot import Snapshots
-
-    fp = hashlib.sha1()
-    if hasattr(loss_obj, "fingerprint"):
-        fp.update(loss_obj.fingerprint())
-    fp.update(
-        repr(
-            (
-                type(loss_obj).__name__, rule, tree_cfg, num_trees,
-                shrinkage, subsample, candidate_features, num_numerical,
-                num_valid_features, seed, sampling, goss_alpha, goss_beta,
-                selgb_ratio, dart_dropout, oblique_P, oblique_density,
-                oblique_weight_type, vs_Ac, vs_Ap,
-                # The fused-gradient path changes the carry structure, so
-                # a snapshot must never resume across routing impls.
-                route_impl,
-                route_fuse,
-            )
-        ).encode()
+        fp.update(np.asarray(bins_tr[: min(1000, bins_tr.shape[0])]).tobytes())
+        fp.update(np.asarray(y_tr[: min(1000, y_tr.shape[0])]).tobytes())
+        store = _SnapshotParts(cache_dir, fp.hexdigest(), resume, timer)
+    clen = _trees_per_chunk(
+        num_trees,
+        snapshot_interval=None if cache_dir is None else snapshot_interval,
+        early_stop_lookahead=early_stop_lookahead if can_early_stop else 0,
+        deadline=deadline,
     )
-    fp.update(np.asarray(bins_tr.shape, np.int64).tobytes())
-    fp.update(np.asarray(bins_va.shape, np.int64).tobytes())
-    if set_tr is not None:
-        fp.update(np.asarray(set_tr.shape, np.int64).tobytes())
-        fp.update(np.asarray(set_tr[: min(1000, set_tr.shape[0])]).tobytes())
-    fp.update(np.asarray(bins_tr[: min(1000, bins_tr.shape[0])]).tobytes())
-    fp.update(np.asarray(y_tr[: min(1000, y_tr.shape[0])]).tobytes())
-    fingerprint = fp.hexdigest()
 
-    snaps = Snapshots(cache_dir, max_kept=2)
-    use_dart = getattr(run, "use_dart", False)
-
-    def _chunk_path(start_it: int) -> str:
-        return os.path.join(cache_dir, f"chunk_{start_it}.npz")
-
-    start = 0
-    carry = None
-    init_pred = None
-    state = snaps.latest() if resume else None
-    if state is not None:
-        _, arrays, meta = state
-        if meta.get("fingerprint") != fingerprint:
-            raise ValueError(
-                f"Snapshot in {cache_dir!r} was created with different "
-                "data or hyperparameters; refusing to resume. Delete the "
-                "directory or disable resume_training."
-            )
-        start = meta["completed_iters"]
-        carry = tuple(
-            jnp.asarray(arrays[f"carry_{i}"])
-            for i in range(meta["num_carry"])
-        )
-        init_pred = jnp.asarray(arrays["init_pred"])
-    if carry is None:
+    restored = store.restore()
+    if restored is None:
         with timer.stage("device_loop.init"):
-            carry, init_pred = run.init_state(y_tr, w_tr)
+            carry, init_pred = boost.init_state(y_tr, w_tr)
+        start, vls_seen = 0, []
+    else:
+        start, carry, init_pred, vls_seen = restored
 
     chunks_done = 0
-    vls_seen = []
-    if state is not None:
-        # Re-seed the validation-loss history from the completed chunks so
-        # early stopping after a resume sees the true global minimum.
-        for st in state[2].get("chunk_starts", []):
-            try:
-                with np.load(_chunk_path(st)) as z:
-                    vls_seen.append(np.asarray(z["vls"]))
-            except Exception:
-                pass
-    from ydf_tpu.utils.snapshot import _durable_replace
-
     chunk_walls = []
-    with _PreemptionGuard() as guard, _flight_guard():
+    with store.guard() as guard:
         while start < num_trees:
-            # The env knob can move the dispatch boundary off the
-            # snapshot cadence (e.g. resume with a different chunk
-            # size); the compile cache in device_loop keys on the
-            # static loop shape, so alternating sizes never rebuild
-            # previously compiled executables.
-            clen = _chunk_len(
-                device_loop.trees_per_dispatch(snapshot_interval),
-                start, num_trees, use_dart,
-            )
+            c = _chunk_len(clen, start, num_trees, boost.use_dart)
             t0_ns = time.perf_counter_ns()
-            # Donated-carry dispatch: the old carry dies here; the
-            # snapshot below serializes the NEW carry.
+            # The carry is donated: the old one dies here, and everything
+            # below reads the new one or the fetched part.
             carry, ys = device_loop.run_chunk(
-                run, carry, start, clen, *data_args, timer=timer,
+                boost, carry, start, c, *data_args, timer=timer,
                 **data_kwargs
             )
-            chunk_arrays = _chunk_arrays_from_ys(ys, timer)
+            part = _chunk_arrays_from_ys(ys, timer)
             _note_chunk(
-                chunk_walls, start, clen, num_trees, t0_ns, chunk_arrays,
-                nv_rows,
+                chunk_walls, start, c, num_trees, t0_ns, part, nv_rows
             )
-            tmp = _chunk_path(start) + ".tmp"
-            with open(tmp, "wb") as f:
-                np.savez(f, **chunk_arrays)
-            # Durable before the snapshot that references it: the final
-            # merge reads chunk payloads back after a crash, so a torn
-            # chunk behind a durable snapshot would be unrecoverable.
-            _durable_replace(tmp, _chunk_path(start))
-
-            start_next = start + clen
-            with timer.stage("device_loop.fetch"):
-                arrays = {"init_pred": np.asarray(init_pred)}
-                for i, leaf in enumerate(jax.tree.leaves(carry)):
-                    arrays[f"carry_{i}"] = np.asarray(leaf)
-            # Snapshot durability is the checkpointed driver's extra
-            # host-sync point on top of the chunk payload fetch.
-            device_loop.count_host_sync(
-                sum(a.nbytes for a in arrays.values())
-            )
-            if chunks_done == 0:
-                # Chunk list carried across interrupted runs via the
-                # snapshot.
-                all_starts = (
-                    list(state[2].get("chunk_starts", []))
-                    if state is not None
-                    else []
-                )
-            all_starts.append(start)
-            snaps.save(
-                start_next,
-                arrays,
-                meta={
-                    "completed_iters": start_next,
-                    "num_carry": len(jax.tree.leaves(carry)),
-                    "fingerprint": fingerprint,
-                    "chunk_starts": all_starts,
-                },
-            )
-            start = start_next
+            store.add(start, c, part, carry, init_pred)
+            start += c
             chunks_done += 1
             failpoints.hit("gbt.chunk")
             _oom_failpoint()
-            if (
-                preempt_after_chunks is not None
-                and chunks_done >= preempt_after_chunks
-            ):
-                guard.trigger(signal.SIGTERM)
-            if guard.triggered:
-                # The snapshot just saved IS the forced final snapshot;
-                # exit resumable with a distinct (schedulable) outcome.
-                # Telemetry buffered since the last flush would die with
-                # this process: export it and write the flight-recorder
-                # black box BEFORE raising (the exit-75 path used to
-                # lose every span since the previous flush). Both are
-                # no-ops when telemetry is off / has no export dir.
-                if telemetry.ENABLED:
-                    _emit_chunk_spans(chunk_walls)
-                    telemetry.flight_record(
-                        "preempt", signal=guard.signal_name,
-                        completed_iters=start, num_trees=num_trees,
+            if guard is not None:
+                if (
+                    preempt_after_chunks is not None
+                    and chunks_done >= preempt_after_chunks
+                ):
+                    guard.trigger(signal.SIGTERM)
+                if guard.triggered:
+                    # The snapshot just saved IS the forced final
+                    # snapshot; exit resumable with a distinct
+                    # (schedulable) outcome. Telemetry buffered since the
+                    # last flush would die with this process: export it
+                    # and write the flight-recorder black box BEFORE
+                    # raising. Both are no-ops when telemetry is off /
+                    # has no export dir.
+                    if telemetry.ENABLED:
+                        _emit_chunk_spans(chunk_walls)
+                        telemetry.flight_record(
+                            "preempt", signal=guard.signal_name,
+                            completed_iters=start, num_trees=num_trees,
+                        )
+                        telemetry.flush()
+                        telemetry.flight_dump("preempt")
+                    raise TrainingPreempted(
+                        f"training preempted by {guard.signal_name}: "
+                        f"snapshot at {start}/{num_trees} iterations in "
+                        f"{cache_dir!r} is resumable (resume_training=True)"
                     )
-                    telemetry.flush()
-                    telemetry.flight_dump("preempt")
-                raise TrainingPreempted(
-                    f"training preempted by {guard.signal_name}: "
-                    f"snapshot at {start}/{num_trees} iterations in "
-                    f"{cache_dir!r} is resumable (resume_training=True)"
-                )
-            if early_stop_lookahead > 0 and nv_rows > 0:
-                # vls_seen covers iterations [0, start) including
-                # pre-resume chunks (re-seeded above), so argmin is an
-                # absolute index.
-                vls_seen.append(chunk_arrays["vls"])
-                if _early_stop_hit(vls_seen, start, early_stop_lookahead):
+            if can_early_stop:
+                # vls_seen covers iterations [0, start), those of
+                # before a resume too, so argmin is an absolute index.
+                vls_seen.append(part["vls"])
+                if _early_stop_hit(
+                    vls_seen, min(start, num_trees), early_stop_lookahead
+                ):
                     break
             if (
                 abort_after_chunks is not None
@@ -2281,16 +2228,9 @@ def _train_gbt(
             if deadline is not None and time.monotonic() >= deadline:
                 break
 
-    # Merge chunk payloads (linear, once).
-    latest = snaps.latest()
-    all_starts = latest[2]["chunk_starts"]
-    parts = []
-    for st in all_starts:
-        with np.load(_chunk_path(st)) as z:
-            parts.append({k: z[k] for k in z.files})
     with timer.stage("device_loop.merge"):
         trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = _merge_chunk_parts(
-            parts, num_trees, use_dart, carry
+            store.parts(), num_trees, boost.use_dart, carry
         )
     logs = {
         "train_loss": tls,
@@ -2300,7 +2240,7 @@ def _train_gbt(
         "oblique_b": obl_b,
         "vs_a": vs_a,
         "vs_b": vs_b,
-        # Pre-resume chunks carry no wall (they ran in another
+        # Chunks of before a resume carry no wall (they ran in another
         # process); their iteration records report 0 seconds.
         "chunk_walls": chunk_walls,
     }

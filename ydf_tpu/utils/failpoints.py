@@ -100,10 +100,10 @@ KNOWN_SITES = frozenset(
         # ops/native_ffi.py — kernel compile and XLA FFI registration.
         "native.build",
         "native.register",
-        # learners/gbt.py — checkpointed boosting loop, after each
-        # chunk's snapshot is durably saved.
+        # learners/gbt.py — boosting loop, after each chunk is stored
+        # (under a working_dir: its snapshot durably saved).
         "gbt.chunk",
-        # learners/gbt.py — OOM chaos hook at the boosting drivers'
+        # learners/gbt.py — OOM chaos hook at the boosting loop's
         # chunk boundaries: the injected fault is converted to a REAL
         # MemoryError so the flight-recorder's OOM path (reason "oom",
         # MemoryLedger snapshot in the dump header) is provable.

@@ -118,9 +118,11 @@ def build_train_step(
     seed: int = 42,
     loss: str = "binomial",
 ):
-    """The FULL jitted GBT boosting loop (`learners/gbt.py:_make_boost_fn`
-    `run`: init + lax.scan of grow_tree over num_trees iterations) at an
-    arbitrary static configuration, plus ShapeDtypeStruct example args —
+    """The jitted GBT boosting program that train() dispatches (the
+    donated chunk function of `learners/gbt.py:_make_boost_fn`: lax.scan
+    of grow_tree over one chunk of all `num_trees` iterations) at an
+    arbitrary static configuration, plus its example args (carry, first
+    iteration, chunk length, then the data) as ShapeDtypeStructs —
     nothing is allocated, so bench-scale shapes trace in seconds.
 
     Defaults are the bench configuration (BASELINE.json config 1:
@@ -131,6 +133,7 @@ def build_train_step(
         BinomialLogLikelihood,
         MeanSquaredError,
     )
+    from ydf_tpu.ops import device_loop
     from ydf_tpu.ops.split_rules import HessianGainRule
 
     loss_obj = (
@@ -141,11 +144,11 @@ def build_train_step(
     # Bypass the lru_cache: exports must trace fresh under the current
     # YDF_TPU_HIST_IMPL (the cache would hand back a closure whose jit
     # cache still holds the other impl's trace).
-    run = _make_boost_fn.__wrapped__(
+    boost = _make_boost_fn.__wrapped__(
         loss_obj, rule, tree_cfg, num_trees, 0.1, 1.0,
         -1, F, F, seed, n, nv,
     )
-    args = (
+    data = (
         jax.ShapeDtypeStruct((n, F), jnp.uint8),     # bins_tr
         jax.ShapeDtypeStruct((n,), jnp.float32),     # y_tr
         jax.ShapeDtypeStruct((n,), jnp.float32),     # w_tr
@@ -153,11 +156,13 @@ def build_train_step(
         jax.ShapeDtypeStruct((nv,), jnp.float32),    # y_va
         jax.ShapeDtypeStruct((nv,), jnp.float32),    # w_va
     )
-    return run, args
+    carry, _init_pred = jax.eval_shape(boost.init_state, data[1], data[2])
+    start = jax.ShapeDtypeStruct((), jnp.int32)
+    return device_loop.chunk_fn(boost), (carry, start, num_trees) + data
 
 
 def export_train_step(hist_impl: str = "matmul", platforms=("tpu",), **kw):
-    """jax.export of the full boosting loop for `platforms`."""
+    """jax.export of the boosting program for `platforms`."""
     run, args = build_train_step(**kw)
     with _hist_impl_env(hist_impl), _tpu_lookups():
         return jax.export.export(run, platforms=tuple(platforms))(*args)
