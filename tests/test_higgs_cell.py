@@ -405,7 +405,10 @@ def test_whole_number_labels_encode_as_the_sorted_path_does(values, dtype):
 def test_row_split_is_kept_with_the_dataset():
     """The seeded split's permutation and gathers are made once a
     Dataset, seed and ratio; the forests of a sweep stay bit-identical
-    and another seed still splits anew."""
+    and another seed still splits anew. A job whose device inputs were
+    kept (the second) splits nothing at all; one that comes back after
+    another seed took the device's place (the fourth) finds the host's
+    rows of the first."""
     from ydf_tpu.learners import gbt
 
     x, y = make_table(6000, 28, 13, "binary_logit")
@@ -425,17 +428,20 @@ def test_row_split_is_kept_with_the_dataset():
 
     gbt._split_rows = counted
     try:
-        first, second, other = train(), train(), train(random_seed=5)
+        first, second, other, back = (
+            train(), train(), train(random_seed=5), train())
     finally:
         gbt._split_rows = real
-    assert calls[0] is calls[1] and calls[2] is not calls[0]
+    assert len(calls) == 3  # first, other, back: not the second
+    assert calls[2] is calls[0] and calls[1] is not calls[0]
     assert not calls[0].flags.writeable
     # what it costs is on the memory ledger: the bins once more a seed
     assert ds._retyped and all(
         d.bin_cache_bytes() >= 3 * calls[0].nbytes
         for d in ds._retyped.values())
-    a, b, c = (m.forest.to_numpy() for m in (first, second, other))
+    a, b, c, d = (m.forest.to_numpy() for m in (first, second, other, back))
     for k in a:
         np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(d[k]))
     assert not np.array_equal(np.asarray(a["threshold"]),
                               np.asarray(c["threshold"]))

@@ -94,6 +94,7 @@ def _resolve_typed_path(path: str) -> List[str]:
 # tuner/CV bin-matrix memo is the one in-memory structure that can
 # silently hold hundreds of MB per Dataset (utils/telemetry.py:
 # MemoryLedger; sampled only at ledger snapshots).
+import threading as _threading  # noqa: E402
 import weakref as _weakref  # noqa: E402
 
 _LIVE_DATASETS: "_weakref.WeakSet" = _weakref.WeakSet()
@@ -103,17 +104,62 @@ def bin_matrix_bytes_total() -> int:
     return sum(d.bin_cache_bytes() for d in list(_LIVE_DATASETS))
 
 
+# The one Dataset whose last train() left that job's inputs on the device
+# (`Dataset.keep_device_inputs`). A weak reference: the arrays hang on
+# their Dataset and die with it; nothing at module level owns device
+# memory. The lock makes "let go of the holder's, then hold" one step,
+# so that two threads training at once never leave two holders.
+_DEVICE_RESIDENT: Optional["_weakref.ref"] = None
+_DEVICE_LOCK = _threading.RLock()
+
+
+def _device_resident() -> Optional["Dataset"]:
+    return _DEVICE_RESIDENT() if _DEVICE_RESIDENT is not None else None
+
+
+def release_device_inputs() -> None:
+    """Lets go of the device arrays that the Dataset trained on last kept
+    (`Dataset.keep_device_inputs`). Whoever is about to send a table to
+    the device calls this FIRST (every learner's upload site does), so
+    the chip never holds a table more than the job at hand needs. The
+    references are dropped, nothing is deleted: a train() still running
+    on those arrays in another thread keeps its own."""
+    global _DEVICE_RESIDENT
+    with _DEVICE_LOCK:
+        held = _device_resident()
+        if held is not None:
+            held._device_inputs = None
+        _DEVICE_RESIDENT = None
+
+
+def device_inputs_bytes_total() -> int:
+    held = _device_resident()
+    return held.device_inputs_bytes() if held is not None else 0
+
+
 def _register_mem_source() -> None:
     from ydf_tpu.utils import telemetry
 
     telemetry.register_mem_source("bin_matrix", bin_matrix_bytes_total)
+    telemetry.register_mem_source("device_inputs", device_inputs_bytes_total)
 
 
 _register_mem_source()
 
 
 class Dataset:
-    """Columnar dataset: name → 1-D numpy array + dataspec."""
+    """Columnar dataset: name → 1-D numpy array + dataspec.
+
+    A Dataset also keeps what fitting on it made, so that the next fit
+    on the SAME object does not make it again: on the host the fitted
+    binners, the bin matrices and the seeded row split (`bin_cache_bytes`,
+    the memory ledger's "bin_matrix" row); on the device the six arrays
+    the last gradient-boosted train() on it handed its boosting loop
+    (`device_inputs_bytes`, the ledger's "device_inputs" row: a table's
+    worth of device memory). At most one Dataset in the process holds
+    device arrays, the one trained on last, and it holds one set of
+    them. To let go of them drop the Dataset (`del ds`; they go with
+    it), or train on another one."""
 
     def __init__(self, data: Dict[str, np.ndarray], dataspec: DataSpecification):
         self.data = {k: np.asarray(v) for k, v in data.items()}
@@ -139,6 +185,9 @@ class Dataset:
         # infers, bins and encodes all of it again (14.5 s a job at
         # 40M x 28, PERF.md section 6).
         self._retyped: Dict = {}
+        # (bin matrix, key, device arrays) of the last train() on this
+        # Dataset that kept its inputs on the device, or None.
+        self._device_inputs: Optional[tuple] = None
         _LIVE_DATASETS.add(self)  # memory-ledger "bin_matrix" source
 
     def bin_cache_bytes(self) -> int:
@@ -150,6 +199,33 @@ class Dataset:
             for a in v if isinstance(v, tuple) else (v,):
                 held[id(a)] = int(getattr(a, "nbytes", 0))
         return sum(held.values())
+
+    # ---- a job's device inputs (see learners/gbt.py) ---------------- #
+
+    def device_inputs(self, bins: np.ndarray, key) -> Optional[tuple]:
+        """The device arrays kept by `keep_device_inputs(bins, key, ...)`,
+        or None: `bins` is compared by identity (the cached bin matrix
+        they were cut from), `key` by value."""
+        entry = self._device_inputs
+        if entry is not None and entry[0] is bins and entry[1] == key:
+            return entry[2]
+        return None
+
+    def keep_device_inputs(self, bins: np.ndarray, key, arrays) -> None:
+        """Keeps `arrays` (device arrays made from `bins` under `key`)
+        with this Dataset and makes it the process's one holder of
+        device inputs: what it or any other Dataset held goes first."""
+        global _DEVICE_RESIDENT
+        with _DEVICE_LOCK:
+            release_device_inputs()
+            self._device_inputs = (bins, key, tuple(arrays))
+            _DEVICE_RESIDENT = _weakref.ref(self)
+
+    def device_inputs_bytes(self) -> int:
+        """Device bytes of the kept job inputs (the memory ledger's
+        "device_inputs" row)."""
+        entry = self._device_inputs
+        return sum(int(a.nbytes) for a in entry[2]) if entry else 0
 
     # ---- binning memo (see dataset/binning.py) ----------------------- #
 

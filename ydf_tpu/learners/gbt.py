@@ -38,7 +38,11 @@ import numpy as np
 
 from ydf_tpu.config import Task, TreeConfig
 from ydf_tpu.utils import failpoints, log, telemetry
-from ydf_tpu.dataset.dataset import InputData
+from ydf_tpu.dataset.dataset import (
+    Dataset,
+    InputData,
+    release_device_inputs,
+)
 from ydf_tpu.learners.generic import GenericLearner
 from ydf_tpu.learners.losses import make_loss
 from ydf_tpu.models.forest import forest_from_stacked_trees
@@ -357,12 +361,31 @@ class GradientBoostedTreesLearner(GenericLearner):
             else None
         )
         with timer.stage("ingest_bin"):
-            prep = self._prepare(data, valid=valid)
+            prep = self._prepare(data, valid=valid, targets=False)
+            # The six arrays this job would hand the boosting loop may
+            # be on the device already, kept with the Dataset by an
+            # earlier job that made the same ones: then nothing that
+            # goes by the row runs on the host, here or in `split`.
+            inputs_key = self._device_inputs_key(data, valid, prep)
+            kept = (
+                prep["dataset"].device_inputs(prep["bins"], inputs_key)
+                if inputs_key is not None
+                else None
+            )
+            timer.counts["device_loop.inputs_cached"] = float(
+                kept is not None
+            )
+            if kept is None:
+                # Before this job's table goes up, never after: the
+                # chip holds one table.
+                release_device_inputs()
+                if "sample_weights" not in prep:
+                    self._encode_targets(prep)
         binner = prep["binner"]
         bins_all = prep["bins"]
         set_all = prep.get("set_bits")
-        labels_all = prep["labels"]
-        w_all = prep["sample_weights"]
+        labels_all = prep.get("labels")  # None, like w_all, where `kept`
+        w_all = prep.get("sample_weights")
         n = bins_all.shape[0]
         num_classes = len(prep.get("classes", [])) or 1
 
@@ -396,7 +419,9 @@ class GradientBoostedTreesLearner(GenericLearner):
         if vs_all is not None:
             vs_all = (vs_all[0], vs_all[1])
         with timer.stage("split"):
-            if "valid_bins" in prep:
+            if kept is not None:
+                bins_tr, y_tr, w_tr, bins_va, y_va, w_va = kept
+            elif "valid_bins" in prep:
                 bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
                 bins_va = prep["valid_bins"]
                 y_va = prep["valid_labels"]
@@ -822,7 +847,8 @@ class GradientBoostedTreesLearner(GenericLearner):
                     # Every array this train() sends to the device, in
                     # one place: the enqueue is the span, the bytes the
                     # counter. (Under a mesh they were placed when they
-                    # were sharded, above; the bytes are the same.)
+                    # were sharded, above; the bytes are the same.) A
+                    # job whose inputs were kept sends nothing.
                     inputs = dict(
                         bins_tr=bins_tr, y_tr=y_tr, w_tr=w_tr,
                         bins_va=bins_va, y_va=y_va, w_va=w_va,
@@ -830,10 +856,16 @@ class GradientBoostedTreesLearner(GenericLearner):
                         set_tr=set_tr, set_va=set_va,
                         vs_tr=vs_tr, vs_va=vs_va,
                     )
-                    device_loop.count_h2d(
-                        sum(a.nbytes for a in jax.tree.leaves(inputs))
-                    )
-                    inputs = jax.tree.map(jnp.asarray, inputs)
+                    if kept is None:
+                        device_loop.count_h2d(
+                            sum(a.nbytes for a in jax.tree.leaves(inputs))
+                        )
+                        inputs = jax.tree.map(jnp.asarray, inputs)
+                        if inputs_key is not None:
+                            prep["dataset"].keep_device_inputs(
+                                bins_all, inputs_key,
+                                [inputs[k] for k in _KEPT_INPUTS],
+                            )
                 forest_stacked, leaf_values, logs = _train_gbt(
             **inputs,
             timer=timer,
@@ -1076,6 +1108,33 @@ class GradientBoostedTreesLearner(GenericLearner):
             telemetry.flush()
         return model
 
+    def _device_inputs_key(self, data, valid, prep) -> Optional[tuple]:
+        """Everything besides the bin matrix that this job's six device
+        arrays (`_KEPT_INPUTS`) depend on, or None for a job whose
+        inputs are not kept with the Dataset: one that is not given a
+        Dataset (a dict or a frame makes a new one every call), brings
+        its own validation rows, splits by query groups, has survival
+        columns, set or vector-sequence features or raw features for
+        oblique splits, or places its arrays across workers or a mesh."""
+        if (
+            not isinstance(data, Dataset)
+            or valid is not None
+            or self.task not in (Task.CLASSIFICATION, Task.REGRESSION)
+            or prep.get("set_bits") is not None
+            or prep.get("vs") is not None
+            or self.split_axis != "AXIS_ALIGNED"
+            or self.distributed_workers
+            or self.mesh is not None
+        ):
+            return None
+        splits = self.validation_ratio > 0 and self.early_stopping != "NONE"
+        return (
+            (self.random_seed, self.validation_ratio) if splits else None,
+            self.label, self.task, self.weights,
+            # the placement: the one device `jnp.asarray` sends to
+            jax.default_backend(), jax.config.jax_default_device,
+        )
+
     def _model_metadata(self) -> Optional[dict]:
         md = {}
         if self.ranking_group:
@@ -1086,6 +1145,11 @@ class GradientBoostedTreesLearner(GenericLearner):
             if self.label_entry_age:
                 md["label_entry_age"] = self.label_entry_age
         return md or None
+
+
+# The arrays of a job that a Dataset keeps on the device for the next
+# (`Dataset.keep_device_inputs`), in `_train_gbt`'s order.
+_KEPT_INPUTS = ("bins_tr", "y_tr", "w_tr", "bins_va", "y_va", "w_va")
 
 
 def _split_rows(dataset, bins_all, rng, seed, ratio):

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ydf_tpu.config import Task, TreeConfig
-from ydf_tpu.dataset.dataset import InputData
+from ydf_tpu.dataset.dataset import InputData, release_device_inputs
 from ydf_tpu.learners.generic import GenericLearner
 from ydf_tpu.models.forest import forest_from_stacked_trees
 from ydf_tpu.models.if_model import IsolationForestModel, average_path_length
@@ -95,6 +95,7 @@ class IsolationForestLearner(GenericLearner):
     def train(self, data: InputData, valid=None) -> IsolationForestModel:
         prep = self._prepare(data)
         binner = prep["binner"]
+        release_device_inputs()  # this job's table goes up: no second one
         bins = jnp.asarray(prep["bins"])
         n, F = bins.shape
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ydf_tpu.config import Task, TreeConfig
-from ydf_tpu.dataset.dataset import InputData
+from ydf_tpu.dataset.dataset import InputData, release_device_inputs
 from ydf_tpu.learners.generic import GenericLearner
 from ydf_tpu.models.forest import forest_from_stacked_trees
 from ydf_tpu.models.rf_model import RandomForestModel
@@ -152,6 +152,7 @@ class RandomForestLearner(GenericLearner):
         with timer.stage("ingest_bin"):
             prep = self._prepare(data)
         binner = prep["binner"]
+        release_device_inputs()  # this job's table goes up: no second one
         bins = jnp.asarray(prep["bins"])
         set_bits = prep.get("set_bits")
         if set_bits is not None:

@@ -80,6 +80,10 @@ class StageTimer:
         # the persistent cache answered; the routing look-ups its trace
         # made as selects; as gathers). ops/device_loop.dispatch fills it.
         self.programs: Dict[object, Tuple[float, bool, int, int]] = {}
+        # Facts of this call that are no spans, under their profile keys
+        # (`device_loop.inputs_cached`: 1.0 where the job's device
+        # inputs were kept with its Dataset and nothing was sent).
+        self.counts: Dict[str, float] = {}
         self._t0 = time.perf_counter_ns()
 
     @contextlib.contextmanager
@@ -106,7 +110,7 @@ class StageTimer:
         `device_loop.route_gather`, the per-row look-ups of its trace
         that are compare-and-select passes and that are gathers
         (ops/lookup.py)."""
-        out = dict(self.seconds)
+        out = {**self.seconds, **self.counts}
         out.setdefault("device_loop.compile", 0.0)
         if self.programs:
             builds = list(self.programs.values())
