@@ -3,7 +3,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ydf_tpu.ops.histogram import histogram, split_bf16
+from ydf_tpu.ops.histogram import (
+    _histogram_matmul,
+    _histogram_segment,
+    histogram,
+    split_bf16,
+)
 
 
 def _ref_histogram(bins, slot, stats, L, B):
@@ -153,6 +158,41 @@ def test_chunk_boundaries_bit_equal(n, chunk):
                       impl=impl, chunk=chunk)
         )
         np.testing.assert_array_equal(got, oracle, err_msg=impl)
+
+
+@pytest.mark.parametrize("quant", ["f32", "bf16x2", "int8"])
+@pytest.mark.parametrize("L", [1, 2, 4, 8, 16, 24])  # 24: one piece a dot
+def test_matmul_narrow_operand_at_every_width(L, quant):
+    """`_histogram_matmul` itself, on the operand each quant mode hands
+    it, is BIT-equal to the segment oracle at every width of its narrow
+    operand: slot counts that fill no sublane tile (2, 4), that fill
+    whole ones (8, 16), one slot, two dots a feature (16) and one piece
+    a dot (24), over two whole chunks and a ragged tail, rows on the
+    trash slot included. Whole-number stats whose cell sums stay under
+    2^24 make both sides exact, so neither how the operand is built nor
+    the order of the chunks can excuse a difference. The f32 values
+    need all three bf16 pieces (18 bits)."""
+    rng = np.random.default_rng(10 * L + len(quant))
+    chunk, F, B, S = 256, 5, 64, 3
+    n = 2 * chunk + 77
+    bins = jnp.asarray(rng.integers(0, B, (n, F)), jnp.uint8)
+    slot = jnp.asarray(rng.integers(0, L + 1, (n,)), jnp.int32)  # L = trash
+    if quant == "f32":
+        whole = rng.integers(-(2 ** 18) + 1, 2 ** 18, (n, S)) | 1
+        stats = jnp.asarray(whole.astype(np.float32))
+    elif quant == "bf16x2":  # high halves of 8 bits beside small residuals
+        hi = rng.integers(-127, 128, (n, S)) * 256
+        lo = rng.integers(-127, 128, (n, S))
+        stats = jnp.asarray(np.concatenate([hi, lo], axis=1), jnp.bfloat16)
+    else:
+        stats = jnp.asarray(rng.integers(-127, 128, (n, S)), jnp.int8)
+    want = np.asarray(_histogram_segment(bins, slot, stats, L, B))
+    mass = _histogram_segment(bins, slot, jnp.abs(stats), L, B)
+    assert float(jnp.max(mass)) < 2.0 ** 24  # every partial sum is exact
+    got = np.asarray(_histogram_matmul(bins, slot, stats, L, B, chunk))
+    assert got.shape == (L, F, B, stats.shape[1])
+    assert np.any(want != 0)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_segment_chunked_scan_path():

@@ -54,7 +54,8 @@ def test_f32_matmul_histogram_is_one_bf16_pass(export):
     """The f32 matmul histogram reaches the MXU as ONE bf16 dot with an
     f32 result: the stats ride as three exact bf16 pieces
     (ops/histogram.py:_HIST_QUANTS), 3 x L x 3 columns a level, and both
-    operands are laid rows-minor ([bins, rows] and [columns, rows]). A
+    operands are laid rows-minor ([bins, rows] and [columns, rows]);
+    the only other dots build that narrow operand, one a level. A
     dot on f32 operands would be one lossy bf16 pass at XLA:TPU's
     default and six passes of the one-hot at HIGHEST; neither may come
     back quietly."""
@@ -70,7 +71,7 @@ def test_f32_matmul_histogram_is_one_bf16_pass(export):
         line for line in exp.mlir_module().splitlines()
         if "stablehlo.dot_general" in line
     ]
-    widths = set()
+    widths, built = set(), set()
     for line in dots:
         assert "HIGH" not in line, line  # HIGH or HIGHEST: extra passes
         sig = line.split(" : ", 1)[1]
@@ -78,8 +79,14 @@ def test_f32_matmul_histogram_is_one_bf16_pass(export):
         assert operands.count("xbf16>") == 2 and "xf32>" in result, line
         # Both operands carry the rows on their minor, contracted side.
         assert "contracting_dims = [1] x [1]" in line, line
-        assert sig.startswith("(tensor<256x2048xbf16>"), line
-        widths.add(int(result.split("x")[1]))
+        if sig.startswith("(tensor<256x2048xbf16>"):
+            widths.add(int(result.split("x")[1]))
+        else:
+            # The narrow operand's own build: a 0/1 matrix repeats the
+            # 9 pieces once a slot, [9 L, 9] x [rows, 9] (narrow()).
+            assert ", tensor<2048x9xbf16>)" in sig, line
+            built.add(int(sig.split("x")[0].split("<")[1]))
+    assert built == widths, (built, widths)
     # depth 4 with sibling subtraction: 1, 1, 2, 4 live slots a level
     # (the deepest builds no histogram), 3 pieces x 3 stats each.
     assert widths == {9, 18, 36}, widths
@@ -111,6 +118,89 @@ def test_routing_has_no_row_gather(export, F):
         if f"tensor<{n}x" in line or f"tensor<{nv}x" in line
     ]
     assert not by_row, by_row[0]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e for XLA:TPU to compile for, with no
+    device attached. Loaded by the worker that runs this file, and only
+    once a test asks for it (libtpu belongs to one process)."""
+    try:
+        return tl.deviceless_tpu_sharding()
+    except Exception as e:  # whatever libtpu raises where it has none
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.mark.parametrize("quant", ["f32", "bf16x2", "int8"])
+@pytest.mark.parametrize("L", [2, 4, 8, 16])  # 16: two dots a feature
+def test_narrow_operand_is_built_once_a_chunk(one_chip, L, quant):
+    """In the program XLA:TPU compiles from `_histogram_matmul`, not only
+    in its source, the loop over features holds the bin column's slice
+    and the contraction(s) and nothing else as long as the chunk. At 2
+    and 4 slots the compiler used to sink the operand's product and its
+    relayout to [columns, chunk] into that loop, where they ran F times
+    a chunk: 1.83 s of a 15.0 s job in `synth100_gbt.sweep` (ledger, PR
+    31; PERF.md section 6, PR 34). One chunk and a tail: both loops."""
+    chunk = 1 << 18
+    hlo = tl.compile_histogram_matmul(
+        one_chip, L=L, F=4, quant=quant, n=chunk + 1000, chunk=chunk
+    )
+    ops = tl.per_feature_body_ops(hlo, chunk)
+    assert ops, "no loop over features in the compiled program"
+    for body in {op["body"] for op in ops}:
+        mine = [op for op in ops if op["body"] == body]
+        assert any(op["contracts"] for op in mine), mine
+        extra = [
+            (op["name"], op["shape"]) for op in mine
+            if not op["contracts"] and not (
+                "dynamic-slice" in op["name"]
+                and op["shape"].startswith(f"s32[{chunk},1]")
+            )
+        ]
+        assert not extra, extra
+
+
+def test_per_feature_body_ops_reads_a_compiled_text():
+    """The reader on a text small enough to check by eye: the innermost
+    `while` body is the loop over features; instructions of the outer
+    body, tuple plumbing and bitcasts are left out, and a fusion counts
+    as the contraction when the computation it calls holds one."""
+    hlo = """
+%fused_dot (p0: bf16[256,64], p1: bf16[36,64]) -> f32[256,36] {
+  %p0 = bf16[256,64]{1,0} parameter(0)
+  %p1 = bf16[36,64]{1,0} parameter(1)
+  ROOT %convolution.1 = f32[256,36]{1,0} convolution(%p0, %p1), dim_labels=bf_oi->bf
+}
+%features (t: (s32[], bf16[9,4,64])) -> (s32[], bf16[9,4,64]) {
+  %t = (s32[], bf16[9,4,64]{2,1,0}) parameter(0)
+  %gte.1 = bf16[9,4,64]{2,1,0:T(4,128)(2,1)} get-tuple-element(%t), index=1
+  %reshape.7 = bf16[36,64]{1,0:T(8,128)(2,1)S(1)} reshape(%gte.1), metadata={op_name="x"}
+  %bitcast.2 = bf16[36,64]{1,0} bitcast(%reshape.7)
+  %fusion.3 = f32[256,36]{1,0} fusion(%gte.1, %reshape.7), kind=kOutput, calls=%fused_dot
+  ROOT %tuple.1 = (s32[], bf16[9,4,64]{2,1,0}) tuple(%gte.1, %gte.1)
+}
+%cond (t: (s32[], bf16[9,4,64])) -> pred[] {
+  ROOT %lt = pred[] constant(true)
+}
+%chunks (c: (s32[])) -> (s32[]) {
+  %c = (s32[]) parameter(0)
+  %mul.1 = bf16[9,4,64]{2,1,0} multiply(%c, %c)
+  %while.1 = (s32[], bf16[9,4,64]{2,1,0}) while(%mul.1), condition=%cond, body=%features
+  ROOT %tuple.2 = (s32[]) tuple(%c)
+}
+ENTRY %main (a: s32[]) -> (s32[]) {
+  %a = s32[] parameter(0)
+  ROOT %while.2 = (s32[]) while(%a), condition=%cond, body=%chunks
+}
+"""
+    got = [
+        (op["body"], op["name"], op["opcode"], op["contracts"])
+        for op in tl.per_feature_body_ops(hlo, 64)
+    ]
+    assert got == [
+        ("features", "reshape.7", "reshape", False),
+        ("features", "fusion.3", "fusion", True),
+    ], got
 
 
 def test_binning_kernel_lowers_to_mosaic():

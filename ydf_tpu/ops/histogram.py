@@ -22,6 +22,11 @@ Two implementations:
     times as wide (see _HIST_QUANTS below). The one-hot is written
     [B, n], rows on the minor side, and A goes to the MXU in dots of at
     most 128 columns: both by measurement on a v5e (PERF.md section 5).
+    A does not depend on the feature and is built once a chunk, outside
+    the loop over features, as [L*S, n] from its first operation on
+    (`narrow` in _histogram_matmul): written as a [S, L, n] product and
+    reshaped, it was rebuilt for every feature by the compiled program
+    at L = 2 and 4 (PERF.md section 6, PR 34).
 
   * "segment" (CPU / small data): `jax.ops.segment_sum` over the fused
     (slot, bin) index — fast on CPU where scatter-add is native; used by the
@@ -239,24 +244,44 @@ def _histogram_matmul(
         # are then spread down the bins and slots, not across them, and
         # no operand is laid out anew per chunk. That is most of what a
         # level costs (PERF.md section 5).
-        # (stats ⊗ onehot(slot))^T, built per chunk to bound memory,
-        # columns (piece, stat, slot); the trash slot L falls outside
-        # arange(L) and contributes zero columns.
-        slot_oh = (
-            jnp.arange(L, dtype=s_chunk.dtype)[:, None] == s_chunk[None, :]
-        ).astype(st_chunk.dtype)  # [L, chunk]
-        a_chunk = (
-            st_chunk.T[:, None, :] * slot_oh[None, :, :]
-        )  # [pieces*S, L, chunk]
+        #
+        # (st ⊗ onehot(slot))^T, [chunk, c] -> [c * L, chunk], rows
+        # (column of st, slot), in two dimensions from its first
+        # operation on: a 0/1 matrix on the MXU repeats each column of
+        # st L times (one exact product a cell), a select keeps the
+        # row's own slot; the trash slot L is no `column % L` and keeps
+        # none. Written as a [c, L, chunk] product and reshaped, the
+        # operand needed a relayout wherever L fills no whole sublane
+        # tile (L = 2, 4), and XLA:TPU sank product and relayout into
+        # the loop over features below, where they ran F times a chunk:
+        # 1.83 s of a 15.0 s job in `synth100_gbt.sweep` (ledger, PR 31;
+        # PERF.md section 6, PR 34). This form runs once a chunk in the
+        # compiled program too, which tests/test_tpu_lowering.py checks.
+        def narrow(st):
+            c = st.shape[1]
+            column = jnp.arange(c * L, dtype=jnp.int32)
+            repeat = (
+                column[:, None] // L == jnp.arange(c, dtype=jnp.int32)
+            ).astype(st.dtype)  # [c * L, c]
+            wide = jax.lax.dot_general(
+                repeat, st, (((1,), (1,)), ((), ())),
+                preferred_element_type=acc_dtype,
+            ).astype(st.dtype)  # [c * L, chunk]
+            slot_of = (column % L).astype(s_chunk.dtype)
+            return jnp.where(slot_of[:, None] == s_chunk[None, :], wide, 0)
+
+        # One operand a dot, columns (piece, stat, slot), each built
+        # from its own pieces: slicing one [pieces * S * L, chunk]
+        # operand into the dots' parts is a copy of it a chunk.
         a_dots = [
-            a_chunk[p * S:(p + per_dot) * S].reshape(-1, chunk)
+            narrow(st_chunk[:, p * S:(p + per_dot) * S])
             for p in range(0, pieces, per_dot)
         ]
 
         def per_feature(f, acc):
             oh = (
                 bvals[:, None] == b_chunk[:, f].astype(jnp.int32)[None, :]
-            ).astype(a_chunk.dtype)  # [B, chunk]
+            ).astype(st_chunk.dtype)  # [B, chunk]
             h = jnp.concatenate(
                 [
                     jax.lax.dot_general(

@@ -28,6 +28,7 @@ import contextlib
 import gzip
 import json
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -36,6 +37,9 @@ import numpy as np
 
 __all__ = [
     "build_train_step",
+    "deviceless_tpu_sharding",
+    "compile_histogram_matmul",
+    "per_feature_body_ops",
     "export_train_step",
     "export_grow_tree",
     "export_binning_pallas",
@@ -58,6 +62,15 @@ CHIP_SPECS = {
     "v5e": {"peak_flops": 197e12, "hbm_gbps": 819e9, "hbm_gib": 16},
     "v4": {"peak_flops": 275e12, "hbm_gbps": 1228e9, "hbm_gib": 32},
     "v5p": {"peak_flops": 459e12, "hbm_gbps": 2765e9, "hbm_gib": 95},
+}
+
+
+# The stats operand each histogram quant mode hands a kernel: dtype and
+# columns for S = 3 stats (bf16x2: high and residual halves side by side).
+_QUANT_STATS = {
+    "f32": (jnp.float32, 3),
+    "bf16x2": (jnp.bfloat16, 6),
+    "int8": (jnp.int8, 3),
 }
 
 
@@ -161,6 +174,116 @@ def build_train_step(
     return device_loop.chunk_fn(boost), (carry, start, num_trees) + data
 
 
+# What libtpu wants to know before it describes a chip that is not
+# attached (.claude/skills/verify/SKILL.md): read when the library loads.
+_DEVICELESS_ENV = {
+    "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+    "TPU_WORKER_HOSTNAMES": "localhost",
+    "TPU_SKIP_MDS_QUERY": "1",
+}
+
+
+def deviceless_tpu_sharding(topology_name: str = "v5e:2x2"):
+    """A SingleDeviceSharding on one chip of a DESCRIBED topology: what
+    XLA:TPU needs to compile with no device attached. Raises what
+    libtpu raises where it can describe none. Call it from a test or a
+    script, never while a module is imported: the process keeps
+    libtpu, and its lock, from here on."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    for key, value in _DEVICELESS_ENV.items():
+        os.environ.setdefault(key, value)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name=topology_name
+    )
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compile_histogram_matmul(
+    sharding, L: int, F: int, quant: str = "f32", n: int = 3 << 18,
+    chunk: int = 1 << 18, num_bins: int = 256,
+) -> str:
+    """XLA:TPU's compiled text of `ops/histogram.py:_histogram_matmul`
+    itself for the chip `sharding` describes, at `L` slots, `F`
+    features and the stats operand the `quant` mode hands it. The text
+    carries the compiler's placement of every operation, its layouts
+    and its `estimated_cycles`: no chip reading."""
+    from ydf_tpu.ops.histogram import _histogram_matmul
+
+    dtype, S = _QUANT_STATS[quant]
+    args = (
+        jax.ShapeDtypeStruct((n, F), jnp.uint8, sharding=sharding),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding),
+        jax.ShapeDtypeStruct((n, S), dtype, sharding=sharding),
+    )
+    fn = jax.jit(
+        lambda b, sl, st: _histogram_matmul(b, sl, st, L, num_bins, chunk)
+    )
+    return fn.lower(*args).compile().as_text()
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = (\(.*?\)|\S+?)(?:\{\S*)? ([a-z][a-z-]*)\("
+)
+
+
+def per_feature_body_ops(hlo_text: str, chunk: int) -> list[dict]:
+    """What a compiled `_histogram_matmul` executes once a FEATURE with a
+    result as long as the chunk: for every innermost `while` body of
+    `hlo_text` (the loop over features; the loop over chunks holds it),
+    each instruction whose result has a `chunk`-sized dimension, and
+    each fusion around the MXU's convolution (`contracts`), as {"body",
+    "name", "opcode", "shape", "contracts"}. Tuple plumbing, parameters
+    and bitcasts run nothing and are left out."""
+    computations, name = {}, None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            computations[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            computations[name].append(line)
+    bodies = {
+        m.group(1)
+        for lines in computations.values() for line in lines
+        for m in [re.search(r" while\(.*body=%([^\s,)]+)", line)] if m
+    }
+    innermost = [
+        b for b in sorted(bodies)
+        if not any(" while(" in line for line in computations[b])
+    ]
+    ops = []
+    for body in innermost:
+        for line in computations[body]:
+            m = _HLO_INSTRUCTION.match(line)
+            if not m or m.group(3) in (
+                "parameter", "get-tuple-element", "tuple", "bitcast"
+            ):
+                continue
+            shape = m.group(2)
+            dims = [
+                int(d) for dim in re.findall(r"\[([\d,]*)\]", shape)
+                for d in dim.split(",") if d
+            ]
+            called = re.search(r"calls=%([^\s,)]+)", line)
+            contracts = bool(called) and any(
+                " convolution(" in l
+                for l in computations.get(called.group(1), [])
+            )
+            if chunk in dims or contracts:
+                ops.append({
+                    "body": body, "name": m.group(1),
+                    "opcode": m.group(3), "shape": shape,
+                    "contracts": contracts,
+                })
+    return ops
+
+
 def export_train_step(hist_impl: str = "matmul", platforms=("tpu",), **kw):
     """jax.export of the boosting program for `platforms`."""
     run, args = build_train_step(**kw)
@@ -219,11 +342,7 @@ def export_histogram_pallas(
     proving all three operand precisions Mosaic-lower for TPU."""
     from ydf_tpu.ops.histogram_pallas import histogram_pallas
 
-    dtype, S = {
-        "f32": (jnp.float32, 3),
-        "bf16x2": (jnp.bfloat16, 6),
-        "int8": (jnp.int8, 3),
-    }[quant]
+    dtype, S = _QUANT_STATS[quant]
     args = (
         jax.ShapeDtypeStruct((n, F), jnp.uint8),
         jax.ShapeDtypeStruct((n,), jnp.int32),
@@ -254,11 +373,7 @@ def export_histogram_routed_pallas(
     f32 one-hot dots in every mode."""
     from ydf_tpu.ops.histogram_pallas import histogram_routed_pallas
 
-    dtype, S = {
-        "f32": (jnp.float32, 3),
-        "bf16x2": (jnp.bfloat16, 6),
-        "int8": (jnp.int8, 3),
-    }[quant]
+    dtype, S = _QUANT_STATS[quant]
     L1 = L + 1
     args = (
         jax.ShapeDtypeStruct((n, F), jnp.uint8),    # bins
