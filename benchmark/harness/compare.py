@@ -6,16 +6,36 @@ on the chip by the rule in PERF.md section 2: above the largest reading
 of sound runs over a dozen seeds, below the smallest reading of the
 control (the program's own lower-precision histogram) and of each
 planted fault.
+
+A configuration whose `reference` block names a `"module"` is read by
+`benchmark/references/<module>.py` instead (`of_config`), which gives
+`forest_arrays(model)` and `readings(x, y, hp, jobs, follow_trees,
+devices)` as this file does; what `readings` returns is judged here.
 """
 
 from __future__ import annotations
 
+import json
+import sys
+import time
+
 import numpy as np
 
+from harness import manifest
 from harness.reference import GbtReference
 
 FOREST_KEYS = ("feature", "threshold", "left", "right", "is_leaf",
                "leaf_value", "cover", "num_nodes")
+
+
+def of_config(config):
+    """What reads this configuration's jobs: the module its `reference`
+    block names, or this one."""
+    name = config["reference"].get("module")
+    if name is None:
+        return sys.modules[__name__]
+    return manifest.named_module("references", name,
+                                 ("forest_arrays", "readings"))
 
 
 def forest_arrays(model):
@@ -44,15 +64,18 @@ def jobs_differ(jobs) -> int:
         for j in jobs[:-1])
 
 
-def readings(x, y, hp, jobs, follow_trees=3, block_rows=1 << 19, ref=None):
+def readings(x, y, hp, jobs, follow_trees=3, devices=None,
+             block_rows=1 << 19, ref=None):
     """{name: number} for the last job of `jobs` (forest_arrays of each
-    job the window finished), by the reference run over the raw table.
-    `ref`: a GbtReference already built for this table, to read several
-    models against it (tools/limits.py)."""
+    job the window finished), by the reference run over the raw table,
+    its row blocks divided over `devices` (the cell's chips; the first
+    device if None). `ref`: a GbtReference already built for this table,
+    to read several models against it (tools/limits.py)."""
     got = jobs[-1]
     if ref is None:
-        ref = GbtReference(x, y, hp, block_rows=block_rows)
+        ref = GbtReference(x, y, hp, block_rows=block_rows, devices=devices)
     ref.reset()
+    t_trees = time.perf_counter()
     trees = min(follow_trees, len(got["train_loss"]))
     out = {
         "jobs_differ": jobs_differ(jobs),
@@ -85,6 +108,10 @@ def readings(x, y, hp, jobs, follow_trees=3, block_rows=1 << 19, ref=None):
         # leaves is steady from seed to seed and is what rounding every
         # gradient to a coarser grid moves.
         out["leaf_gap_median"] = float(np.median(np.concatenate(leaf_gaps)))
+    ref.seconds["trees"] = time.perf_counter() - t_trees
+    print("[reference.phases] " + json.dumps(dict(
+        ref.seconds, chips=len(ref.parts), blocks=ref.blocks)),
+        file=sys.stderr, flush=True)
     return out
 
 

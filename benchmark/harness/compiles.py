@@ -15,13 +15,15 @@ _HIT = "/jax/compilation_cache/cache_hits"
 class CompileCounter:
     def __init__(self) -> None:
         self.builds = 0
+        self.build_s = 0.0
         self.cache_hits = 0
         jax.monitoring.register_event_duration_secs_listener(self._on_time)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_time(self, event: str, _duration: float, **_kw) -> None:
+    def _on_time(self, event: str, duration: float, **_kw) -> None:
         if event == _BUILD:
             self.builds += 1
+            self.build_s += duration
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == _HIT:

@@ -2,7 +2,10 @@
 
 `rows` x `features` float32 columns drawn from a standard normal, a
 label, then 1 % NaN in columns 1 and 9, injected after the label so that
-no NaN leaks into it. Two labels, named by the configuration's `table`:
+no NaN leaks into it. Two labels, named by the configuration's `table`
+(any other name is a file of its own, `benchmark/tables/<table>.py`,
+whose `make_table(rows, features, seed)` keeps `make_table`'s contract
+below):
 
   binary_logit       a copy of `chip_smoke.make_data` (PR 21), the
                      Higgs-shaped table: a binary label from a fixed
@@ -53,15 +56,26 @@ def _fill_block(seed, block, x, y, kind) -> None:
     x[:, lo:hi] = xb
 
 
+def table_module(kind: str):
+    """The file that makes a table kind this one does not."""
+    from harness import manifest
+
+    try:
+        return manifest.named_module("tables", kind, ("make_table",))
+    except manifest.ManifestError as err:
+        raise manifest.ManifestError(
+            f"table kind {kind!r} is not one of {KINDS}, and {err}")
+
+
 def make_table(rows: int, features: int, seed: int, kind: str):
     """Returns (x, y): x float32 [features, rows] (column-major table),
     y [rows], int64 labels or float32 targets. The same arguments give
     the same bytes. `seed` is any whole number; its absolute value is
     used."""
+    if kind not in KINDS:
+        return table_module(kind).make_table(rows, features, seed)
     if features < 10:
         raise ValueError("the label and the NaN columns need 10 features")
-    if kind not in KINDS:
-        raise ValueError(f"table kind {kind!r} is not one of {KINDS}")
     x = np.empty((features, rows), np.float32)
     y = np.empty((rows,), np.int64 if kind == "binary_logit" else np.float32)
     blocks = range((rows + BLOCK_ROWS - 1) // BLOCK_ROWS)

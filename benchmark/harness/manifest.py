@@ -6,13 +6,19 @@ against the driver's rules for names, before a chip is asked for.
 A cell is found by name: its configuration at `configs/<config>.json`
 (the manifest's `file`), its traffic mix at `traffic/<traffic>.json`,
 its limits at `limits/<cell>.json`, and each per-layer metric's reader
-at `metrics/<metric>.py`. Adding any of them is adding files and
-entries; nothing here lists them.
+at `metrics/<metric>.py`. What a configuration's file names is found by
+name too: a `table` kind that `harness/datagen.py` does not make itself
+at `tables/<table>.py`, its `reference` block's `module` at
+`references/<module>.py`, its `learner` among the program's learners,
+and a cell's `chips` reach that learner as a mesh over them
+(`harness/runner.py` `learner_of`). Adding any of them is adding files
+and entries; nothing here lists them.
 """
 
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import os
 import re
@@ -198,6 +204,7 @@ def load(check_files=True):
                 if key not in cfg:
                     raise ManifestError(
                         f"config {c['name']}: reduced key {key!r} not in file")
+            config_names(cfg, c["name"])
         for w in cells.values():
             traffic_file(w["traffic"])
             load_json(f"benchmark/limits/{w['name']}.json")
@@ -218,6 +225,54 @@ def traffic_file(traffic):
         if os.path.exists(base + suffix):
             return base + suffix
     raise ManifestError(f"no traffic file for {traffic!r} under traffic/")
+
+
+def named_module(directory, name, gives):
+    """The module `<directory>/<name>.py` under the benchmark, which has
+    to define every name in `gives`. It is loaded by its path, so that no
+    installed package of the directory's name stands in its way."""
+    _name(name, f"{directory} file")
+    folder = os.path.join(BENCH, directory)
+    path = os.path.join(folder, name + ".py")
+    if not os.path.exists(path):
+        known = sorted(f[:-3] for f in os.listdir(folder)
+                       if f.endswith(".py")) if os.path.isdir(folder) else []
+        raise ManifestError(f"no benchmark/{directory}/{name}.py; "
+                            f"known: {known}")
+    key = f"benchmark_{directory}.{name}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        sys.modules[key] = module
+    module = sys.modules[key]
+    missing = [g for g in gives if not callable(getattr(module, g, None))]
+    if missing:
+        raise ManifestError(f"benchmark/{directory}/{name}.py lacks {missing}")
+    return module
+
+
+def config_names(config, config_name):
+    """Checks that what a configuration's file names by name is there:
+    its table's file, its reference's module, a well-formed learner (the
+    learner itself is looked up in the program, which this check does not
+    import)."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    from harness import datagen
+
+    try:
+        if config["table"] not in datagen.KINDS:
+            named_module("tables", config["table"], ("make_table",))
+        if "module" in config["reference"]:
+            named_module("references", config["reference"]["module"],
+                         ("forest_arrays", "readings"))
+    except ManifestError as err:
+        raise ManifestError(f"config {config_name}: harness/datagen.py makes "
+                            f"{datagen.KINDS}, harness/reference.py reads "
+                            f"where no module is named, and {err}")
+    _name(config.get("learner", "GradientBoostedTreesLearner"),
+          f"config {config_name}: learner")
 
 
 def reader(metric_name):

@@ -27,14 +27,21 @@ The rows are routed by the model's own conditions, as a served model's
 reference is run over the tokens that were served: a comparison tree by
 tree would fail sound runs on every near-tie between two cuts.
 
-All heavy sums run on the default device in row blocks, float32 at
-`highest` with exact one-hot operands; what is added across blocks is
-added in float64 on the host.
+All heavy sums run in row blocks, float32 at `highest` with exact
+one-hot operands; what is added across blocks is added in float64 on the
+host. The blocks are divided over the devices the reference is given
+(the cell's chips), consecutive blocks to a device, so that a table only
+four chips hold can be read: each device runs the same one-device
+programs over its own blocks, all devices at once, and their per-block
+partial sums are put end to end in block order before the host adds
+them. No mesh, no collective; with one device it is one part.
 """
 
 from __future__ import annotations
 
 import functools
+import os
+import time
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -65,8 +72,9 @@ def validation_mask(n: int, ratio: float, seed: int) -> np.ndarray:
 
 def _per_column(fn, columns):
     """[fn(i, column) ...]: numpy's sorts and sums release the
-    interpreter lock, so a few threads go through wide tables faster."""
-    with ThreadPoolExecutor(4) as pool:
+    interpreter lock, so a thread a core goes through wide tables faster
+    (four threads took 16 s over 224M x 28, half the host's part)."""
+    with ThreadPoolExecutor(min(16, os.cpu_count() or 4)) as pool:
         return list(pool.map(lambda ic: fn(*ic), enumerate(columns)))
 
 
@@ -221,15 +229,41 @@ def node_depths(left, right, is_leaf, num_nodes):
     return depth
 
 
+class _Part:
+    """One device's run of consecutive row blocks, [lo, hi)."""
+
+    def __init__(self, device, lo, hi):
+        self.device, self.lo, self.hi = device, lo, hi
+
+    def put(self, a):
+        return jax.device_put(a, self.device)
+
+
+def _in_block_order(results):
+    """The parts' per-block partial sums as one float64 array, blocks in
+    table order. Called after every part's program is enqueued, so the
+    devices work side by side while the host waits for the first."""
+    if len(results) == 1:
+        return np.asarray(results[0], np.float64)
+    out = np.empty((sum(r.shape[0] for r in results),) + results[0].shape[1:],
+                   np.float64)
+    lo = 0
+    for r in results:  # widened as it is copied in: no second copy
+        out[lo:lo + r.shape[0]] = np.asarray(r)
+        lo += r.shape[0]
+    return out
+
+
 class GbtReference:
     """Holds the reference's own bins and predictions for one table."""
 
-    def __init__(self, x, y, hp, block_rows=1 << 19):
+    def __init__(self, x, y, hp, block_rows=1 << 19, devices=None):
         """x: float32 [F, n] raw table; y: [n] targets, or labels in
         {0, 1} (class 1 of the model is the rarer label, by YDF's
         dictionary order); hp: the configuration's hyperparameters (loss,
         num_bins, validation_ratio, random_seed, shrinkage, max_depth,
-        max_frontier, min_examples, l2_regularization)."""
+        max_frontier, min_examples, l2_regularization); devices: the
+        chips to divide the row blocks over, the first device if None."""
         self.loss = hp["loss"]
         if self.loss not in ("binomial", "squared_error"):
             raise ValueError(f"no reference for loss {self.loss!r}")
@@ -239,32 +273,51 @@ class GbtReference:
         if block_rows % SUB:
             raise ValueError("block_rows must be a multiple of SUB")
         self.blocks = (self.n + block_rows - 1) // block_rows
+        devices = list(devices) if devices else [jax.devices()[0]]
+        cuts = [self.blocks * d // len(devices)
+                for d in range(len(devices) + 1)]
+        self.parts = [_Part(dev, lo, hi) for dev, lo, hi
+                      in zip(devices, cuts, cuts[1:]) if hi > lo]
+        t0 = time.perf_counter()
         pad = self.blocks * block_rows - self.n
         if self.loss == "binomial":
             counts = np.bincount(y, minlength=2)
             y = (y != int(np.argmax(counts))).astype(np.float32)
-        self.means = column_means(x)
-        self.edges = bin_edges(x, self.means, hp["num_bins"])
-        valid = validation_mask(self.n, hp["validation_ratio"],
-                                hp["random_seed"])
         shape = (self.blocks, block_rows)
 
-        def on_device(a, dtype):
-            return jnp.asarray(np.pad(a.astype(dtype), (0, pad)).reshape(shape))
+        def on_devices(a, dtype):
+            a = np.pad(a.astype(dtype), (0, pad)).reshape(shape)
+            return [p.put(a[p.lo:p.hi]) for p in self.parts]
 
-        self.y = on_device(y, np.float32)
-        self.w_tr = on_device(~valid, np.float32)
-        self.w_va = on_device(valid, np.float32)
+        def binned(p):  # a part's bins, [F, its blocks, block] uint8
+            means, edges = p.put(self.means), p.put(self.edges)
+            cols = []
+            for b in range(p.lo, p.hi):
+                lo = b * block_rows
+                xb = np.zeros((self.F, block_rows), np.float32)
+                xb[:, :min(block_rows, self.n - lo)] = x[:, lo:lo + block_rows]
+                cols.append(_bin_block(p.put(xb), means, edges))
+            return jnp.stack(cols, axis=1)
+
+        # The split's permutation of all rows (17 s at 224M) runs beside
+        # the bins; a thread a part sends them: one thread's cutting and
+        # sending of 58 MB blocks feeds one chip at 0.6 GB/s.
+        with ThreadPoolExecutor(len(self.parts) + 1) as pool:
+            split = pool.submit(validation_mask, self.n,
+                                hp["validation_ratio"], hp["random_seed"])
+            self.means = column_means(x)
+            self.edges = bin_edges(x, self.means, hp["num_bins"])
+            t1 = time.perf_counter()
+            self.bins = list(pool.map(binned, self.parts))
+            valid = split.result()
+        self.y = on_devices(y, np.float32)
+        self.w_tr = on_devices(~valid, np.float32)
+        self.w_va = on_devices(valid, np.float32)
         self.n_tr = float(self.n - valid.sum())
         self.n_va = float(valid.sum())
-        means, edges = jnp.asarray(self.means), jnp.asarray(self.edges)
-        cols = []
-        for b in range(self.blocks):
-            lo = b * block_rows
-            xb = np.zeros((self.F, block_rows), np.float32)
-            xb[:, :min(block_rows, self.n - lo)] = x[:, lo:lo + block_rows]
-            cols.append(_bin_block(jnp.asarray(xb), means, edges))
-        self.bins = jnp.stack(cols, axis=1)  # [F, blocks, block] uint8
+        jax.block_until_ready((self.bins, self.y, self.w_tr, self.w_va))
+        self.seconds = {"host_bins": t1 - t0,
+                        "upload": time.perf_counter() - t1}
         mean = float(y[~valid].mean(dtype=np.float64))
         if self.loss == "binomial":
             p = min(max(mean, EPS), 1 - EPS)
@@ -274,8 +327,9 @@ class GbtReference:
 
     def reset(self):
         """Back to before the first tree."""
-        self.pred = jnp.full((self.blocks, self.block),
-                             self.initial_prediction, jnp.float32)
+        self.pred = [
+            jnp.full((p.hi - p.lo, self.block), self.initial_prediction,
+                     jnp.float32, device=p.device) for p in self.parts]
 
     # -- one tree of the model ------------------------------------------
 
@@ -310,14 +364,16 @@ class GbtReference:
         right = np.where(is_leaf, 0, tree["right"]).astype(np.int32)
         depth = node_depths(left, right, is_leaf, N)
         thr_bin, off_grid = self._grid_bins(tree)
-        tables = [jnp.asarray(a) for a in (
-            np.where(is_leaf, 0, tree["feature"]).astype(np.int32),
-            thr_bin, left, right, is_leaf)]
-        node = jnp.zeros((self.blocks, self.block), jnp.int32)
+        tables = (np.where(is_leaf, 0, tree["feature"]).astype(np.int32),
+                  thr_bin, left, right, is_leaf)
+        tables = [[p.put(a) for a in tables] for p in self.parts]
+        node = [jnp.zeros((p.hi - p.lo, self.block), jnp.int32,
+                          device=p.device) for p in self.parts]
         at_depth = []
         for _ in range(int(depth.max())):
             at_depth.append(node)
-            node = _route_step(node, self.bins, *tables)
+            node = [_route_step(nd, bins, *tb)
+                    for nd, bins, tb in zip(node, self.bins, tables)]
         out = {"thresholds_off_grid": off_grid}
         if with_regret:
             out["split_regret"] = self._regret(
@@ -329,9 +385,11 @@ class GbtReference:
             raise ValueError(f"{len(leaves)} leaves exceed {LEAF_PAD}")
         slot_of = np.full(len(is_leaf), LEAF_PAD, np.int32)
         slot_of[leaves] = np.arange(len(leaves))
-        parts = _leaf_sums(_lookup(jnp.asarray(slot_of), node),
-                           self.pred, self.y, self.w_tr, loss=self.loss)
-        sums = np.asarray(parts, np.float64).sum(axis=(0, 1))[:len(leaves)]
+        sums = _in_block_order([
+            _leaf_sums(_lookup(p.put(slot_of), nd), pred, y, w, loss=self.loss)
+            for p, nd, pred, y, w
+            in zip(self.parts, node, self.pred, self.y, self.w_tr)])
+        sums = sums.sum(axis=(0, 1))[:len(leaves)]
         ref = -hp["shrinkage"] * sums[:, 0] / (
             sums[:, 1] + hp["l2_regularization"] + EPS)
         got = np.asarray(tree["leaf_value"], np.float64)[leaves]
@@ -344,10 +402,12 @@ class GbtReference:
 
         values = np.zeros(len(is_leaf), np.float32)
         values[leaves] = ref
-        self.pred = _add_leaf_values(self.pred, node, jnp.asarray(values))
-        sums = np.asarray(
-            _loss_sums(self.pred, self.y, self.w_tr, self.w_va,
-                       loss=self.loss), np.float64).sum(axis=(0, 1))
+        self.pred = [_add_leaf_values(pred, nd, p.put(values))
+                     for p, pred, nd in zip(self.parts, self.pred, node)]
+        sums = _in_block_order([
+            _loss_sums(pred, y, w_tr, w_va, loss=self.loss)
+            for pred, y, w_tr, w_va
+            in zip(self.pred, self.y, self.w_tr, self.w_va)]).sum(axis=(0, 1))
 
         def reported(total, n):
             mean = total / (n + EPS)
@@ -372,10 +432,12 @@ class GbtReference:
                 group = ids[lo:lo + SLOTS]
                 slot_of = np.full(len(is_leaf), -1, np.int32)
                 slot_of[group] = np.arange(len(group))
-                parts = _level_hist(
-                    self.bins, _lookup(jnp.asarray(slot_of), node),
-                    self.pred, self.y, self.w_tr, num_bins=B, loss=self.loss)
-                h = np.asarray(parts, np.float64).sum(axis=0)
+                h = _in_block_order([
+                    _level_hist(bins, _lookup(p.put(slot_of), nd), pred, y, w,
+                                num_bins=B, loss=self.loss)
+                    for p, bins, nd, pred, y, w in zip(
+                        self.parts, self.bins, node, self.pred, self.y,
+                        self.w_tr)]).sum(axis=0)
                 h = h.reshape(self.F, B, SLOTS, 3).transpose(2, 0, 1, 3)
                 for s, i in enumerate(group):
                     lt = np.cumsum(h[s], axis=1)[:, :-1]  # bin <= t

@@ -5,7 +5,10 @@ calls `run_cell`; the fault tests call it without that look.
 The traffic generator is general: it reads the mix's file (closed loop,
 how many warm jobs, whether jobs share one ingested `ydf.Dataset` or
 ingest a fresh table each) and the configuration's file (shapes and
-hyperparameters) and drives `GradientBoostedTreesLearner.train()`.
+hyperparameters) and drives the `train()` of the learner the
+configuration names (`GradientBoostedTreesLearner` where it names none).
+A cell of more than one chip hands the learner its chips as the program
+takes them, `mesh=ydf.make_mesh(devices)`: every chip takes rows.
 """
 
 from __future__ import annotations
@@ -30,18 +33,36 @@ def log(tag, **fields):
           flush=True)
 
 
-class Traffic:
-    """Closed-loop training jobs from a mix's parameters."""
+def learner_of(ydf, config, devices=None):
+    """What builds the configuration's learner on the cell's chips, from
+    the configuration's file alone: call it once a job. One chip: the
+    learner as it always was built, no `mesh` handed. More: the program's
+    mesh over exactly those devices, every chip taking rows."""
+    name = config.get("learner", "GradientBoostedTreesLearner")
+    if not (name.endswith("Learner") and hasattr(ydf, name)):
+        known = sorted(n for n in dir(ydf) if n.endswith("Learner"))
+        raise ValueError(f"no learner {name!r} in the program; "
+                         f"known: {known}")
+    hp = dict(config["hyperparameters"])
+    hp["task"] = ydf.Task[hp["task"]]
+    if devices is not None and len(devices) > 1:
+        hp["mesh"] = ydf.make_mesh(list(devices))
+    return lambda: getattr(ydf, name)(label="label", **hp)
 
-    def __init__(self, config, mix, seed):
+
+class Traffic:
+    """Closed-loop training jobs from a mix's parameters, on the cell's
+    `devices` (None: the program's default, one chip)."""
+
+    def __init__(self, config, mix, seed, devices=None):
         import ydf_tpu as ydf
 
         if mix["loop"] != "closed" or mix["clients"] != 1:
             raise ValueError("this generator drives one closed-loop client")
         self.ydf, self.config, self.mix, self.seed = ydf, config, mix, seed
         self.rows, self.features = config["rows"], config["features"]
-        self.hp = dict(config["hyperparameters"])
-        self.hp["task"] = ydf.Task[self.hp["task"]]
+        self.new_learner = learner_of(ydf, config, devices)
+        self.reference = compare.of_config(config)
         self.started = 0
         self.table = self._table(seed)
         self.shared = None
@@ -61,15 +82,14 @@ class Traffic:
         if self.shared is None:  # a fresh table a job, ingest inside it
             self.table = self._table(self.seed + self.started)
         ds = self.shared or self._ingest(self.table)
-        model = self.ydf.GradientBoostedTreesLearner(
-            label="label", **self.hp).train(ds)
+        model = self.new_learner().train(ds)
         t1 = time.perf_counter()
         return {
             "t0": t0, "t1": t1, "rows": self.rows,
             "trees": int(model.num_trees()),
             "profile": dict(model.training_profile),
             "implementations": model.training_logs["implementations"],
-            "arrays": compare.forest_arrays(model),
+            "arrays": self.reference.forest_arrays(model),
         }
 
     def release(self):
@@ -106,7 +126,7 @@ def run_cell(m, cell_name, seed, seconds, trace, t_start, check_kwargs=None,
         config=entry["file"])
 
     # ---- set-up: table, ingest, warm jobs of the cell's own shape -------
-    traffic = Traffic(config, mix, seed)
+    traffic = Traffic(config, mix, seed, devices[:cell["chips"]])
     t_table = time.time() - t_start
     for _ in range(mix["warm_jobs"]):
         warm = traffic.job()
@@ -152,17 +172,19 @@ def run_cell(m, cell_name, seed, seconds, trace, t_start, check_kwargs=None,
     # ---- correct: the plain reference over what the window produced ------
     table = traffic.release()
     t_ref = time.perf_counter()
-    numbers = compare.readings(
+    numbers = traffic.reference.readings(
         table[0], table[1], config["reference"],
         [j["arrays"] for j in jobs],
-        follow_trees=min(3, config["num_trees"]), **(check_kwargs or {}))
+        follow_trees=min(3, config["num_trees"]),
+        devices=devices[:cell["chips"]], **(check_kwargs or {}))
     numbers["programs_built_in_window"] = builds_in_window
     correct, compared = compare.judge(numbers, limits)
     log("reference", seconds=time.perf_counter() - t_ref)
 
     run = {"jobs": jobs, "window_s": window_s, "row_trees": row_trees,
            "config": config, "mix": mix, "loop_stats": loop_stats,
-           "memory": memory, "device_kind": device["kind"], "trace": None}
+           "memory": memory, "device_kind": device["kind"],
+           "chips": cell["chips"], "trace": None}
     breakdown = None
     if trace:
         t_red = time.perf_counter()

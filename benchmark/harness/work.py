@@ -40,9 +40,12 @@ def tree_bytes_and_ops(train_rows: int, features: int, levels: int):
             levels * train_rows * features * ADDS_PER_CELL)
 
 
-def least_seconds_per_tree(train_rows, features, levels, peaks):
-    """(seconds, what bounds it)."""
+def least_seconds_per_tree(train_rows, features, levels, peaks, chips=1):
+    """(seconds, what bounds it). `peaks` are one chip's; the rows of a
+    cell on `chips` chips are divided over them, and bytes and operations
+    both go by the row, so the least time is a `chips`-th of one chip's."""
     nbytes, ops = tree_bytes_and_ops(train_rows, features, levels)
     by_bytes = nbytes / peaks["hbm_bytes_per_s"]
     by_ops = ops / peaks["flops_per_s"]
-    return max(by_bytes, by_ops), ("bytes" if by_bytes >= by_ops else "ops")
+    return (max(by_bytes, by_ops) / chips,
+            "bytes" if by_bytes >= by_ops else "ops")

@@ -12,9 +12,10 @@ ROWS = 40_000
 
 
 def small_files(cell_name="synth100_gbt.sweep", rows=ROWS, depth=4,
-                features=28, frontier=None, table=None):
+                features=28, frontier=None, table=None, chips=1):
     m = manifest.load()
     files = list(manifest.cell_files(m, cell_name))
+    files[0] = dict(files[0], chips=chips)  # the reference's devices
     cfg = copy.deepcopy(files[2])
     cfg["rows"], cfg["features"] = rows, features
     if table:  # the binary table and loss, through the same harness

@@ -19,17 +19,18 @@ SCRIPT = """
 import json, sys
 sys.path[:0] = [{root!r}, {bench!r}]
 from tests.small import run_small
-r = run_small(seed=17)
+r = run_small(seed=17, chips={chips})
 print(json.dumps({{"correct": r["correct"], "compared": r["compared"]}}))
 """
 
 
-def run_with(quant):
+def run_with(quant, chips=1):
     bench = os.path.dirname(HERE)
     env = dict(os.environ, YDF_TPU_HIST_QUANT=quant, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c",
-         SCRIPT.format(root=os.path.dirname(bench), bench=bench)],
+         SCRIPT.format(root=os.path.dirname(bench), bench=bench,
+                       chips=chips)],
         env=env, capture_output=True, text=True, timeout=600, check=True)
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -38,3 +39,8 @@ def run_with(quant):
 def test_control(quant, correct):
     got = run_with(quant)
     assert got["correct"] is correct, got["compared"]
+
+
+def test_control_is_not_correct_with_the_reference_over_four_devices():
+    got = run_with("int8", chips=4)
+    assert got["correct"] is False, got["compared"]

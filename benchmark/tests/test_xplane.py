@@ -1,12 +1,14 @@
 """The trace reduction on a small trace recorded on the chip (a v5e, two
 one-tree jobs of 20,000 rows with a 50 ms pause after each, PR 25's
-sizing probe) and on intervals worked by hand."""
+sizing probe), on intervals worked by hand, and on that trace with a
+second, less busy device plane beside the first (a four-chip cell's)."""
 
 import os
 
 import pytest
 
 from harness import xplane
+from tests.two_planes import with_second_device
 
 TRACE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "data", "small_trace.xplane.pb")
@@ -55,3 +57,26 @@ def test_recorded_trace():
 
 def test_a_trace_without_the_span_gives_nothing():
     assert xplane.reduce(TRACE, "no_such_span", ["job"]) is None
+
+
+def test_two_device_planes_mean_busy_and_name_the_busier(tmp_path):
+    one = xplane.reduce(TRACE, "job", ["job", "between_jobs"])
+    with open(TRACE, "rb") as f:
+        both = with_second_device(f.read())
+    path = tmp_path / "two.xplane.pb"
+    path.write_bytes(both)
+    events = xplane.device_op_events(xplane.load(str(path)))
+    assert list(events) == ["/device:TPU:0", "/device:TPU:1"]
+    assert [len(v) for v in events.values()] == [1544, 772]
+    lo = min(s for s, _, _ in events["/device:TPU:0"])
+    hi = max(e for _, e, _ in events["/device:TPU:0"])
+    alone = [xplane.busy_seconds({d: ev}, lo, hi) for d, ev in events.items()]
+    assert alone[1] < alone[0]
+    two = xplane.reduce(str(path), "job", ["job", "between_jobs"])
+    assert two["devices"] == 2 and two["n_device_events"] == 1544 + 772
+    assert two["window_s"] == one["window_s"]
+    assert one["busy_s"] == pytest.approx(alone[0], rel=1e-9)
+    assert two["busy_s"] == pytest.approx((alone[0] + alone[1]) / 2, rel=1e-9)
+    # operations and gaps are the busier plane's: those of the one-chip trace
+    assert two["device_ops"] == one["device_ops"]
+    assert two["idle_gaps"] == one["idle_gaps"]

@@ -43,6 +43,7 @@ def main():
     from harness import compare, manifest
     from harness.datagen import as_columns, make_table
     from harness.reference import GbtReference
+    from harness.runner import learner_of
 
     if jax.devices()[0].platform != "tpu" and not args.rows:
         sys.exit("limits: no TPU (use --rows for a CPU rehearsal)")
@@ -60,15 +61,13 @@ def main():
         out.write(line + "\n")
         out.flush()
 
-    def train(config, ds, quant=None):
+    def train(cell, config, ds, quant=None):
         if quant:
             os.environ["YDF_TPU_HIST_QUANT"] = quant
         gbt._make_boost_fn.cache_clear()  # the switch is read when tracing
         try:
-            hp = dict(config["hyperparameters"])
-            hp["task"] = ydf.Task[hp["task"]]
-            model = ydf.GradientBoostedTreesLearner(
-                label="label", **hp).train(ds)
+            model = learner_of(
+                ydf, config, jax.devices()[:cell["chips"]])().train(ds)
             impl = model.training_logs["implementations"]
             return compare.forest_arrays(model), impl
         finally:
@@ -82,11 +81,13 @@ def main():
         x, y = make_table(rows, first["features"], seed, first["table"])
         ds = ydf.Dataset.from_data(as_columns(x, y), label="label")
         ref = None
-        for name, (_, _, config, _, _) in cells.items():
+        for name, (cell, _, config, _, _) in cells.items():
             hp = config["reference"]
             follow = min(3, config["num_trees"])
-            if ref is None or ref.hp["max_depth"] != hp["max_depth"]:
-                ref = GbtReference(x, y, hp, block_rows=args.block_rows)
+            if (ref is None or ref.hp["max_depth"] != hp["max_depth"]
+                    or len(ref.parts) != cell["chips"]):
+                ref = GbtReference(x, y, hp, block_rows=args.block_rows,
+                                   devices=jax.devices()[:cell["chips"]])
             ref.hp = hp
 
             def read(arrays, kind, impl=None):
@@ -100,16 +101,16 @@ def main():
             for kind in kinds if nth < args.control_seeds else ["sound"]:
                 t0 = time.perf_counter()
                 if kind == "sound":
-                    sound, impl = train(config, ds)
+                    sound, impl = train(cell, config, ds)
                     read(sound, kind, impl)
                 elif kind in ("bf16x2", "int8"):
-                    arrays, impl = train(config, ds, quant=kind)
+                    arrays, impl = train(cell, config, ds, quant=kind)
                     read(arrays, kind, impl)
                 elif kind == "half_batch":
                     half = ydf.Dataset.from_data(
                         as_columns(x[:, : rows // 2], y[: rows // 2]),
                         label="label")
-                    read(train(config, half)[0], kind)
+                    read(train(cell, config, half)[0], kind)
                     del half
                 elif kind == "split_altered":
                     # the root of tree 1 cut one bin further up

@@ -1,8 +1,10 @@
 #!/usr/bin/env python3
 """What can be checked with no chip, in one command: the manifest and
-every file it names against the driver's rules, the trace reduction on
-the recorded trace, `train_mfu_pct`'s arithmetic on hand-worked shapes.
-It prints counts and verdicts, never a device metric.
+every file it names against the driver's rules (each configuration's
+table file, reference module and learner name among them), the look-ups
+by name on stand-in files, the trace reduction on the recorded trace and
+on two device planes, `train_mfu_pct`'s arithmetic on hand-worked shapes
+and on four chips. It prints counts and verdicts, never a device metric.
 
     JAX_PLATFORMS=cpu python3 benchmark/tools/selfcheck.py
 """
@@ -21,6 +23,11 @@ def main():
     m = manifest.load()
     print(f"manifest: {len(m['workloads'])} cells, {len(m['per_layer'])} "
           "per-layer metrics, every name, unit and file in order")
+    for c in m["configs"]:
+        cfg = manifest.load_json(c["file"])
+        print(f"config {c['name']}: table {cfg['table']!r}, reference "
+              f"{cfg['reference'].get('module', 'harness/reference.py')!r}, "
+              f"learner {cfg.get('learner', 'GradientBoostedTreesLearner')!r}")
     tests = [os.path.join(BENCH, "tests", t) for t in
              ("test_manifest.py", "test_xplane.py", "test_work.py")]
     sys.exit(subprocess.call(

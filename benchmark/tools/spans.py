@@ -43,8 +43,8 @@ def traced_job(workload, seed, trace_dir):
     # operation's metadata out, so an executable cached before a scope
     # was named (or renamed) is served with the names it was built with.
     jax.config.update("jax_enable_compilation_cache", False)
-    _, _, config, mix, _ = manifest.cell_files(manifest.load(), workload)
-    traffic = Traffic(config, mix, seed)
+    cell, _, config, mix, _ = manifest.cell_files(manifest.load(), workload)
+    traffic = Traffic(config, mix, seed, jax.devices()[:cell["chips"]])
     for _ in range(mix["warm_jobs"]):
         traffic.job()
     shutil.rmtree(trace_dir, ignore_errors=True)
