@@ -181,15 +181,12 @@ def bypass_cases():
     return {
         "dict": lambda: dict(data=cols),
         "valid": lambda: dict(data=make_dataset(), valid=columns(seed=3)),
-        "ranking": lambda: dict(
-            data=ydf.Dataset.from_data(cols), kind="regression",
-            task=Task.RANKING, ranking_group="q"),
         "oblique": lambda: dict(
             data=make_dataset(), split_axis="SPARSE_OBLIQUE"),
     }
 
 
-@pytest.mark.parametrize("case", ["dict", "valid", "ranking", "oblique"])
+@pytest.mark.parametrize("case", ["dict", "valid", "oblique"])
 def test_what_bypasses_never_hits(case):
     kw = bypass_cases()[case]()
     data = kw.pop("data")
@@ -199,6 +196,23 @@ def test_what_bypasses_never_hits(case):
     assert sent_first > 0 and sent_second == sent_first
     assert dataset_lib._device_resident() is None
     assert_same_forest(first, second)
+
+
+def test_a_ranking_job_keeps_its_inputs_and_its_query_structure():
+    """Since PR 36 a ranking job hits too: its rows go by query and the
+    query structure is kept beside the six arrays (the whole of it:
+    tests/test_ranking_reference.py)."""
+    ds = ydf.Dataset.from_data(columns())
+    kw = dict(kind="regression", task=Task.RANKING, ranking_group="q")
+    first, sent_first = job(ds, **kw)
+    second, sent_second = job(ds, **kw)
+    assert cached(first) == 0 and sent_first > 0
+    assert cached(second) == 1 and sent_second == 0
+    assert len(holder(ds)._device_inputs[2]) == 7
+    assert holder(ds).device_inputs_bytes() == sent_first
+    assert_same_forest(first, second)
+    third, sent_third = job(ds, ranking_max_group_size=16, **kw)  # another cap
+    assert cached(third) == 0 and sent_third > 0
 
 
 def test_a_job_that_bypasses_lets_go_of_the_kept_inputs():

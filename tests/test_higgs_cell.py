@@ -211,9 +211,11 @@ def test_forest_is_the_parents_bit_for_bit(monkeypatch):
 
 def test_manifest_holds_the_configuration_and_its_cell():
     m = manifest.load()
-    assert len(m["configs"]) == 2 and len(m["workloads"]) == 2
-    assert len(m["per_layer"]) == 13
-    assert m["per_layer"][-1]["name"] == "program_build_s"
+    assert len(m["configs"]) == 3 and len(m["workloads"]) == 3  # PR 36
+    assert len(m["per_layer"]) == 15
+    assert m["per_layer"][12]["name"] == "program_build_s"
+    assert [e["workloads"] for e in m["per_layer"][13:]] == [
+        ["mslr30k_rank.sweep"]] * 2
     cell, entry, cfg, mix, limits = manifest.cell_files(m, CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "sweep")
     assert entry["reduced"] == ["num_trees", "rows"]

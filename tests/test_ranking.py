@@ -5,18 +5,23 @@ import pytest
 
 import ydf_tpu as ydf
 from ydf_tpu.config import Task
-from ydf_tpu.learners.ranking_loss import build_group_rows
+from ydf_tpu.learners.ranking_loss import build_rank_groups
 
 D = "/root/reference/yggdrasil_decision_forests/test_data/dataset"
 
 
-def test_build_group_rows():
-    groups = np.array(["b", "a", "b", "c", "a", "b"])
-    rows, G = build_group_rows(groups)
-    assert G == 3
-    # group "a" → rows 1, 4 ; "b" → 0, 2, 5 ; "c" → 3
-    sets = [set(r[r >= 0].tolist()) for r in rows]
-    assert {1, 4} in sets and {0, 2, 5} in sets and {3} in sets
+def test_build_rank_groups():
+    # rows ordered by query: "a" -> rows 0, 1 ; "b" -> 2, 3, 4 ; "c" -> 5
+    groups, facts = build_rank_groups(np.array([0, 0, 1, 1, 1, 2]))
+    assert [len(lane) for lane in groups.lanes] == [1, 2, 4]
+    assert [s.tolist() for s in groups.starts] == [[5], [0], [2]]
+    assert [s.tolist() for s in groups.sizes] == [[1], [2], [3]]
+    # slots: bucket 1 holds 1, bucket 2 holds 2, bucket 4 holds 4 (3 used)
+    assert groups.slot.tolist() == [1, 2, 3, 4, 5, 0]
+    assert facts["rank_pairs"] == 1 + 4 + 9 == facts["rank_pairs_all"]
+    assert facts["rank_pair_slots"] == 1 + 4 + 16
+    with pytest.raises(ValueError, match="ordered by query"):
+        build_rank_groups(np.array([1, 0, 1]))
 
 
 def test_gbt_ranking_synthetic_dataset():

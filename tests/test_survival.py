@@ -186,12 +186,13 @@ def test_cep_tracks_label_means():
 
 
 def test_ranking_group_truncation_warns():
-    from ydf_tpu.learners.ranking_loss import build_group_rows
+    from ydf_tpu.learners.ranking_loss import build_rank_groups
 
-    groups = np.array([0] * 10 + [1] * 3)
+    codes = np.array([0] * 10 + [1] * 3)
     with pytest.warns(UserWarning, match="max_group_size"):
-        rows, G = build_group_rows(groups, max_group_size=4)
-    assert G == 4
+        groups, _ = build_rank_groups(codes, max_group_size=4)
+    assert [len(lane) for lane in groups.lanes] == [4]
+    assert groups.sizes[0].tolist() == [4, 3]
 
 
 def _naive_cox_weighted(preds, departure, event, entry, w):

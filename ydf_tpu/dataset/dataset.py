@@ -225,7 +225,15 @@ class Dataset:
         """Device bytes of the kept job inputs (the memory ledger's
         "device_inputs" row)."""
         entry = self._device_inputs
-        return sum(int(a.nbytes) for a in entry[2]) if entry else 0
+        if not entry:
+            return 0
+        import jax
+
+        # (a ranking job keeps its query structure, a tree of arrays and
+        # plain numbers, beside the six)
+        return sum(
+            int(getattr(a, "nbytes", 0)) for a in jax.tree.leaves(entry[2])
+        )
 
     # ---- binning memo (see dataset/binning.py) ----------------------- #
 

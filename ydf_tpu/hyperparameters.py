@@ -187,8 +187,9 @@ _PARAM_INFO: Dict[str, _Info] = {
     "ndcg_truncation": _Info(
         "NDCG@k truncation for the lambdarank loss.", min_value=1),
     "ranking_max_group_size": _Info(
-        "Cap on documents per query group in the dense [groups, size] "
-        "device layout; larger groups are truncated with a warning.",
+        "Cap on the documents of a query group that train; a longer "
+        "group keeps its first ones, with a warning. None (the default): "
+        "every document of every group trains.",
         min_value=1),
     "sampling_method": _Info(
         "Per-iteration example sampling: RANDOM (uses `subsample`), GOSS "
@@ -338,6 +339,8 @@ def _type_of(default: Any, annotation: Any) -> str:
         return "float"
     if isinstance(default, str):
         return "str"
+    if default is None and str(annotation) == "Optional[int]":
+        return "int"  # None: no limit (ranking_max_group_size)
     return "object"
 
 

@@ -25,6 +25,7 @@ final truncation.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import os
 import signal
@@ -99,7 +100,7 @@ class GradientBoostedTreesLearner(GenericLearner):
         loss: str = "DEFAULT",
         ranking_group: Optional[str] = None,
         ndcg_truncation: int = 5,
-        ranking_max_group_size: int = 2048,
+        ranking_max_group_size: Optional[int] = None,
         label_event_observed: Optional[str] = None,
         label_entry_age: Optional[str] = None,
         max_frontier="auto",
@@ -166,8 +167,9 @@ class GradientBoostedTreesLearner(GenericLearner):
         self.loss = loss
         self.ranking_group = ranking_group
         self.ndcg_truncation = ndcg_truncation
-        # Cap on documents per query group in the dense [G, Gmax] layout;
-        # larger groups are truncated with a warning (build_group_rows).
+        # Cap on the documents of a query that train (None: every one
+        # does); a longer query keeps its first ones, with a warning
+        # (ranking_loss.build_rank_groups).
         self.ranking_max_group_size = ranking_max_group_size
         # Survival analysis (reference train config label_event_observed /
         # label_entry_age, Cox loss loss_imp_cox.cc): the label column is
@@ -389,11 +391,44 @@ class GradientBoostedTreesLearner(GenericLearner):
         n = bins_all.shape[0]
         num_classes = len(prep.get("classes", [])) or 1
 
-        group_values = None
-        if self.task == Task.RANKING:
-            if self.ranking_group is None:
-                raise ValueError("Task.RANKING requires ranking_group=")
-            group_values = np.asarray(prep["dataset"].data[self.ranking_group])
+        from ydf_tpu.learners.losses import CustomLoss
+        from ydf_tpu.learners.ranking_loss import (
+            LambdaMartNdcg,
+            build_rank_groups,
+        )
+
+        if isinstance(self.loss, CustomLoss):
+            loss_obj = self.loss
+        else:
+            loss_obj = make_loss(self.loss, self.task, num_classes)
+        grouped = isinstance(loss_obj, LambdaMartNdcg)
+        if grouped:
+            # Non-NDCG losses (e.g. SQUARED_ERROR on a ranking task) need no
+            # group structure and skip all of it.
+            if self.task != Task.RANKING:
+                raise ValueError(
+                    f"{loss_obj.name} requires task=Task.RANKING"
+                )
+            loss_obj = dataclasses.replace(
+                loss_obj, ndcg_truncation=self.ndcg_truncation
+            )
+        if self.task == Task.RANKING and self.ranking_group is None:
+            raise ValueError("Task.RANKING requires ranking_group=")
+
+        # The query structure of a group-structured loss, as the compiled
+        # program takes it: (training RankGroups, validation RankGroups or
+        # None, the counters' facts). It is kept with the Dataset beside
+        # the six arrays, so only a job that makes them makes it: the
+        # rows ordered by query here, bucketed and sent below.
+        rank = None
+        codes_all = order_all = None
+        with timer.stage("rank_groups"):
+            if kept is not None:
+                rank = kept[6] if len(kept) > 6 else None
+            elif self.task == Task.RANKING:
+                codes_all, order_all = _rows_by_query(
+                    prep["dataset"].data[self.ranking_group], ordered=grouped
+                )
 
         ev_all = en_all = None
         if self.task == Task.SURVIVAL_ANALYSIS:
@@ -411,8 +446,11 @@ class GradientBoostedTreesLearner(GenericLearner):
 
         # --- validation extraction (reference :1243): deterministic split
         # of the training set, unless an explicit valid dataset is given.
-        # Ranking splits whole query groups, like the reference.
-        tr_groups = va_groups = None
+        # Ranking splits whole query groups, like the reference; under a
+        # group-structured loss both parts' rows go by query (tr_codes,
+        # va_codes: each row's query, non-decreasing).
+        tr_codes = va_codes = None
+        tr_order = va_order = None  # of a part whose rows no split gathers
         set_tr = set_va = None
         vs_all = prep.get("vs")  # (values, lengths, missing) or None
         vs_tr = vs_va = None  # (values, lengths) pairs
@@ -420,7 +458,7 @@ class GradientBoostedTreesLearner(GenericLearner):
             vs_all = (vs_all[0], vs_all[1])
         with timer.stage("split"):
             if kept is not None:
-                bins_tr, y_tr, w_tr, bins_va, y_va, w_va = kept
+                bins_tr, y_tr, w_tr, bins_va, y_va, w_va = kept[:6]
             elif "valid_bins" in prep:
                 bins_tr, y_tr, w_tr = bins_all, labels_all, w_all
                 bins_va = prep["valid_bins"]
@@ -433,11 +471,13 @@ class GradientBoostedTreesLearner(GenericLearner):
                     vs_tr = vs_all
                     vv = prep.get("valid_vs")
                     vs_va = (vv[0], vv[1]) if vv is not None else None
-                tr_groups = group_values
-                if self.task == Task.RANKING:
-                    va_groups = np.asarray(
-                        prep["valid_dataset"].data[self.ranking_group]
+                if grouped:
+                    tr_order = order_all
+                    va_codes, va_order = _rows_by_query(
+                        prep["valid_dataset"].data[self.ranking_group],
+                        ordered=True,
                     )
+                    va_codes = va_codes[va_order]
             elif (
                 self.validation_ratio > 0
                 and self.early_stopping != "NONE"
@@ -451,28 +491,55 @@ class GradientBoostedTreesLearner(GenericLearner):
                 # ships index sets; feature-parallel still rejects
                 # validation with its targeted error.
                 rng = np.random.RandomState(self.random_seed)
-                if group_values is not None:
-                    uniq = np.unique(group_values)
-                    # Never consume every group (nor zero): a single-group
-                    # dataset trains without validation rather than on nothing.
+                if codes_all is not None:
+                    # The queries are numbered by their sorted distinct
+                    # ids; the first `nvg` of a seeded permutation of
+                    # those numbers validate. Never every query (nor
+                    # none): a single-query dataset trains without
+                    # validation rather than on nothing.
+                    nq = int(codes_all.max()) + 1
                     nvg = min(
-                        max(int(len(uniq) * self.validation_ratio), 1),
-                        len(uniq) - 1,
+                        max(int(nq * self.validation_ratio), 1), nq - 1
                     )
-                    gperm = rng.permutation(len(uniq))
-                    va_mask = np.isin(group_values, uniq[gperm[:nvg]])
-                    va_idx = np.flatnonzero(va_mask)
-                    tr_idx = np.flatnonzero(~va_mask)
-                    tr_groups = group_values[tr_idx]
-                    va_groups = group_values[va_idx]
-                    bins_tr, bins_va = bins_all[tr_idx], bins_all[va_idx]
+                    va_query = np.zeros((nq,), bool)
+                    va_query[rng.permutation(nq)[:nvg]] = True
+                    if grouped:
+                        in_va = va_query[codes_all[order_all]]
+                        tr_idx, va_idx = order_all[~in_va], order_all[in_va]
+                        tr_codes, va_codes = codes_all[tr_idx], codes_all[va_idx]
+                    else:
+                        in_va = va_query[codes_all]
+                        va_idx = np.flatnonzero(in_va)
+                        tr_idx = np.flatnonzero(~in_va)
+                    rows_tr, rows_va = len(tr_idx), len(va_idx)
+                    if (
+                        grouped
+                        and self.split_axis == "AXIS_ALIGNED"
+                        and set_all is None
+                        and vs_all is None
+                    ):
+                        # Whole queries split, so how many rows train
+                        # goes by the table; the program's shapes should
+                        # not: both parts get a capacity (zero-weight
+                        # rows of no query at their ends).
+                        rows_tr, rows_va = _split_capacities(
+                            n, rows_tr, rows_va, self.validation_ratio
+                        )
+                    bins_tr = _take_rows(bins_all, tr_idx, rows_tr)
+                    bins_va = _take_rows(bins_all, va_idx, rows_va)
                 else:
                     tr_idx, va_idx, bins_tr, bins_va = _split_rows(
                         prep.get("dataset"), bins_all, rng,
                         self.random_seed, self.validation_ratio,
                     )
-                y_tr, w_tr = labels_all[tr_idx], w_all[tr_idx]
-                y_va, w_va = labels_all[va_idx], w_all[va_idx]
+                y_tr, w_tr = (
+                    _take_rows(a, tr_idx, bins_tr.shape[0])
+                    for a in (labels_all, w_all)
+                )
+                y_va, w_va = (
+                    _take_rows(a, va_idx, bins_va.shape[0])
+                    for a in (labels_all, w_all)
+                )
                 if set_all is not None:
                     set_tr, set_va = set_all[tr_idx], set_all[va_idx]
                 if vs_all is not None:
@@ -494,7 +561,26 @@ class GradientBoostedTreesLearner(GenericLearner):
                         np.zeros((0,) + vs_all[0].shape[1:], np.float32),
                         np.zeros((0,) + vs_all[1].shape[1:], np.int32),
                     )
-                tr_groups = group_values
+                if grouped:
+                    tr_order = order_all
+            if tr_order is not None:
+                # All of a part trains (or validates): its rows by query.
+                def by_query(a, order):
+                    if a is None or order is None:
+                        return a
+                    if isinstance(a, tuple):
+                        return tuple(x[order] for x in a)
+                    return a[order]
+
+                tr_codes = codes_all[tr_order]
+                bins_tr, y_tr, w_tr, set_tr, vs_tr = (
+                    by_query(a, tr_order)
+                    for a in (bins_tr, y_tr, w_tr, set_tr, vs_tr)
+                )
+                bins_va, y_va, w_va, set_va, vs_va = (
+                    by_query(a, va_order)
+                    for a in (bins_va, y_va, w_va, set_va, vs_va)
+                )
 
         if self.mesh is not None:
             from ydf_tpu.parallel import mesh as pmesh
@@ -571,29 +657,6 @@ class GradientBoostedTreesLearner(GenericLearner):
                 if vs_va is not None and vs_va[0].shape[0] > 0:
                     vs_va = _pad_shard_vs(vs_va, bins_va.shape[0])
 
-        from ydf_tpu.learners.losses import CustomLoss
-
-        if isinstance(self.loss, CustomLoss):
-            loss_obj = self.loss
-        else:
-            loss_obj = make_loss(self.loss, self.task, num_classes)
-        from ydf_tpu.learners.ranking_loss import LambdaMartNdcg, build_group_rows
-
-        if isinstance(loss_obj, LambdaMartNdcg):
-            # Non-NDCG losses (e.g. SQUARED_ERROR on a ranking task) need no
-            # group structure and skip this entirely.
-            if self.task != Task.RANKING:
-                raise ValueError("LAMBDA_MART_NDCG requires task=Task.RANKING")
-            loss_obj.ndcg_truncation = self.ndcg_truncation
-            rows_tr, _ = build_group_rows(
-                tr_groups, max_group_size=self.ranking_max_group_size
-            )
-            loss_obj.register_groups("train", len(y_tr), rows_tr)
-            if bins_va.shape[0] > 0:
-                rows_va, _ = build_group_rows(
-                    va_groups, max_group_size=self.ranking_max_group_size
-                )
-                loss_obj.register_groups("valid", len(y_va), rows_va)
         from ydf_tpu.learners.survival_loss import CoxProportionalHazardLoss
 
         if isinstance(loss_obj, CoxProportionalHazardLoss):
@@ -796,6 +859,10 @@ class GradientBoostedTreesLearner(GenericLearner):
             else:
                 x_tr_raw = x_all
                 x_va_raw = np.zeros((0, binner.num_numerical), np.float32)
+            if tr_order is not None:
+                x_tr_raw = x_tr_raw[tr_order]
+                if va_order is not None:
+                    x_va_raw = x_va_raw[va_order]
             if self.mesh is not None:
                 # Match the row padding applied to bins_tr/bins_va above
                 # (pad rows carry zero weight; their raw values only enter
@@ -828,6 +895,31 @@ class GradientBoostedTreesLearner(GenericLearner):
         else:
             vs_tr = vs_va = None
         vs_Pv = (vs_Ac + vs_Ap) * binner.num_vs if vs_tr is not None else 0
+
+        if grouped and rank is None:
+            with timer.stage("rank_groups"):
+                # Bucketed and sent here, after the mesh's padding rows
+                # are there (they belong to no query), counted with the
+                # bytes this job sends.
+                groups_tr, facts = build_rank_groups(
+                    tr_codes, bins_tr.shape[0],
+                    self.ranking_max_group_size, self.ndcg_truncation,
+                )
+                groups_va = None
+                if bins_va.shape[0] > 0:
+                    groups_va, _ = build_rank_groups(
+                        va_codes, bins_va.shape[0],
+                        self.ranking_max_group_size, self.ndcg_truncation,
+                    )
+                device_loop.count_h2d(sum(
+                    a.nbytes for a in jax.tree.leaves((groups_tr, groups_va))
+                ))
+                rank = jax.tree.map(jnp.asarray, (groups_tr, groups_va)) + (
+                    facts,
+                )
+        if rank is not None:
+            for name, value in rank[2].items():
+                timer.counts["device_loop." + name] = value
 
         with timer.stage("device_loop"), _flight_guard():
             if self.distributed_workers:
@@ -864,10 +956,13 @@ class GradientBoostedTreesLearner(GenericLearner):
                         if inputs_key is not None:
                             prep["dataset"].keep_device_inputs(
                                 bins_all, inputs_key,
-                                [inputs[k] for k in _KEPT_INPUTS],
+                                [inputs[k] for k in _KEPT_INPUTS]
+                                + ([rank] if rank is not None else []),
                             )
                 forest_stacked, leaf_values, logs = _train_gbt(
             **inputs,
+            groups_tr=rank[0] if rank is not None else None,
+            groups_va=rank[1] if rank is not None else None,
             timer=timer,
             loss_obj=loss_obj,
             rule=rule,
@@ -1113,13 +1208,16 @@ class GradientBoostedTreesLearner(GenericLearner):
         arrays (`_KEPT_INPUTS`) depend on, or None for a job whose
         inputs are not kept with the Dataset: one that is not given a
         Dataset (a dict or a frame makes a new one every call), brings
-        its own validation rows, splits by query groups, has survival
-        columns, set or vector-sequence features or raw features for
-        oblique splits, or places its arrays across workers or a mesh."""
+        its own validation rows, has survival columns, set or
+        vector-sequence features or raw features for oblique splits, or
+        places its arrays across workers or a mesh. A ranking job's
+        arrays also depend on its group column, and the query structure
+        kept beside them (`RankGroups`) on the cap and the truncation."""
         if (
             not isinstance(data, Dataset)
             or valid is not None
-            or self.task not in (Task.CLASSIFICATION, Task.REGRESSION)
+            or self.task
+            not in (Task.CLASSIFICATION, Task.REGRESSION, Task.RANKING)
             or prep.get("set_bits") is not None
             or prep.get("vs") is not None
             or self.split_axis != "AXIS_ALIGNED"
@@ -1131,6 +1229,12 @@ class GradientBoostedTreesLearner(GenericLearner):
         return (
             (self.random_seed, self.validation_ratio) if splits else None,
             self.label, self.task, self.weights,
+            (
+                self.ranking_group, self.loss, self.ranking_max_group_size,
+                self.ndcg_truncation,
+            )
+            if self.task == Task.RANKING
+            else None,
             # the placement: the one device `jnp.asarray` sends to
             jax.default_backend(), jax.config.jax_default_device,
         )
@@ -1150,6 +1254,49 @@ class GradientBoostedTreesLearner(GenericLearner):
 # The arrays of a job that a Dataset keeps on the device for the next
 # (`Dataset.keep_device_inputs`), in `_train_gbt`'s order.
 _KEPT_INPUTS = ("bins_tr", "y_tr", "w_tr", "bins_va", "y_va", "w_va")
+
+
+def _rows_by_query(group_values, ordered: bool):
+    """(codes, order) of a ranking job's group column: `codes` [n]
+    numbers each row's query by the sorted distinct ids, so that the
+    numbering does not depend on the order of the rows; `order` (None
+    unless `ordered`) lists the rows by query, in dataset order within
+    one, which is the order a group-structured loss keeps them in on the
+    device: a query's documents are consecutive rows, and a table
+    shuffled across queries gives the arrays of the ordered one."""
+    _, codes = np.unique(np.asarray(group_values), return_inverse=True)
+    codes = codes.reshape(-1)
+    return codes, np.argsort(codes, kind="stable") if ordered else None
+
+
+def _take_rows(a, idx, rows):
+    """a[idx], with zero rows after it up to `rows` rows."""
+    if rows == len(idx):
+        return a[idx]
+    out = np.zeros((rows,) + a.shape[1:], a.dtype)
+    np.take(a, idx, axis=0, out=out[: len(idx)])
+    return out
+
+
+def _split_capacities(n, rows_tr, rows_va, ratio):
+    """(training rows, validation rows) to pad the parts of a split by
+    whole queries to. The parts' sizes go by which queries validate,
+    and a compiled program goes by its shapes: a table of `n` rows
+    always gets the sizes the ratio gives, plus 1/128 of `n`, rounded
+    up to a round number (the power of two under n / 64), so that
+    tables of one size share their shapes, and with them their
+    program's speed (on the chip, programs built for 12,584,074 to
+    12,614,780 training rows ran 13.89 to 14.10 s a job: PERF.md section
+    6, PR 36). A part larger than that takes the next round number
+    that holds it."""
+    step = 1 << max((n >> 6).bit_length() - 1, 3)
+    expected_va = int(n * ratio)
+
+    def capacity(expected, rows):
+        least = max(expected + max(n >> 7, 1), rows)
+        return -(-least // step) * step
+
+    return capacity(n - expected_va, rows_tr), capacity(expected_va, rows_va)
 
 
 def _split_rows(dataset, bins_all, rng, seed, ratio):
@@ -1202,8 +1349,8 @@ def _make_boost_fn(
     Caching the closure is what makes jax.jit's own cache effective across
     `train()` calls: a fresh closure per call would retrace + recompile the
     whole lax.scan every time. Keyed on hashable frozen-dataclass configs
-    (LambdaMartNdcg hashes by identity — its captured per-dataset group
-    arrays make cross-call reuse incorrect anyway)."""
+    (a ranking loss is one: its query structure is an argument of
+    `run_chunk`, `groups_tr` and `groups_va`)."""
     K = loss_obj.num_dims
     N = tree_cfg.max_nodes
     B = tree_cfg.num_bins
@@ -1257,7 +1404,7 @@ def _make_boost_fn(
             # Iteration 0's stats rows, with EXACTLY the ops the unfused
             # path would run (g·(w·1), h·(w·1), w·1) so the fused loop is
             # bit-identical from the first tree.
-            g0, h0 = loss_obj.grad_hess(y_tr, preds0)
+            g0, h0 = loss_obj.grad_hess(y_tr, preds0)  # squared error only
             w_eff0 = w_tr * jnp.ones((n,), jnp.float32)
             stats0 = jnp.stack(
                 [g0[:, 0] * w_eff0, h0[:, 0] * w_eff0, w_eff0], axis=1
@@ -1269,8 +1416,17 @@ def _make_boost_fn(
 
     def _make_step(bins_tr, y_tr, w_tr, bins_va, y_va, w_va,
                    x_tr_raw=None, x_va_raw=None, set_tr=None, set_va=None,
-                   vs_tr=None, vs_va=None):
+                   vs_tr=None, vs_va=None, groups_tr=None, groups_va=None):
         y_f = y_tr.astype(jnp.float32)
+
+        # A group-structured loss (ranking) reads its rows through the
+        # query structure; what every tree shares of it (relevances and
+        # ideal DCG in the buckets' layout) is made here, once a chunk.
+        rank_tr = rank_va = {}
+        if groups_tr is not None:
+            rank_tr = {"groups": loss_obj.group_context(y_tr, groups_tr)}
+        if groups_va is not None:
+            rank_va = {"groups": loss_obj.group_context(y_va, groups_va)}
 
         # Feature-major bins copy for the fused native route kernel,
         # computed HERE — outside the boosting scan — so the one
@@ -1303,24 +1459,25 @@ def _make_boost_fn(
                 # keep every positive example and the selgb_ratio fraction
                 # of that group's negatives scored highest by the current
                 # model (the "hard" negatives).
-                rows, _ = loss_obj._rows_for("train", n)  # [G, Gmax]
-                pad = rows >= n  # trash-row padding
-                s_g = jnp.where(pad, -jnp.inf, preds[rows.clip(0, n - 1), 0])
-                pos_g = (y_f[rows.clip(0, n - 1)] > 0) & ~pad
-                neg_g = ~pos_g & ~pad
-                neg_score = jnp.where(neg_g, s_g, -jnp.inf)
-                # Rank of each negative inside its group, by descending
-                # score: rank r kept iff r < ceil(ratio * #negatives).
-                order = jnp.argsort(-neg_score, axis=1)
-                rank = jnp.argsort(order, axis=1)
-                n_neg = jnp.sum(neg_g, axis=1, keepdims=True)
-                keep_neg = neg_g & (rank < jnp.ceil(selgb_ratio * n_neg))
-                keep_g = pos_g | keep_neg
-                mask = jnp.zeros((n + 1,), jnp.float32)
-                mask = mask.at[jnp.where(pad, n, rows).reshape(-1)].set(
-                    keep_g.reshape(-1).astype(jnp.float32)
-                )
-                return mask[:n]
+                from ydf_tpu.learners import ranking_loss
+
+                groups, ctx = rank_tr["groups"]
+                keep = []
+                for s_g, (y_g, valid, _, _) in zip(
+                    ranking_loss.to_groups(groups, preds[:, 0]), ctx
+                ):
+                    pos_g = (y_g > 0) & valid
+                    neg_g = ~pos_g & valid
+                    neg_score = jnp.where(neg_g, s_g, -jnp.inf)
+                    # Rank of each negative inside its group, by
+                    # descending score: rank r kept iff
+                    # r < ceil(ratio * #negatives).
+                    order = jnp.argsort(-neg_score, axis=1)
+                    rank = jnp.argsort(order, axis=1)
+                    n_neg = jnp.sum(neg_g, axis=1, keepdims=True)
+                    keep_neg = neg_g & (rank < jnp.ceil(selgb_ratio * n_neg))
+                    keep.append((pos_g | keep_neg).astype(jnp.float32))
+                return ranking_loss.from_groups(groups, keep)[0]
             if subsample < 1.0:
                 return jax.random.bernoulli(
                     k_sub, subsample, (n,)
@@ -1553,7 +1710,9 @@ def _make_boost_fn(
                 w_eff = w_tr
             else:
                 with jax.named_scope("ydf.grad"):
-                    g, h = loss_obj.grad_hess(y_tr, preds_used)  # [n, K]
+                    g, h = loss_obj.grad_hess(
+                        y_tr, preds_used, **rank_tr
+                    )  # [n, K]
                     m = sample_mask(k_sub, g, preds_used)
                     w_eff = w_tr * m
 
@@ -1800,9 +1959,9 @@ def _make_boost_fn(
             trees = jax.tree.map(lambda *xs: jnp.stack(xs), *trees_k)
             lvs = jnp.stack(leaves_k)  # [K, N, 1]
             with jax.named_scope("ydf.loss"):
-                tl = loss_obj.loss(y_tr, preds, w_tr, tag="train")
+                tl = loss_obj.loss(y_tr, preds, w_tr, tag="train", **rank_tr)
                 vl = (
-                    loss_obj.loss(y_va, vpreds, w_va, tag="valid")
+                    loss_obj.loss(y_va, vpreds, w_va, tag="valid", **rank_va)
                     if nv > 0
                     else jnp.float32(0)
                 )
@@ -1823,14 +1982,15 @@ def _make_boost_fn(
     @functools.partial(jax.jit, static_argnames=("chunk_len",))
     def run_chunk(carry, start, chunk_len, bins_tr, y_tr, w_tr,
                   bins_va, y_va, w_va, x_tr_raw=None, x_va_raw=None,
-                  set_tr=None, set_va=None, vs_tr=None, vs_va=None):
+                  set_tr=None, set_va=None, vs_tr=None, vs_va=None,
+                  groups_tr=None, groups_va=None):
         """One checkpointable slice of the boosting loop: iterations
         [start, start + chunk_len). Chunking is invisible to the result —
         the per-iteration RNG folds the iteration index into the carried
         key, so every chunk boundary gives the same forest."""
         step = _make_step(
             bins_tr, y_tr, w_tr, bins_va, y_va, w_va, x_tr_raw, x_va_raw,
-            set_tr, set_va, vs_tr, vs_va,
+            set_tr, set_va, vs_tr, vs_va, groups_tr, groups_va,
         )
         return jax.lax.scan(
             step, carry, start + jnp.arange(chunk_len)
@@ -2122,8 +2282,8 @@ def _train_gbt(
     oblique_mode="SPARSE", mhld_max_attributes=4, num_label_classes=1,
     monotone=None,
     x_tr_raw=None, x_va_raw=None, set_tr=None, set_va=None,
-    vs_tr=None, vs_va=None, vs_Ac=0, vs_Ap=0, route_impl="xla",
-    route_fuse=True,
+    vs_tr=None, vs_va=None, groups_tr=None, groups_va=None,
+    vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True,
     cache_dir=None, resume=False, snapshot_interval=50,
     abort_after_chunks=None, preempt_after_chunks=None,
     early_stop_lookahead=0, deadline=None, timer,
@@ -2140,9 +2300,9 @@ def _train_gbt(
     gradient_boosted_trees.cc:1314-1325). Under `cache_dir` every chunk
     ends in a durable snapshot, and SIGTERM/SIGINT ends the train
     resumable at the next one."""
-    # Identity-hashed losses (LambdaMartNdcg carries per-dataset group
-    # arrays) can never hit the cache — bypass it so dead entries don't pin
-    # device memory or evict the reusable frozen-dataclass ones.
+    # Identity-hashed losses can never hit the cache — bypass it so dead
+    # entries don't pin device memory or evict the reusable
+    # frozen-dataclass ones.
     from ydf_tpu.learners.losses import CustomLoss
 
     builder = (
@@ -2175,6 +2335,9 @@ def _train_gbt(
     if vs_tr is not None:
         data_kwargs["vs_tr"] = vs_tr
         data_kwargs["vs_va"] = vs_va
+    if groups_tr is not None:
+        data_kwargs["groups_tr"] = groups_tr
+        data_kwargs["groups_va"] = groups_va
 
     can_early_stop = early_stop_lookahead > 0 and nv_rows > 0
     if cache_dir is None:

@@ -1,11 +1,9 @@
 """LambdaMART NDCG ranking loss.
 
 Re-design of the reference's NDCG loss (`ydf/learner/gradient_boosted_trees/
-loss/loss_imp_ndcg.{h,cc}`, LambdaMART per Burges et al.) in fully-batched
-form: query groups are padded into a dense [num_groups, G] index matrix, and
-per-group pairwise lambdas are computed as [G, G] tensors, scanned over
-chunks of groups to bound memory. Gains are exponential (2^rel - 1) and
-discounts are truncated at `ndcg_truncation` (reference default 5).
+loss/loss_imp_ndcg.{h,cc}`, LambdaMART per Burges et al.) in batched form.
+Gains are exponential (2^rel - 1) and discounts are truncated at
+`ndcg_truncation` (reference default 5).
 
 For ordered pair (i better than j):
     rho    = sigmoid(s_j - s_i)
@@ -14,11 +12,44 @@ For ordered pair (i better than j):
 
 The reported loss is -NDCG@truncation (lower is better), matching the
 reference's convention.
+
+**The tie rule.** Documents of equal score rank in dataset order within
+their query: the one that comes first in the table ranks first. Before
+the first tree every score is 0, so the first tree's gradients are this
+rule.
+
+**The layout** (`build_rank_groups`, `RankGroups`). The learner orders
+the rows of a split by query once on the host, so that a query's
+documents are consecutive rows on the device, in dataset order. Queries
+are bucketed by size, one bucket a power of two (1, 2, 4, ... up to the
+longest), and a bucket's `[queries, G]` view of a per-row vector is that
+many slices of G consecutive rows (`to_groups`), the slots past a
+query's end masked: no query is padded beyond twice its size, none to
+the longest. What comes back by the row (`from_groups`) is read from the
+buckets' views put end to end, through each row's slot, gradient and
+hessian in one gather of pairs. (On the chip at 13.5M rows and 113k
+queries, PERF.md section 6, PR 36: the slices take 0.092 s a vector, an
+index matrix 0.133 s; the gather of pairs 0.140 s, one gather a vector
+0.205 s for two, a scatter-add 0.200 s for one.) The structure
+is data handed to the compiled program, arrays whose shapes are all it
+specialises on; the loss itself is hashable by its truncation alone, so
+the second `train()` on a table builds no program.
+
+**The pairs.** |disc_i - disc_j| is exactly 0 for two documents that
+both rank at or past the truncation, so every pair with a lambda has
+one of the query's top `ndcg_truncation` documents in it. A bucket
+therefore computes `[queries, T, G]` pair slots (T = min(truncation,
+G)), each top document against every document of its query, and never a
+`[G, G]` block; the top T come from T passes of arg-max (first position
+wins a tie: the tie rule), so nothing is sorted. The sums are those of
+the `[G, G]` formula in another order.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import dataclasses
+import warnings
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,232 +58,303 @@ import numpy as np
 _EPS = 1e-12
 
 
-def build_group_rows(
-    group_values: np.ndarray, max_group_size: int = 2048
-) -> Tuple[np.ndarray, int]:
-    """Group column → dense row-index matrix [num_groups, G], padded with -1.
+class RankGroups(NamedTuple):
+    """The query structure of one split's rows, as the compiled program
+    takes it. Bucket b holds the queries of more than `G_b / 2` and at
+    most `G_b = len(lanes[b])` documents."""
 
-    Over-long groups are truncated to `max_group_size` (with the kept items
-    chosen in dataset order); truncation warns, because dropped documents
-    get zero gradient and leave NDCG — raise the learner's
-    `ranking_max_group_size` to keep them."""
-    codes, _ = _factorize(group_values)
-    order = np.argsort(codes, kind="stable")
-    sorted_codes = codes[order]
-    boundaries = np.flatnonzero(np.diff(sorted_codes)) + 1
-    groups = np.split(order, boundaries)
-    largest = max(len(g) for g in groups)
-    G = min(largest, max_group_size)
-    if largest > max_group_size:
-        import warnings
+    # Per bucket, [queries of the bucket] int32: the row of each query's
+    # first document, and how many documents it trains on.
+    starts: Tuple[jax.Array, ...]
+    sizes: Tuple[jax.Array, ...]
+    # Per bucket, arange(G_b): the bucket's width, carried as a shape.
+    lanes: Tuple[jax.Array, ...]
+    # [rows] int32: each row's slot in the buckets' [queries, G_b] views
+    # put end to end; one past the last slot for a row no query holds
+    # (mesh padding, documents past `ranking_max_group_size`).
+    slot: jax.Array
 
-        n_trunc = sum(1 for g in groups if len(g) > max_group_size)
+
+def _bucket_width(size: np.ndarray) -> np.ndarray:
+    """The least power of two that holds `size` (>= 1) documents."""
+    width = np.ones_like(size)
+    while np.any(width < size):
+        width = np.where(width < size, width * 2, width)
+    return width
+
+
+def build_rank_groups(
+    codes: np.ndarray, num_rows: Optional[int] = None,
+    max_group_size: Optional[int] = None, truncation: int = 5,
+):
+    """(RankGroups as numpy arrays, facts) for rows ordered by query:
+    `codes` [n] is non-decreasing, one value a query. `num_rows` >= n is
+    the length of the per-row vectors (mesh padding rows belong to no
+    query). A query longer than `max_group_size` trains on its first
+    `max_group_size` documents only, with a warning; None: no cap.
+    `facts`: what the program computes a tree, for the counters
+    (`rank_pair_slots`, `rank_pairs`, `rank_pairs_all`, `rank_buckets`).
+    """
+    codes = np.asarray(codes)
+    n = len(codes)
+    num_rows = n if num_rows is None else num_rows
+    if n and np.any(codes[1:] < codes[:-1]):
+        raise ValueError("build_rank_groups: rows are not ordered by query")
+    first = np.flatnonzero(np.r_[True, codes[1:] != codes[:-1]]) if n else (
+        np.zeros((0,), np.int64))
+    full = np.diff(np.r_[first, n])
+    sizes = full
+    if max_group_size is not None and n and full.max() > max_group_size:
         warnings.warn(
-            f"{n_trunc} query group(s) exceed max_group_size="
-            f"{max_group_size} (largest: {largest}); excess documents are "
-            "dropped from training and NDCG. Raise ranking_max_group_size "
-            "to keep them.",
+            f"{int(np.sum(full > max_group_size))} query group(s) exceed "
+            f"max_group_size={max_group_size} (largest: {int(full.max())}); "
+            "excess documents are dropped from training and NDCG. Raise "
+            "ranking_max_group_size (None: no cap) to keep them.",
             stacklevel=3,
         )
-    rows = np.full((len(groups), G), -1, np.int64)
-    for gi, g in enumerate(groups):
-        g = g[:G]
-        rows[gi, : len(g)] = g
-    return rows, G
+        sizes = np.minimum(full, max_group_size)
+    widths = _bucket_width(sizes)
+    starts_b, sizes_b, lanes_b = [], [], []
+    slot = np.full((num_rows,), -1, np.int64)
+    offset = 0
+    for G in np.unique(widths):
+        q = np.flatnonzero(widths == G)
+        starts_b.append(first[q].astype(np.int32))
+        sizes_b.append(sizes[q].astype(np.int32))
+        lanes_b.append(np.arange(G, dtype=np.int32))
+        # Row first[q] + j sits at slot offset + (q's place) * G + j.
+        place = np.repeat(np.arange(len(q)), sizes[q])
+        lane = np.arange(sizes[q].sum()) - np.repeat(
+            np.cumsum(sizes[q]) - sizes[q], sizes[q])
+        slot[np.repeat(first[q], sizes[q]) + lane] = offset + place * G + lane
+        offset += len(q) * int(G)
+    if offset >= 2 ** 31:
+        raise ValueError(f"{offset} slots do not fit an int32 index")
+    slot[slot < 0] = offset
+    groups = RankGroups(tuple(starts_b), tuple(sizes_b), tuple(lanes_b),
+                        slot.astype(np.int32))
+    top = np.minimum(truncation, sizes)
+    facts = {
+        "rank_pair_slots": float(np.sum(
+            np.minimum(truncation, widths) * widths)),
+        "rank_pairs": float(np.sum(top * sizes)),
+        "rank_pairs_all": float(np.sum(sizes.astype(np.float64) ** 2)),
+        "rank_buckets": float(len(starts_b)),
+    }
+    return groups, facts
 
 
-def _factorize(values: np.ndarray):
-    vals = np.asarray(values)
-    uniq, codes = np.unique(vals, return_inverse=True)
-    return codes, uniq
+def to_groups(groups: RankGroups, v: jax.Array, fill=0.0):
+    """Per bucket, the `[queries, G_b]` view of the per-row vector `v`:
+    each query's documents in dataset order, `fill` past its end."""
+    widest = max((len(lane) for lane in groups.lanes), default=0)
+    # Room past the end: a slice that would run over is moved back.
+    v_pad = jnp.concatenate([v, jnp.full((widest,), fill, v.dtype)])
+    out = []
+    for starts, sizes, lanes in zip(*groups[:3]):
+        G = lanes.shape[0]
+        rows = jax.vmap(
+            lambda st: jax.lax.dynamic_slice_in_dim(v_pad, st, G)
+        )(starts)
+        out.append(jnp.where(_mask(sizes, lanes), rows, fill))
+    return out
 
 
+def from_groups(groups: RankGroups, *per_bucket):
+    """The per-row vectors of the buckets' `[queries, G_b]` arrays, one
+    vector for each list `per_bucket` gives (a tuple of them): 0 for a
+    row no query holds. What a view holds past a query's end is never
+    read. Several vectors come through ONE gather, of a row of them a
+    slot."""
+    flat = jnp.concatenate(
+        [
+            jnp.stack([a.reshape(-1) for a in views], axis=1)
+            for views in zip(*per_bucket)
+        ]
+        + [jnp.zeros((1, len(per_bucket)), per_bucket[0][0].dtype)]
+    )
+    rows = flat[groups.slot]
+    return tuple(rows[:, k] for k in range(len(per_bucket)))
+
+
+def _mask(sizes, lanes):
+    return lanes[None, :] < sizes[:, None]
+
+
+def _top_documents(score, valid, T):
+    """The `T` first-ranked documents of every query of a bucket, by
+    decreasing `score` [Q, G] among `valid`, the earlier position first
+    among equals. Returns T one-hot masks [Q, G], the first-ranked
+    document's first; a query of fewer than T documents picks nothing
+    more."""
+    neg = jnp.float32(-jnp.inf)
+    lanes = jnp.arange(score.shape[1], dtype=jnp.int32)[None, :]
+    free = valid
+    out = []
+    for _ in range(T):
+        at = jnp.argmax(jnp.where(free, score, neg), axis=1).astype(jnp.int32)
+        picked = (lanes == at[:, None]) & free
+        out.append(picked)
+        free = free & ~picked
+    return out
+
+
+def _position_discounts(T):
+    return [float(1.0 / np.log2(t + 2.0)) for t in range(T)]
+
+
+def _take(a, picked):
+    """a[q, position picked in q] as [Q] (0 where nothing is picked)."""
+    return jnp.sum(jnp.where(picked, a, 0.0), axis=1)
+
+
+@dataclasses.dataclass(frozen=True)
 class LambdaMartNdcg:
-    """Group-structured loss: register_groups() must be called (by the GBT
-    learner) for every prediction array length it will see."""
+    """Group-structured loss: the learner hands `grad_hess` and `loss`
+    the `group_context` of the split's `RankGroups`."""
+
+    ndcg_truncation: int = 5
 
     name = "LAMBDA_MART_NDCG"
     num_dims = 1
 
-    def __init__(self, ndcg_truncation: int = 5, group_chunk_bytes: int = 1 << 26):
-        self.ndcg_truncation = ndcg_truncation
-        self.group_chunk_bytes = group_chunk_bytes
-        self._structs: Dict[str, Tuple[jax.Array, int, int]] = {}
-
-    def register_groups(self, tag: str, n: int, rows: np.ndarray) -> None:
-        """rows: [num_groups, G] indices into the length-n example arrays of
-        the dataset named `tag` ("train" / "valid"), padding = -1."""
-        rows = np.where(rows < 0, n, rows).astype(np.int32)  # pad → trash row
-        self._structs[tag] = (jnp.asarray(rows), rows.shape[1], n)
-
-    def _rows_for(self, tag: str, n: int):
-        if tag not in self._structs:
-            raise ValueError(f"No group structure registered for {tag!r}")
-        rows, G, reg_n = self._structs[tag]
-        if reg_n != n:
-            raise ValueError(
-                f"Group structure {tag!r} was registered for {reg_n} "
-                f"examples, got {n}"
-            )
-        return rows, G
-
-    # ------------------------------------------------------------------ #
-
-    def _gather_groups(self, tag, labels, preds):
-        """Pads predictions/labels with a trash row and gathers them into
-        the [num_groups, G] layout: returns (s_g, y_g, m_g) with m_g the
-        validity mask."""
-        n = preds.shape[0]
-        rows, _ = self._rows_for(tag, n)
-        s_pad = jnp.concatenate([preds[:, 0], jnp.zeros((1,))])
-        y_pad = jnp.concatenate(
-            [labels.astype(jnp.float32), jnp.full((1,), -1.0)]
-        )
-        return rows, s_pad[rows], y_pad[rows], rows < n
-
     def initial_predictions(self, labels, weights):
         return jnp.zeros((1,), jnp.float32)
 
-    def _per_group_lambdas(self, s, y, m):
-        """s, y, m: [G] score, relevance, validity. Returns (g, h) [G]."""
-        G = s.shape[0]
-        gains = jnp.where(m, jnp.exp2(y) - 1.0, 0.0)
-        # ranks by decreasing score (invalid rows sink)
-        s_masked = jnp.where(m, s, -jnp.inf)
-        order = jnp.argsort(-s_masked)
-        rank_of = jnp.argsort(order)  # position of each doc
-        pos_disc = jnp.where(
-            jnp.arange(G) < self.ndcg_truncation,
-            1.0 / jnp.log2(jnp.arange(G, dtype=jnp.float32) + 2.0),
-            0.0,
-        )
-        disc = pos_disc[rank_of]
-        ideal = jnp.sort(gains)[::-1]
-        maxdcg = jnp.sum(ideal * pos_disc)
-        inv_maxdcg = jnp.where(maxdcg > 0, 1.0 / (maxdcg + _EPS), 0.0)
+    def group_context(self, labels, groups: RankGroups):
+        """What a split's trees share, made once outside the boosting
+        scan: per bucket the relevances, validity, gains and 1 / maxDCG
+        in the `[queries, G]` layout."""
+        ctx = []
+        with jax.named_scope("ydf.rank"):
+            y_b = to_groups(groups, labels.astype(jnp.float32), -1.0)
+            for y, sizes, lanes in zip(y_b, groups.sizes, groups.lanes):
+                m = _mask(sizes, lanes)
+                gains = jnp.where(m, jnp.exp2(y) - 1.0, 0.0)
+                maxdcg = self._dcg(gains, m, gains)
+                inv = jnp.where(maxdcg > 0, 1.0 / (maxdcg + _EPS), 0.0)
+                ctx.append((y, m, gains, inv))
+        return groups, tuple(ctx)
 
-        better = (y[:, None] > y[None, :]) & m[:, None] & m[None, :]
-        rho = jax.nn.sigmoid(s[None, :] - s[:, None])  # rho[i,j]=σ(s_j−s_i)
-        delta = (
-            jnp.abs(gains[:, None] - gains[None, :])
-            * jnp.abs(disc[:, None] - disc[None, :])
-            * inv_maxdcg
+    def _dcg(self, score, valid, gains):
+        """[Q]: the gains of each query's top documents by `score`, each
+        times its position's discount."""
+        T = min(self.ndcg_truncation, score.shape[1])
+        return sum(
+            _take(gains, picked) * d
+            for picked, d in zip(
+                _top_documents(score, valid, T), _position_discounts(T))
         )
-        lam = jnp.where(better, rho * delta, 0.0)
-        hl = jnp.where(better, rho * (1.0 - rho) * delta, 0.0)
-        g = -jnp.sum(lam, axis=1) + jnp.sum(lam, axis=0)
-        h = jnp.sum(hl, axis=1) + jnp.sum(hl, axis=0)
+
+    def _bucket_lambdas(self, s, y, m, gains, inv_maxdcg):
+        """s, y, m, gains: [Q, G]; inv_maxdcg: [Q]. Returns (g, h) [Q, G]."""
+        T = min(self.ndcg_truncation, s.shape[1])
+        top = _top_documents(s, m, T)
+        discs = _position_discounts(T)
+        # Every document's discount: its position's if it is in the top T.
+        disc = sum(jnp.where(picked, d, 0.0) for picked, d in zip(top, discs))
+        g = jnp.zeros_like(s)
+        h = jnp.zeros_like(s)
+        later = m
+        for picked, d in zip(top, discs):
+            # The pairs of this top document `a` with every document that
+            # ranks after it (those before it had their turn).
+            later = later & ~picked
+            has = jnp.any(picked, axis=1, keepdims=True)
+            s_a = _take(s, picked)[:, None]
+            y_a = _take(y, picked)[:, None]
+            gain_a = _take(gains, picked)[:, None]
+            a_better = y_a > y
+            pair = later & has & (y_a != y)
+            delta = (jnp.abs(gain_a - gains) * jnp.abs(d - disc)
+                     * inv_maxdcg[:, None])
+            rho = jax.nn.sigmoid(jnp.where(a_better, s - s_a, s_a - s))
+            lam = jnp.where(pair, rho * delta, 0.0)
+            lam = jnp.where(a_better, lam, -lam)  # toward the worse one
+            hl = jnp.where(pair, rho * (1.0 - rho) * delta, 0.0)
+            g = g + lam + jnp.where(
+                picked, -jnp.sum(lam, axis=1, keepdims=True), 0.0)
+            h = h + hl + jnp.where(
+                picked, jnp.sum(hl, axis=1, keepdims=True), 0.0)
         return g, h
 
-    def grad_hess(self, labels, preds):
-        n = preds.shape[0]
-        rows, sg, yg, mg = self._gather_groups("train", labels, preds)
-        G = rows.shape[1]
+    def grad_hess(self, labels, preds, groups):
+        groups, ctx = groups
+        with jax.named_scope("ydf.rank"):
+            s_b = to_groups(groups, preds[:, 0])
+            gh = [self._bucket_lambdas(s, *c) for s, c in zip(s_b, ctx)]
+            g, h = from_groups(
+                groups, [a for a, _ in gh], [b for _, b in gh]
+            )
+        return g[:, None], h[:, None]
 
-        chunk = max(1, self.group_chunk_bytes // max(G * G * 4, 1))
-        ngroups = rows.shape[0]
-        pad_g = (-ngroups) % chunk
-        sgp = jnp.pad(sg, ((0, pad_g), (0, 0)))
-        ygp = jnp.pad(yg, ((0, pad_g), (0, 0)), constant_values=-1.0)
-        mgp = jnp.pad(mg, ((0, pad_g), (0, 0)), constant_values=False)
-        nchunks = (ngroups + pad_g) // chunk
-
-        def one_chunk(c):
-            return jax.vmap(self._per_group_lambdas)(*c)
-
-        gs, hs = jax.lax.map(
-            one_chunk,
-            (
-                sgp.reshape(nchunks, chunk, G),
-                ygp.reshape(nchunks, chunk, G),
-                mgp.reshape(nchunks, chunk, G),
-            ),
-        )
-        gs = gs.reshape(-1, G)[:ngroups]
-        hs = hs.reshape(-1, G)[:ngroups]
-
-        g_flat = jnp.zeros((n + 1,), jnp.float32).at[rows].add(
-            jnp.where(mg, gs, 0.0)
-        )[:n]
-        h_flat = jnp.zeros((n + 1,), jnp.float32).at[rows].add(
-            jnp.where(mg, hs, 0.0)
-        )[:n]
-        return g_flat[:, None], h_flat[:, None]
-
-    def loss(self, labels, preds, weights, tag: str = "train"):
-        """-NDCG@truncation averaged over groups."""
-        rows, sg, yg, mg = self._gather_groups(tag, labels, preds)
-        G = rows.shape[1]
-
-        pos_disc = jnp.where(
-            jnp.arange(G) < self.ndcg_truncation,
-            1.0 / jnp.log2(jnp.arange(G, dtype=jnp.float32) + 2.0),
-            0.0,
-        )
-
-        def group_ndcg(s, y, m):
-            gains = jnp.where(m, jnp.exp2(y) - 1.0, 0.0)
-            order = jnp.argsort(-jnp.where(m, s, -jnp.inf))
-            dcg = jnp.sum(gains[order] * pos_disc)
-            idcg = jnp.sum(jnp.sort(gains)[::-1] * pos_disc)
-            return jnp.where(idcg > 0, dcg / (idcg + _EPS), 0.0), idcg > 0
-
-        ndcg, ok = jax.vmap(group_ndcg)(sg, yg, mg)
-        return -jnp.sum(ndcg) / (jnp.sum(ok) + _EPS)
+    def loss(self, labels, preds, weights, tag: str = "train", groups=None):
+        """-NDCG@truncation averaged over the queries that have a
+        relevant document."""
+        groups, ctx = groups
+        total = jnp.float32(0.0)
+        count = jnp.float32(0.0)
+        with jax.named_scope("ydf.rank"):
+            for s, (_, m, gains, inv) in zip(
+                to_groups(groups, preds[:, 0]), ctx
+            ):
+                total += jnp.sum(self._dcg(s, m, gains) * inv)
+                count += jnp.sum(inv > 0)
+        return -total / (count + _EPS)
 
     def predict_proba(self, preds):
         return preds
 
 
+@dataclasses.dataclass(frozen=True)
 class XeNdcg(LambdaMartNdcg):
     """Cross-entropy NDCG surrogate (Bruch et al. 2020; reference
     loss_imp_cross_entropy_ndcg.cc, Loss enum XE_NDCG_MART): per query
     group, the model's softmax over document scores is pulled toward the
     normalized relevance-gain distribution. Gradients are the listwise
-    softmax residual — no pairwise O(G^2) lambdas needed.
+    softmax residual, no pairs.
 
-    Reuses LambdaMartNdcg's group registration/bookkeeping; only the
-    gradient and loss computations differ.
+    Shares LambdaMartNdcg's group layout; only the gradient and loss
+    computations differ.
     """
 
     name = "XE_NDCG_MART"
 
-    def _group_softmax_terms(self, s, y, m):
-        """s, y, m: [G]. Returns (p, t): softmax scores and gain targets
-        over the valid rows (zeros on padding)."""
-        s_masked = jnp.where(m, s, -jnp.inf)
-        p = jax.nn.softmax(s_masked)
-        p = jnp.where(m, p, 0.0)
-        gains = jnp.where(m, jnp.exp2(y) - 1.0, 0.0)
-        denom = jnp.sum(gains)
+    @staticmethod
+    def _softmax_terms(s, m, gains):
+        """s, m, gains: [Q, G]. Returns (p, t, valid): softmax scores and
+        gain targets over the valid documents (zeros past a query's
+        end), and which queries have a relevant document."""
+        p = jnp.where(m, jax.nn.softmax(jnp.where(m, s, -jnp.inf), axis=1), 0.0)
+        denom = jnp.sum(gains, axis=1, keepdims=True)
         # All-zero-relevance groups contribute nothing (uniform target
         # would only add noise; the reference samples relevances instead).
         t = jnp.where(denom > 0, gains / (denom + _EPS), 0.0)
-        valid = denom > 0
-        return p, t, valid
+        return p, t, denom > 0
 
-    def grad_hess(self, labels, preds):
-        n = preds.shape[0]
-        rows, s_g, y_g, m_g = self._gather_groups("train", labels, preds)
+    def grad_hess(self, labels, preds, groups):
+        groups, ctx = groups
+        g_b, h_b = [], []
+        with jax.named_scope("ydf.rank"):
+            for s, (_, m, gains, _inv) in zip(
+                to_groups(groups, preds[:, 0]), ctx
+            ):
+                p, t, valid = self._softmax_terms(s, m, gains)
+                g_b.append(jnp.where(valid, p - t, 0.0))
+                h_b.append(jnp.where(valid, p * (1.0 - p), 0.0))
+            g, h = from_groups(groups, g_b, h_b)
+        return g[:, None], jnp.maximum(h[:, None], 1e-6)
 
-        def per_group(s, y, m):
-            p, t, valid = self._group_softmax_terms(s, y, m)
-            g = jnp.where(valid, p - t, 0.0)
-            h = jnp.where(valid, p * (1.0 - p), 0.0)
-            return g, h
-
-        g_g, h_g = jax.vmap(per_group)(s_g, y_g, m_g)
-        g = jnp.zeros((n + 1,)).at[rows.reshape(-1)].add(g_g.reshape(-1))
-        h = jnp.zeros((n + 1,)).at[rows.reshape(-1)].add(h_g.reshape(-1))
-        return g[:n, None], jnp.maximum(h[:n, None], 1e-6)
-
-    def loss(self, labels, preds, weights, tag: str = "train"):
-        _, s_g, y_g, m_g = self._gather_groups(tag, labels, preds)
-
-        def per_group(s, y, m):
-            p, t, valid = self._group_softmax_terms(s, y, m)
-            ce = -jnp.sum(t * jnp.log(p + _EPS))
-            return jnp.where(valid, ce, 0.0), valid
-
-        ce, valid = jax.vmap(per_group)(s_g, y_g, m_g)
-        return jnp.sum(ce) / (jnp.sum(valid.astype(jnp.float32)) + _EPS)
+    def loss(self, labels, preds, weights, tag: str = "train", groups=None):
+        groups, ctx = groups
+        total = jnp.float32(0.0)
+        count = jnp.float32(0.0)
+        with jax.named_scope("ydf.rank"):
+            for s, (_, m, gains, _inv) in zip(
+                to_groups(groups, preds[:, 0]), ctx
+            ):
+                p, t, valid = self._softmax_terms(s, m, gains)
+                ce = -jnp.sum(t * jnp.log(p + _EPS), axis=1, keepdims=True)
+                total += jnp.sum(jnp.where(valid, ce, 0.0))
+                count += jnp.sum(valid)
+        return total / (count + _EPS)

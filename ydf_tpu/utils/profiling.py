@@ -42,6 +42,7 @@ TRAIN_SPANS = tuple(
     "ydf." + name
     for name in (
         "ingest_bin",
+        "rank_groups",
         "split",
         "device_loop",
         "device_loop.h2d",
@@ -60,6 +61,7 @@ TRAIN_SPANS = tuple(
 # ops/histogram.py).
 DEVICE_SCOPES = (
     "ydf.grad",
+    "ydf.rank",
     "ydf.hist",
     "ydf.sibling",
     "ydf.gain",
