@@ -4,9 +4,11 @@ import numpy as np
 import pytest
 
 from ydf_tpu.ops.histogram import (
+    StatColumn,
     _histogram_matmul,
     _histogram_segment,
     histogram,
+    narrow_columns_per_slot,
     split_bf16,
 )
 
@@ -193,6 +195,94 @@ def test_matmul_narrow_operand_at_every_width(L, quant):
     assert got.shape == (L, F, B, stats.shape[1])
     assert np.any(want != 0)
     np.testing.assert_array_equal(got, want)
+
+
+_SEVEN = (StatColumn(), StatColumn(), StatColumn(pieces=1))
+_FOUR = (StatColumn(), StatColumn(same_as=2), StatColumn(pieces=1))
+
+
+@pytest.mark.parametrize("columns", [_SEVEN, _FOUR], ids=["7", "4"])
+@pytest.mark.parametrize("L", [1, 2, 4, 8, 16, 32])
+def test_described_operand_sums_the_plain_ones_bits(L, columns):
+    """`_histogram_matmul` told that the weight column is 0 or 1 (one
+    bf16 piece: 7 columns a slot) and that the hessian column is the
+    weight (not contracted: 4) returns the plain 9-column result BIT for
+    bit, on real-valued gradients that need all three pieces, over two
+    whole chunks and a ragged tail with rows of weight 0 and rows on
+    the trash slot, at one dot a level (to 16 slots) and past it (32);
+    and it sits as close to the segment impl as f32 sums do."""
+    rng = np.random.default_rng(37 * L + len(columns))
+    chunk, F, B = 256, 5, 32
+    n = 2 * chunk + 77
+    bins = jnp.asarray(rng.integers(0, B, (n, F)), jnp.uint8)
+    slot = jnp.asarray(rng.integers(0, L + 1, (n,)), jnp.int32)  # L = trash
+    w = (rng.random(n) < 0.85).astype(np.float32)
+    g = _mixed_magnitudes(rng, (n,), -2.0, 2.0) * w
+    h = w if columns is _FOUR else rng.random(n).astype(np.float32) * w
+    stats = jnp.asarray(np.stack([g, h, w], axis=1))
+    assert narrow_columns_per_slot(columns) == {_SEVEN: 7, _FOUR: 4}[columns]
+    plain = np.asarray(_histogram_matmul(bins, slot, stats, L, B, chunk))
+    got = np.asarray(
+        _histogram_matmul(bins, slot, stats, L, B, chunk, columns)
+    )
+    assert got.shape == plain.shape == (L, F, B, 3) and np.any(plain != 0)
+    assert got.tobytes() == plain.tobytes()
+    through_jit = histogram(
+        bins, slot, stats, num_slots=L, num_bins=B, impl="matmul",
+        chunk=chunk, stat_columns=columns,
+    )
+    assert np.asarray(through_jit).tobytes() == plain.tobytes()
+    segment = np.asarray(_histogram_segment(bins, slot, stats, L, B))
+    mass = np.asarray(_histogram_segment(bins, slot, jnp.abs(stats), L, B))
+    assert np.max(np.abs(got - segment) / np.maximum(mass, 1e-30)) < 2.0 ** -20
+
+
+def test_a_description_is_for_the_f32_matmul_operand_alone():
+    """Every other impl and both lower-precision modes take a
+    description and ignore it; one that does not fit the operand is
+    refused where it is traced."""
+    rng = np.random.default_rng(5)
+    n, F, L, B = 600, 3, 4, 16
+    bins = jnp.asarray(rng.integers(0, B, (n, F)), jnp.uint8)
+    slot = jnp.asarray(rng.integers(0, L + 1, (n,)), jnp.int32)
+    w = (rng.random(n) < 0.8).astype(np.float32)
+    stats = jnp.asarray(
+        np.stack([rng.normal(size=n).astype(np.float32) * w, w, w], axis=1)
+    )
+
+    def run(impl, quant, columns):
+        return np.asarray(histogram(
+            bins, slot, stats, num_slots=L, num_bins=B, impl=impl,
+            quant=quant, stat_columns=columns,
+        ))
+
+    for impl, quant in [("segment", "f32"), ("pallas_interpret", "f32"),
+                        ("matmul", "bf16x2"), ("matmul", "int8")]:
+        assert run(impl, quant, _FOUR).tobytes() == run(
+            impl, quant, None).tobytes(), (impl, quant)
+    for bad in (
+        _SEVEN[:2],  # two columns described of three
+        (StatColumn(), StatColumn(same_as=1), StatColumn()),  # itself
+        (StatColumn(same_as=1), StatColumn(same_as=2), StatColumn()),
+        (StatColumn(pieces=4), StatColumn(), StatColumn()),
+    ):
+        with pytest.raises(ValueError, match="column"):
+            run("matmul", "f32", bad)
+
+
+def test_split_bf16_takes_a_count_a_column():
+    """One count a column: block p holds piece p of the columns with
+    more than p pieces, and each piece is the uniform split's."""
+    rng = np.random.default_rng(3)
+    x = _mixed_magnitudes(rng, (500, 3), -3.0, 3.0)
+    x[:, 2] = rng.integers(0, 2, 500)
+    whole = np.asarray(split_bf16(jnp.asarray(x), 3).astype(jnp.float32))
+    got = np.asarray(split_bf16(jnp.asarray(x), (3, 2, 1)).astype(jnp.float32))
+    assert got.shape == (500, 6)
+    np.testing.assert_array_equal(got[:, :3], whole[:, :3])  # piece 0
+    np.testing.assert_array_equal(got[:, 3:5], whole[:, 3:5])  # piece 1
+    np.testing.assert_array_equal(got[:, 5], whole[:, 6])  # piece 2
+    assert not whole[:, 5].any() and not whole[:, 8].any()  # 0/1: one piece
 
 
 def test_segment_chunked_scan_path():

@@ -11,6 +11,7 @@ quick_scorer_extended.cc:1-985 (serving kernel)."""
 
 import gzip
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -52,21 +53,25 @@ def test_grow_tree_lowers_for_tpu():
 @pytest.mark.parametrize("export", ["grow_tree", "train_step"])
 def test_f32_matmul_histogram_is_one_bf16_pass(export):
     """The f32 matmul histogram reaches the MXU as ONE bf16 dot with an
-    f32 result: the stats ride as three exact bf16 pieces
-    (ops/histogram.py:_HIST_QUANTS), 3 x L x 3 columns a level, and both
-    operands are laid rows-minor ([bins, rows] and [columns, rows]);
-    the only other dots build that narrow operand, one a level. A
-    dot on f32 operands would be one lossy bf16 pass at XLA:TPU's
-    default and six passes of the one-hot at HIGHEST; neither may come
-    back quietly."""
+    f32 result: the stats ride as exact bf16 pieces
+    (ops/histogram.py:_HIST_QUANTS), three a column, 9 x L columns a
+    level, where the grower is handed plain stats, and 7 x L in the
+    binomial train step, whose learner knows its weight column to be 0
+    or 1 (one piece); both operands are laid rows-minor ([bins, rows]
+    and [columns, rows]); the only other dots build that narrow
+    operand, one a level. A dot on f32 operands would be one lossy bf16
+    pass at XLA:TPU's default and six passes of the one-hot at HIGHEST;
+    neither may come back quietly."""
     if export == "grow_tree":
         exp = tl.export_grow_tree(
             n=2048, F=8, max_depth=4, hist_impl="matmul"
         )
+        pieces = 9
     else:
         exp = tl.export_train_step(
             hist_impl="matmul", n=2048, F=8, num_trees=3, max_depth=4
         )
+        pieces = 7
     dots = [
         line for line in exp.mlir_module().splitlines()
         if "stablehlo.dot_general" in line
@@ -83,13 +88,13 @@ def test_f32_matmul_histogram_is_one_bf16_pass(export):
             widths.add(int(result.split("x")[1]))
         else:
             # The narrow operand's own build: a 0/1 matrix repeats the
-            # 9 pieces once a slot, [9 L, 9] x [rows, 9] (narrow()).
-            assert ", tensor<2048x9xbf16>)" in sig, line
+            # pieces once a slot, [9 L, 9] x [rows, 9] (narrow()).
+            assert f", tensor<2048x{pieces}xbf16>)" in sig, line
             built.add(int(sig.split("x")[0].split("<")[1]))
     assert built == widths, (built, widths)
     # depth 4 with sibling subtraction: 1, 1, 2, 4 live slots a level
-    # (the deepest builds no histogram), 3 pieces x 3 stats each.
-    assert widths == {9, 18, 36}, widths
+    # (the deepest builds no histogram), the kept pieces of 3 stats each.
+    assert widths == {pieces, 2 * pieces, 4 * pieces}, widths
 
 
 @pytest.mark.parametrize("F", [28, 100])
@@ -131,24 +136,58 @@ def one_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("quant", ["f32", "bf16x2", "int8"])
-@pytest.mark.parametrize("L", [2, 4, 8, 16])  # 16: two dots a feature
-def test_narrow_operand_is_built_once_a_chunk(one_chip, L, quant):
+def _described(columns_per_slot):
+    """The description `learners/gbt.py:_hist_stat_columns` gives a job
+    with no weights column: 7 columns a slot (a 0/1 weight), 4 (a
+    hessian that is the weight besides); 9 is none."""
+    from ydf_tpu.learners.gbt import _hist_stat_columns
+    from ydf_tpu.learners.losses import (
+        BinomialLogLikelihood,
+        MeanSquaredError,
+    )
+
+    if columns_per_slot == 9:
+        return None
+    loss = {7: BinomialLogLikelihood, 4: MeanSquaredError}[columns_per_slot]
+    return _hist_stat_columns(None, "RANDOM", loss())
+
+
+def _feature_loops(one_chip, L, quant="f32", columns_per_slot=9):
+    """{loop body: its operations a feature as long as the chunk} of the
+    compiled `_histogram_matmul`. One chunk and a tail: both loops."""
+    chunk = 1 << 18
+    hlo = tl.compile_histogram_matmul(
+        one_chip, L=L, F=4, quant=quant, n=chunk + 1000, chunk=chunk,
+        stat_columns=_described(columns_per_slot),
+    )
+    ops = tl.per_feature_body_ops(hlo, chunk)
+    assert ops, "no loop over features in the compiled program"
+    return {
+        body: [op for op in ops if op["body"] == body]
+        for body in {op["body"] for op in ops}
+    }
+
+
+@pytest.mark.parametrize(
+    "L,quant,columns_per_slot",
+    # L = 16: two dots a feature where a slot is 9 columns.
+    [(L, q, 9) for q in ("f32", "bf16x2", "int8") for L in (2, 4, 8, 16)]
+    + [(L, "f32", c) for c in (7, 4) for L in (2, 4, 8, 16, 32, 64)],
+)
+def test_narrow_operand_is_built_once_a_chunk(
+    one_chip, L, quant, columns_per_slot
+):
     """In the program XLA:TPU compiles from `_histogram_matmul`, not only
     in its source, the loop over features holds the bin column's slice
     and the contraction(s) and nothing else as long as the chunk. At 2
     and 4 slots the compiler used to sink the operand's product and its
     relayout to [columns, chunk] into that loop, where they ran F times
     a chunk: 1.83 s of a 15.0 s job in `synth100_gbt.sweep` (ledger, PR
-    31; PERF.md section 6, PR 34). One chunk and a tail: both loops."""
+    31; PERF.md section 6, PR 34). At every width a described operand
+    takes (PR 37), frontiers of 32 and 64 slots among them, which no
+    cell runs."""
     chunk = 1 << 18
-    hlo = tl.compile_histogram_matmul(
-        one_chip, L=L, F=4, quant=quant, n=chunk + 1000, chunk=chunk
-    )
-    ops = tl.per_feature_body_ops(hlo, chunk)
-    assert ops, "no loop over features in the compiled program"
-    for body in {op["body"] for op in ops}:
-        mine = [op for op in ops if op["body"] == body]
+    for mine in _feature_loops(one_chip, L, quant, columns_per_slot).values():
         assert any(op["contracts"] for op in mine), mine
         extra = [
             (op["name"], op["shape"]) for op in mine
@@ -158,6 +197,26 @@ def test_narrow_operand_is_built_once_a_chunk(one_chip, L, quant):
             )
         ]
         assert not extra, extra
+
+
+@pytest.mark.parametrize(
+    "columns_per_slot,widths", [(9, [48, 96]), (7, [112]), (4, [64])]
+)
+def test_level_5_is_one_dot_where_the_operand_is_described(
+    one_chip, columns_per_slot, widths
+):
+    """At 16 slots (level 5 of a depth-6 tree) the loop over features
+    holds ONE contraction, 112 or 64 columns wide, where the weight is
+    known to be 0 or 1 (and the hessian to be the weight); with no
+    description it holds the two of 96 and 48 columns it always held,
+    and the one-hot is built and streamed twice (PERF.md section 6, PR
+    37)."""
+    for mine in _feature_loops(one_chip, 16, "f32", columns_per_slot).values():
+        got = sorted(
+            int(re.search(r"f32\[(?:\d+,)?256,(\d+)\]", op["shape"]).group(1))
+            for op in mine if op["contracts"]
+        )
+        assert got == widths, mine
 
 
 def test_per_feature_body_ops_reads_a_compiled_text():

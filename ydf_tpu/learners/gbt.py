@@ -49,6 +49,12 @@ from ydf_tpu.learners.losses import make_loss
 from ydf_tpu.models.forest import forest_from_stacked_trees
 from ydf_tpu.models.gbt_model import GradientBoostedTreesModel
 from ydf_tpu.ops import device_loop, grower, lookup
+from ydf_tpu.ops.histogram import (
+    StatColumn,
+    narrow_columns_per_slot,
+    resolve_hist_impl,
+    resolve_hist_quant,
+)
 from ydf_tpu.ops.routing import apply_leaf_values, route_tree_bins
 from ydf_tpu.ops.split_rules import HessianGainRule
 
@@ -959,8 +965,17 @@ class GradientBoostedTreesLearner(GenericLearner):
                                 [inputs[k] for k in _KEPT_INPUTS]
                                 + ([rank] if rank is not None else []),
                             )
+                stat_columns = _hist_stat_columns(
+                    self.weights, self.sampling_method, loss_obj
+                )
+                timer.counts["device_loop.hist_columns_per_slot"] = float(
+                    narrow_columns_per_slot(
+                        stat_columns, resolve_hist_quant(None)
+                    )
+                )
                 forest_stacked, leaf_values, logs = _train_gbt(
             **inputs,
+            stat_columns=stat_columns,
             groups_tr=rank[0] if rank is not None else None,
             groups_va=rank[1] if rank is not None else None,
             timer=timer,
@@ -1161,11 +1176,6 @@ class GradientBoostedTreesLearner(GenericLearner):
             else:
                 # What "auto" resolved to for this train on this backend
                 # (chip_smoke.py asserts the TPU answers).
-                from ydf_tpu.ops.histogram import (
-                    resolve_hist_impl,
-                    resolve_hist_quant,
-                )
-
                 model.training_logs["implementations"] = {
                     "hist_impl": resolve_hist_impl("auto"),
                     "hist_quant": resolve_hist_quant(None),
@@ -1322,6 +1332,28 @@ def _split_rows(dataset, bins_all, rng, seed, ratio):
     return rows
 
 
+def _hist_stat_columns(weights, sampling: str, loss_obj):
+    """What the histogram may assume of the stats rows `[g w, h w, w]`
+    the boosting loop grows every tree from (ops/histogram.py
+    StatColumn), or None for nothing. The one place that says so, from
+    what the learner can see before it builds the program: `weights`
+    (the learner's weights column, or None), the sampling method and
+    the loss's class. With no weights column the rows' weights are ones,
+    and zeros on the rows the learner pads (the split's capacities, the
+    mesh's pad); a `RANDOM` or `SELGB` sample multiplies them by a mask
+    of zeros and ones (`GOSS` re-weights the rows it keeps by
+    (1 - alpha) / beta), so `w` is 0 or 1: its own first bf16 piece. A
+    loss whose class declares `unit_hessian` returns a hessian of ones,
+    so `h w` is `w` bit for bit."""
+    if weights is not None or sampling not in ("RANDOM", "SELGB"):
+        return None
+    hessian = (
+        StatColumn(same_as=2) if getattr(loss_obj, "unit_hessian", False)
+        else StatColumn()
+    )
+    return (StatColumn(), hessian, StatColumn(pieces=1))
+
+
 class _BoostFns(NamedTuple):
     """The jitted programs of one boosting configuration
     (`_make_boost_fn`): `init_state(y_tr, w_tr)` gives the first carry
@@ -1342,9 +1374,11 @@ def _make_boost_fn(
     oblique_weight_type="BINARY", oblique_weight_range=None,
     oblique_mode="SPARSE", mhld_max_attributes=4, num_label_classes=1,
     monotone=None, vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True,
+    stat_columns=None,
 ):
     """Builds (and caches) the jitted boosting loop for one static config,
-    as a `_BoostFns`.
+    as a `_BoostFns`. `stat_columns` is `_hist_stat_columns`' word on
+    the stats rows every tree is grown from.
 
     Caching the closure is what makes jax.jit's own cache effective across
     `train()` calls: a fresh closure per call would retrace + recompile the
@@ -1842,6 +1876,7 @@ def _make_boost_fn(
                     set_bits=set_tr,
                     route_impl=route_impl,
                     route_fuse=route_fuse,
+                    stat_columns=stat_columns,
                 )
                 # Leaf values scaled by shrinkage at storage time, like the
                 # reference (set_leaf applies shrinkage). The raw
@@ -2283,7 +2318,7 @@ def _train_gbt(
     monotone=None,
     x_tr_raw=None, x_va_raw=None, set_tr=None, set_va=None,
     vs_tr=None, vs_va=None, groups_tr=None, groups_va=None,
-    vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True,
+    vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True, stat_columns=None,
     cache_dir=None, resume=False, snapshot_interval=50,
     abort_after_chunks=None, preempt_after_chunks=None,
     early_stop_lookahead=0, deadline=None, timer,
@@ -2324,6 +2359,7 @@ def _train_gbt(
             vs_Ap if vs_tr is not None else 0,
             route_impl=route_impl,
             route_fuse=route_fuse,
+            stat_columns=stat_columns,
         )
     nv_rows = bins_va.shape[0]
     data_args = (bins_tr, y_tr, w_tr, bins_va, y_va, w_va) + (

@@ -69,6 +69,10 @@ class MeanSquaredError:
 
     name = "SQUARED_ERROR"
     num_dims = 1
+    # grad_hess returns a hessian of ones, whatever the predictions: the
+    # stats row's `h w` is then `w` bit for bit, and the histogram reads
+    # that column instead of summing it (gbt.py:_hist_stat_columns).
+    unit_hessian = True
 
     def initial_predictions(self, labels, weights):
         return (jnp.sum(weights * labels) / (jnp.sum(weights) + _EPS))[None]

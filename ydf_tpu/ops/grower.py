@@ -572,8 +572,8 @@ def grow_tree(
         "rule", "max_depth", "frontier", "max_nodes", "num_bins",
         "num_numerical", "min_examples", "min_split_gain",
         "candidate_features", "num_valid_features", "hist_impl",
-        "hist_subtract", "hist_quant", "route_impl", "route_fuse",
-        "dense_lookups", "monotone",
+        "hist_subtract", "hist_quant", "stat_columns", "route_impl",
+        "route_fuse", "dense_lookups", "monotone",
     ),
 )
 def _grow_tree_jit(
@@ -618,6 +618,13 @@ def _grow_tree_jit(
     # contraction is not histogram-dominated; staying on one grid keeps
     # parent − prefix consistent).
     hist_quant: str = "f32",
+    # What the caller knows of the columns of `stats`, one
+    # ops/histogram.py StatColumn each, or None: which bf16 pieces the
+    # matmul histogram of the scalar features may leave off the MXU.
+    # The histograms are the same bit for bit with it and without; it
+    # is derived where the stats are made (learners/gbt.py), never
+    # taken from a user.
+    stat_columns: Optional[tuple] = None,
     # Example-routing impl for the per-layer slot/leaf update: "xla"
     # (default — the exact oracle chain of gathers/selects) or "native"
     # (the fused ydf_route_update CPU kernel, one multithreaded pass per
@@ -850,6 +857,7 @@ def _grow_tree_jit(
                     bins, hslot_e, hist_stats, num_slots=Lh,
                     num_bins=B, impl=hist_impl, quant=hist_quant,
                     quant_scale=qscale, compact=_compact_cap(Lh),
+                    stat_columns=stat_columns,
                 )  # [Lh, F, B, S] (dequantized f32 under quantization)
             hist = sibling_reconstruct(
                 hist_small, parent_hist, small_is_left, Ld
@@ -869,6 +877,7 @@ def _grow_tree_jit(
             hist = histogram(
                 bins, slot, hist_stats, num_slots=Ld, num_bins=B,
                 impl=hist_impl, quant=hist_quant, quant_scale=qscale,
+                stat_columns=stat_columns,
             )  # [Ld, F, B, S]
         right_scalar = None
         if F > 0:

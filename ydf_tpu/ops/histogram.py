@@ -18,10 +18,15 @@ Two implementations:
     one-hot matmul is the idiomatic way to histogram on the MXU. Work is
     chunked over examples to bound the materialized one-hot. Both
     operands are bf16 and the dot is one MXU pass: the one-hot is exact
-    in bf16, and an f32 stat rides as three exact bf16 pieces, A three
-    times as wide (see _HIST_QUANTS below). The one-hot is written
-    [B, n], rows on the minor side, and A goes to the MXU in dots of at
-    most 128 columns: both by measurement on a v5e (PERF.md section 5).
+    in bf16, and an f32 stat rides as exact bf16 pieces laid side by
+    side in A: three a column unless the caller says what the column
+    holds (StatColumn below: a column of zeros and ones is its own
+    first piece, a column that equals another is not contracted at
+    all), so a slot of [g w, h w, w] is 9 columns of A, 7 where w is
+    0 or 1, 4 where h w is w besides (see _HIST_QUANTS below). The
+    one-hot is written [B, n], rows on the minor side, and A goes to
+    the MXU in dots of at most 128 columns where whole pieces fit
+    them: both by measurement on a v5e (PERF.md section 5).
     A does not depend on the feature and is built once a chunk, outside
     the loop over features, as [L*S, n] from its first operation on
     (`narrow` in _histogram_matmul): written as a [S, L, n] product and
@@ -78,6 +83,7 @@ subtraction of the same character, not a new failure mode.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +109,12 @@ _HIST_IMPLS = frozenset(
 #           accumulation, and adds the three slabs of the result. A 0/1
 #           one-hot times a bf16 piece is exact, so this is the sum a
 #           multi-pass f32 dot computes, with the one-hot streamed
-#           through the MXU once and not once per pass.
+#           through the MXU once and not once per pass. The operand
+#           holds only the pieces that can be non-zero: a caller that
+#           knows a column to be zeros and ones, or to equal another,
+#           says so (StatColumn), and its second and third pieces, or
+#           the whole column, stay off the MXU; the sums are the same
+#           bit for bit.
 #   bf16x2  the same with the last piece dropped: a bf16 high part plus
 #           a bf16 residual, 16 bits of every stat. Reconstruction error
 #           per example is bounded by the bf16 rounding of the RESIDUAL,
@@ -125,13 +136,74 @@ _HIST_QUANTS = frozenset({"f32", "bf16x2", "int8"})
 _MXU_COLUMNS = 128
 
 
-def split_bf16(x, pieces: int):
+class StatColumn(NamedTuple):
+    """What a caller knows of one column of the f32 stats it hands the
+    `matmul` histogram. The default is any f32. A wrong claim is not
+    caught: a "0 or 1" column that holds a 0.5 is summed rounded to 8
+    bits, so a description is derived from facts, where the stats are
+    made (learners/gbt.py:_hist_stat_columns), and never passed through
+    from a user."""
+
+    # bf16 pieces that hold every value of the column exactly: 3 for
+    # any f32, 1 where every value is 0 or 1 (it is its own first piece
+    # and leaves a remainder of exactly 0).
+    pieces: int = 3
+    # Index of the column this one equals bit for bit, or None. Such a
+    # column is not contracted: its sums are the other column's.
+    same_as: Optional[int] = None
+
+
+def _piece_counts(stat_columns, num_stats: int):
+    """bf16 pieces of each column of a stats operand that go onto the
+    MXU, 0 for a column that is another's copy; the description
+    checked against the operand."""
+    if len(stat_columns) != num_stats:
+        raise ValueError(
+            f"stat_columns describes {len(stat_columns)} columns of a "
+            f"stats operand that has {num_stats}"
+        )
+    for col in stat_columns:
+        if col.same_as is None:
+            if col.pieces not in (1, 2, 3):
+                raise ValueError(f"{col}: a column has 1 to 3 bf16 pieces")
+        elif not (
+            0 <= col.same_as < num_stats
+            and stat_columns[col.same_as].same_as is None
+        ):
+            raise ValueError(f"{col}: same_as names no contracted column")
+    return [
+        col.pieces if col.same_as is None else 0 for col in stat_columns
+    ]
+
+
+def narrow_columns_per_slot(
+    stat_columns=None, quant: str = "f32", num_stats: int = 3
+) -> int:
+    """Columns a slot on the narrow side of the `matmul` histogram's
+    dots: every kept bf16 piece of an f32 operand (9 for three plain
+    columns), the halves of `bf16x2`, the columns of `int8`."""
+    if quant == "f32":
+        stat_columns = stat_columns or (StatColumn(),) * num_stats
+        return sum(_piece_counts(stat_columns, num_stats))
+    return {"bf16x2": 2, "int8": 1}[quant] * num_stats
+
+
+def split_bf16(x, pieces):
     """f32 [n, S] -> bf16 [n, pieces * S]: column block p is the bf16
     rounding of what blocks 0..p-1 left of x, so the blocks add up to
     the leading 8 * pieces significand bits of x. Every subtraction is
-    exact in f32, and three pieces hold all 24 bits of a normal f32."""
-    parts, rest = [], x.astype(jnp.float32)
-    for _ in range(pieces):
+    exact in f32, and three pieces hold all 24 bits of a normal f32.
+    `pieces` may be one count a column: block p then holds the columns
+    with more than p pieces, in their order (a count of 0: in none)."""
+    counts = (
+        (pieces,) * x.shape[1] if isinstance(pieces, int) else tuple(pieces)
+    )
+    parts, rest, live = [], x.astype(jnp.float32), list(range(x.shape[1]))
+    for p in range(max(counts)):
+        keep = [i for i, c in enumerate(live) if counts[c] > p]
+        if len(keep) < len(live):
+            rest = jnp.concatenate([rest[:, i:i + 1] for i in keep], axis=1)
+            live = [live[i] for i in keep]
         # reduce_precision is the rounding to bf16 that no compiler
         # pass may fold away (a convert pair can be, under XLA's
         # excess-precision rule); the cast after it is then exact.
@@ -203,7 +275,8 @@ def _histogram_segment(
 
 
 def _histogram_matmul(
-    bins, slot, stats, num_slots: int, num_bins: int, chunk: int = 1 << 18
+    bins, slot, stats, num_slots: int, num_bins: int, chunk: int = 1 << 18,
+    stat_columns=None,
 ):
     n, F = bins.shape
     S = stats.shape[1]
@@ -226,19 +299,39 @@ def _histogram_matmul(
         jnp.int32 if jnp.issubdtype(stats.dtype, jnp.integer)
         else jnp.float32
     )
-    # The bf16x2 halves and int8 stats are exact in their tile type; an
-    # f32 operand becomes three bf16 pieces a chunk (see _HIST_QUANTS),
-    # added up after the scan.
-    pieces = 3 if stats.dtype == jnp.float32 else 1
+    # The bf16x2 halves and int8 stats are exact in their tile type and
+    # go onto the MXU as they are; an f32 operand becomes bf16 pieces a
+    # chunk (see _HIST_QUANTS), three a column or what `stat_columns`
+    # says of it (StatColumn), added up after the scan.
+    is_f32 = stats.dtype == jnp.float32
+    columns = (
+        stat_columns or (StatColumn(),) * S if is_f32
+        else (StatColumn(pieces=1),) * S
+    )
+    counts = _piece_counts(columns, S)
+    # The narrow side's blocks of L columns, in order (piece, column):
+    # piece p of every column that has one.
+    piece_blocks = [
+        [(p, c) for c, count in enumerate(counts) if count > p]
+        for p in range(max(counts))
+    ]
+    blocks = [block for piece in piece_blocks for block in piece]
     # Whole pieces a dot: as many as keep its narrow side within one
     # 128-column MXU tile, and one piece a dot past that (the width the
-    # f32 operand had). Measured on a v5e (PERF.md section 5).
-    per_dot = min(pieces, max(1, _MXU_COLUMNS // (L * S)))
+    # f32 operand had). Measured on a v5e (PERF.md section 5). Three
+    # plain columns at 16 slots are dots of 96 and 48 columns; with a
+    # weight of zeros and ones (7 columns a slot) one dot of 112.
+    dots = [[0, 0]]  # [first block, blocks) of each dot
+    for piece in piece_blocks:
+        first, width = dots[-1]
+        if width and (width + len(piece)) * L > _MXU_COLUMNS:
+            dots.append([first + width, 0])
+        dots[-1][1] += len(piece)
 
     def one_chunk(carry, b_chunk, s_chunk, st_chunk):
-        # [F, B, pieces*S*L] += sums over [chunk, F], [chunk], [chunk, S]
-        if pieces > 1:
-            st_chunk = split_bf16(st_chunk, pieces)  # bf16 [chunk, pieces*S]
+        # [F, B, blocks*L] += sums over [chunk, F], [chunk], [chunk, S]
+        if is_f32:
+            st_chunk = split_bf16(st_chunk, counts)  # bf16 [chunk, blocks]
         # Both operands are written with the rows on the minor,
         # contracted side, as the MXU takes them: a row's bin and slot
         # are then spread down the bins and slots, not across them, and
@@ -271,11 +364,10 @@ def _histogram_matmul(
             return jnp.where(slot_of[:, None] == s_chunk[None, :], wide, 0)
 
         # One operand a dot, columns (piece, stat, slot), each built
-        # from its own pieces: slicing one [pieces * S * L, chunk]
-        # operand into the dots' parts is a copy of it a chunk.
+        # from its own pieces: slicing one [blocks * L, chunk] operand
+        # into the dots' parts is a copy of it a chunk.
         a_dots = [
-            narrow(st_chunk[:, p * S:(p + per_dot) * S])
-            for p in range(0, pieces, per_dot)
+            narrow(st_chunk[:, first:first + width]) for first, width in dots
         ]
 
         def per_feature(f, acc):
@@ -293,7 +385,7 @@ def _histogram_matmul(
                     for a_dot in a_dots
                 ],
                 axis=1,
-            )  # [B, pieces*S*L]
+            )  # [B, blocks*L]
             return acc.at[f].add(h)
 
         return jax.lax.fori_loop(0, F, per_feature, carry)
@@ -305,7 +397,7 @@ def _histogram_matmul(
         ]
         return one_chunk(carry, *rows)
 
-    hist = jnp.zeros((F, B, pieces * S * L), dtype=acc_dtype)
+    hist = jnp.zeros((F, B, len(blocks) * L), dtype=acc_dtype)
     hist = jax.lax.fori_loop(0, n_full, whole_chunk, hist)
     if tail:
         # Padded examples land in the trash slot L and add exact zeros,
@@ -318,11 +410,24 @@ def _histogram_matmul(
             jnp.pad(slot[n - tail:], (0, pad), constant_values=L),
             jnp.pad(stats[n - tail:], ((0, pad), (0, 0))),
         )
-    # Smallest piece first: the f32 sums of the three slabs lose no
-    # more in this fold than one accumulator of whole values would.
-    hist = hist.reshape(F, B, pieces, S, L)
-    hist = functools.reduce(
-        jnp.add, [hist[:, :, p] for p in reversed(range(pieces))]
+    # A column's slabs, smallest piece first: the f32 sums of three
+    # slabs lose no more in this fold than one accumulator of whole
+    # values would; a one-piece column is its one slab. A column that
+    # equals another takes that column's sums.
+    hist = hist.reshape(F, B, len(blocks), L)
+    folded = {
+        c: functools.reduce(
+            jnp.add,
+            [hist[:, :, blocks.index((p, c))] for p in reversed(range(count))],
+        )
+        for c, count in enumerate(counts) if count
+    }
+    hist = jnp.stack(
+        [
+            folded[c if col.same_as is None else col.same_as]
+            for c, col in enumerate(columns)
+        ],
+        axis=2,
     )  # [F, B, S, L]
     # Returned in the ACCUMULATOR dtype (int32 for int8 stats — a cast
     # back to int8 would wrap); the _histogram_jit wrapper owns the final
@@ -358,12 +463,13 @@ def _compact_live_rows(bins, slot, stats, cap: int, num_slots: int):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "num_slots", "num_bins", "impl", "chunk", "quant", "compact"
+        "num_slots", "num_bins", "impl", "chunk", "quant", "compact",
+        "stat_columns",
     ),
 )
 def _histogram_jit(
     bins, slot, stats, quant_scale, num_slots, num_bins, impl, chunk,
-    quant, compact,
+    quant, compact, stat_columns=None,
 ):
     if impl == "auto":
         # Refuse a literal "auto" INSIDE a jit boundary: callers that
@@ -439,8 +545,11 @@ def _histogram_jit(
                 bins_d, slot_d, stats_q, num_slots, num_bins, chunk
             )
         elif impl == "matmul":
+            # Only an f32 operand has pieces to leave out: the other
+            # modes' operands, and the other impls, take no description.
             out = _histogram_matmul(
-                bins_d, slot_d, stats_q, num_slots, num_bins, chunk
+                bins_d, slot_d, stats_q, num_slots, num_bins, chunk,
+                stat_columns,
             )
         elif impl in ("pallas", "pallas_interpret"):
             from ydf_tpu.ops.histogram_pallas import histogram_pallas
@@ -613,6 +722,7 @@ def histogram(
     quant: str | None = None,
     quant_scale: jax.Array | None = None,  # f32 [S] int8 scale (traced)
     compact: int = 0,
+    stat_columns: Optional[Tuple[StatColumn, ...]] = None,
 ) -> jax.Array:
     """Returns hist[num_slots, F, num_bins, S] = Σ_examples stats.
 
@@ -624,9 +734,12 @@ def histogram(
     computed from this call's stats. `compact`
     > 0 enables trash-row compaction on the segment impl: live rows are
     gathered into a `compact`-row buffer before the scatter (with a
-    full-row fallback when they don't fit)."""
+    full-row fallback when they don't fit). `stat_columns`, one
+    StatColumn a column of f32 `stats`, lets the matmul impl leave the
+    bf16 pieces that are zero by construction off the MXU; the sums are
+    the same bit for bit, and every other impl and mode ignores it."""
     return _histogram_jit(
         bins, slot, stats, quant_scale, num_slots, num_bins,
         resolve_hist_impl(impl), chunk, resolve_hist_quant(quant),
-        compact,
+        compact, stat_columns,
     )

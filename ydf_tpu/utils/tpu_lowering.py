@@ -141,7 +141,7 @@ def build_train_step(
     Defaults are the bench configuration (BASELINE.json config 1:
     500k x 28, 20 trees, depth 6)."""
     from ydf_tpu.config import TreeConfig
-    from ydf_tpu.learners.gbt import _make_boost_fn
+    from ydf_tpu.learners.gbt import _hist_stat_columns, _make_boost_fn
     from ydf_tpu.learners.losses import (
         BinomialLogLikelihood,
         MeanSquaredError,
@@ -160,6 +160,8 @@ def build_train_step(
     boost = _make_boost_fn.__wrapped__(
         loss_obj, rule, tree_cfg, num_trees, 0.1, 1.0,
         -1, F, F, seed, n, nv,
+        # As train() does for a job with no weights column.
+        stat_columns=_hist_stat_columns(None, "RANDOM", loss_obj),
     )
     data = (
         jax.ShapeDtypeStruct((n, F), jnp.uint8),     # bins_tr
@@ -203,13 +205,15 @@ def deviceless_tpu_sharding(topology_name: str = "v5e:2x2"):
 
 def compile_histogram_matmul(
     sharding, L: int, F: int, quant: str = "f32", n: int = 3 << 18,
-    chunk: int = 1 << 18, num_bins: int = 256,
+    chunk: int = 1 << 18, num_bins: int = 256, stat_columns=None,
 ) -> str:
     """XLA:TPU's compiled text of `ops/histogram.py:_histogram_matmul`
     itself for the chip `sharding` describes, at `L` slots, `F`
-    features and the stats operand the `quant` mode hands it. The text
-    carries the compiler's placement of every operation, its layouts
-    and its `estimated_cycles`: no chip reading."""
+    features and the stats operand the `quant` mode hands it, described
+    by `stat_columns` (one `ops/histogram.py` StatColumn a column, as
+    `learners/gbt.py:_hist_stat_columns` gives them; None: any f32).
+    The text carries the compiler's placement of every operation, its
+    layouts and its `estimated_cycles`: no chip reading."""
     from ydf_tpu.ops.histogram import _histogram_matmul
 
     dtype, S = _QUANT_STATS[quant]
@@ -219,7 +223,9 @@ def compile_histogram_matmul(
         jax.ShapeDtypeStruct((n, S), dtype, sharding=sharding),
     )
     fn = jax.jit(
-        lambda b, sl, st: _histogram_matmul(b, sl, st, L, num_bins, chunk)
+        lambda b, sl, st: _histogram_matmul(
+            b, sl, st, L, num_bins, chunk, stat_columns
+        )
     )
     return fn.lower(*args).compile().as_text()
 
