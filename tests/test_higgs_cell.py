@@ -212,10 +212,16 @@ def test_forest_is_the_parents_bit_for_bit(monkeypatch):
 def test_manifest_holds_the_configuration_and_its_cell():
     m = manifest.load()
     assert len(m["configs"]) == 3 and len(m["workloads"]) == 3  # PR 36
-    assert len(m["per_layer"]) == 15
+    assert len(m["per_layer"]) == 18
     assert m["per_layer"][12]["name"] == "program_build_s"
-    assert [e["workloads"] for e in m["per_layer"][13:]] == [
+    assert [e["workloads"] for e in m["per_layer"][13:15]] == [
         ["mslr30k_rank.sweep"]] * 2
+    # PR 38: set-up's parts, in every cell
+    assert [(e["name"], e["moves"], "workloads" in e)
+            for e in m["per_layer"][15:]] == [
+        ("dataset_ingest_s", "setup_s", False),
+        ("bin_build_s", "setup_s", False),
+        ("inputs_build_s", "setup_s", False)]
     cell, entry, cfg, mix, limits = manifest.cell_files(m, CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "sweep")
     assert entry["reduced"] == ["num_trees", "rows"]

@@ -71,6 +71,8 @@ def test_training_profile_holds_the_drivers_spans(driver, tmp_path):
                  and k != "device_loop.compile")
     assert nested <= p["device_loop"]
     assert 0 <= p["device_loop.compile"] <= p["device_loop.dispatch"]
+    children = sum(p[k] for k in expected if k.startswith("ingest_bin."))
+    assert children <= p["ingest_bin"]
     top_level = sum(p[k] for k in expected if "." not in k)
     assert p["other"] == pytest.approx(p["total"] - top_level, abs=1e-9)
     assert p["other"] < 0.1 * p["total"]

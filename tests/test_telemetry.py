@@ -287,12 +287,17 @@ def test_train_predict_trace_and_metrics_acceptance(tmp_path):
     # The measured children of `train`: one span per boundary of
     # train() (utils/profiling.TRAIN_SPANS), the same names and
     # intervals the profiler's trace gets; nothing is attributed.
+    # A dict is ingested inside the job: the Dataset's own spans
+    # (DATASET_SPANS) lie in `ingest_bin.dataspec`.
     spans = {e["name"]: e for e in evs if e["name"].startswith("ydf.")}
-    from ydf_tpu.utils.profiling import TRAIN_SPANS
+    from ydf_tpu.utils.profiling import DATASET_SPANS, TRAIN_SPANS
 
-    assert {"ydf.ingest_bin", "ydf.split", "ydf.device_loop",
+    assert {"ydf.ingest_bin", "ydf.ingest_bin.dataspec",
+            "ydf.ingest_bin.binner_fit", "ydf.ingest_bin.transform",
+            "ydf.ingest_bin.targets", "ydf.split", "ydf.device_loop",
             "ydf.device_loop.dispatch", "ydf.device_loop.wait",
-            "ydf.finalize"} <= set(spans) <= set(TRAIN_SPANS)
+            "ydf.finalize", *DATASET_SPANS} <= set(spans)
+    assert set(spans) <= set(TRAIN_SPANS) | set(DATASET_SPANS)
     assert not any("attributed" in (e.get("args") or {}) for e in evs)
     # Nesting by containment: every chunk and span in the train span,
     # every dotted span in its parent.
@@ -300,8 +305,10 @@ def test_train_predict_trace_and_metrics_acceptance(tmp_path):
         assert _contains(trains[0], c)
     for name, e in spans.items():
         assert _contains(trains[0], e), name
+        parent = {"ydf.dataset.from_data": "ydf.ingest_bin.dataspec"}.get(
+            name, name.rsplit(".", 1)[0])
         if name.count(".") > 1:
-            assert _contains(spans[name.rsplit(".", 1)[0]], e), name
+            assert _contains(spans[parent], e), name
     serves = [e for e in evs if e["name"] == "serve.predict"]
     kernels = [e for e in evs if e["name"] == "serve.kernel"]
     assert serves and kernels

@@ -610,10 +610,25 @@ class BinnedDataset:
         the same (features, num_bins) — tuner trials, CV folds sharing
         a fold dataset, bench steady-state — reuses the fitted Binner
         and the cached bin/set/vs encodings instead of re-binning."""
+        return BinnedDataset.of_binner(
+            dataset, BinnedDataset.fit_binner(dataset, features, num_bins)
+        )
+
+    @staticmethod
+    def fit_binner(
+        dataset: Dataset, features: Sequence[str], num_bins: int = 256
+    ) -> Binner:
+        """The first half of `create`: the Binner, fitted or memoized."""
         binner = dataset.cached_binner(features, num_bins)
         if binner is None:
             binner = Binner.fit(dataset, features, num_bins=num_bins)
             dataset.store_binner(features, num_bins, binner)
+        return binner
+
+    @staticmethod
+    def of_binner(dataset: Dataset, binner: Binner) -> "BinnedDataset":
+        """The second half of `create`: `dataset` under `binner`, its
+        encodings made or memoized."""
         fp = binner.fingerprint()
         aux = dataset.cached_bin_aux(fp)
         if aux is None:

@@ -147,6 +147,46 @@ def _register_mem_source() -> None:
 _register_mem_source()
 
 
+def _columns_of(data: InputData) -> Dict[str, np.ndarray]:
+    """The columns of any input `Dataset.from_data` takes but a Dataset:
+    a path (csv, tfrecord, avro), a frame, a dict, a grain source."""
+    if isinstance(data, str):
+        fmt, raw_path = _split_typed_path(data)
+        if fmt == "tfrecord":
+            from ydf_tpu.dataset.tfrecord import (
+                read_tfrecord_columns,
+                resolve_tfrecord_path,
+            )
+
+            return read_tfrecord_columns(resolve_tfrecord_path(raw_path))
+        if fmt == "avro":
+            from ydf_tpu.dataset.avro import read_avro_columns
+            from ydf_tpu.dataset.tfrecord import resolve_tfrecord_path
+
+            return read_avro_columns(resolve_tfrecord_path(raw_path))
+        parts = [_read_csv(f) for f in _resolve_typed_path(data)]
+        return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+    if _frame_io().is_polars_frame(data):
+        # polars (reference dataset/io/polars_io.py): checked before
+        # the generic DataFrame branch — polars also has
+        # .to_dict/.columns but its Series API differs in corners.
+        return _frame_io().polars_to_columns(data)
+    if hasattr(data, "to_dict") and hasattr(data, "columns"):  # DataFrame
+        return {c: data[c].to_numpy() for c in data.columns}
+    if isinstance(data, dict):
+        return {k: _column_array(v) for k, v in data.items()}
+    from ydf_tpu.dataset import grain_io
+
+    if grain_io.is_grain(data):
+        # PyGrain DataLoader / MapDataset / IterDataset of
+        # per-example dicts (reference dataset/io/pygrain_io.py).
+        return grain_io.to_columns(data)
+    if _frame_io().is_xarray_dataset(data):
+        # xarray (reference dataset/io/xarray_io.py).
+        return _frame_io().xarray_to_columns(data)
+    raise TypeError(f"Unsupported dataset type: {type(data)}")
+
+
 class Dataset:
     """Columnar dataset: name → 1-D numpy array + dataspec.
 
@@ -159,7 +199,13 @@ class Dataset:
     worth of device memory). At most one Dataset in the process holds
     device arrays, the one trained on last, and it holds one set of
     them. To let go of them drop the Dataset (`del ds`; they go with
-    it), or train on another one."""
+    it), or train on another one.
+
+    What making all this cost is kept beside it, in `build_seconds`:
+    the ingest that made the Dataset (`dataset.from_data`, and
+    `dataset.from_data.infer` inside it), and the spans of the train()
+    that made the inputs kept now (`profiling.BUILD_SPANS`, each as
+    `dataset.<span>`), which every train() on it reports."""
 
     def __init__(self, data: Dict[str, np.ndarray], dataspec: DataSpecification):
         self.data = {k: np.asarray(v) for k, v in data.items()}
@@ -188,6 +234,8 @@ class Dataset:
         # (bin matrix, key, device arrays) of the last train() on this
         # Dataset that kept its inputs on the device, or None.
         self._device_inputs: Optional[tuple] = None
+        # Seconds, by profile key: see the class's docstring.
+        self.build_seconds: Dict[str, float] = {}
         _LIVE_DATASETS.add(self)  # memory-ledger "bin_matrix" source
 
     def bin_cache_bytes(self) -> int:
@@ -318,61 +366,32 @@ class Dataset:
                         )
                     return data._retyped[key]
             return data
-        if isinstance(data, str):
-            fmt, raw_path = _split_typed_path(data)
-            if fmt == "tfrecord":
-                from ydf_tpu.dataset.tfrecord import (
-                    read_tfrecord_columns,
-                    resolve_tfrecord_path,
+        if dataspec is not None:
+            # Re-keyed under a known dataspec (evaluation, prediction,
+            # validation rows): nothing is inferred, nothing recorded.
+            return Dataset(_columns_of(data), dataspec)
+        # An ingest: timed, and its seconds kept on the Dataset it makes
+        # (`build_seconds`), which every train() on it reports.
+        from ydf_tpu.utils.profiling import StageTimer
+
+        timer = StageTimer()
+        with timer.stage("dataset.from_data"):
+            cols = _columns_of(data)
+            with timer.stage("dataset.from_data.infer"):
+                dataspec = infer_dataspec(
+                    cols,
+                    label=label,
+                    max_vocab_count=max_vocab_count,
+                    min_vocab_frequency=min_vocab_frequency,
+                    column_types=column_types,
+                    detect_numerical_as_discretized=(
+                        detect_numerical_as_discretized
+                    ),
+                    discretized_max_bins=discretized_max_bins,
                 )
-
-                cols = read_tfrecord_columns(
-                    resolve_tfrecord_path(raw_path)
-                )
-            elif fmt == "avro":
-                from ydf_tpu.dataset.avro import read_avro_columns
-                from ydf_tpu.dataset.tfrecord import resolve_tfrecord_path
-
-                cols = read_avro_columns(resolve_tfrecord_path(raw_path))
-            else:
-                files = _resolve_typed_path(data)
-                parts = [_read_csv(f) for f in files]
-                cols = {}
-                for k in parts[0]:
-                    cols[k] = np.concatenate([p[k] for p in parts])
-        elif _frame_io().is_polars_frame(data):
-            # polars (reference dataset/io/polars_io.py): checked before
-            # the generic DataFrame branch — polars also has
-            # .to_dict/.columns but its Series API differs in corners.
-            cols = _frame_io().polars_to_columns(data)
-        elif hasattr(data, "to_dict") and hasattr(data, "columns"):  # DataFrame
-            cols = {c: data[c].to_numpy() for c in data.columns}
-        elif isinstance(data, dict):
-            cols = {k: _column_array(v) for k, v in data.items()}
-        else:
-            from ydf_tpu.dataset import grain_io
-
-            if grain_io.is_grain(data):
-                # PyGrain DataLoader / MapDataset / IterDataset of
-                # per-example dicts (reference dataset/io/pygrain_io.py).
-                cols = grain_io.to_columns(data)
-            elif _frame_io().is_xarray_dataset(data):
-                # xarray (reference dataset/io/xarray_io.py).
-                cols = _frame_io().xarray_to_columns(data)
-            else:
-                raise TypeError(f"Unsupported dataset type: {type(data)}")
-
-        if dataspec is None:
-            dataspec = infer_dataspec(
-                cols,
-                label=label,
-                max_vocab_count=max_vocab_count,
-                min_vocab_frequency=min_vocab_frequency,
-                column_types=column_types,
-                detect_numerical_as_discretized=detect_numerical_as_discretized,
-                discretized_max_bins=discretized_max_bins,
-            )
-        return Dataset(cols, dataspec)
+            ds = Dataset(cols, dataspec)
+        ds.build_seconds.update(timer.seconds)
+        return ds
 
     def sample(self, max_rows: int, seed: int = 1234):
         """(subset Dataset, sorted row indices). Row order is preserved so

@@ -369,7 +369,9 @@ class GradientBoostedTreesLearner(GenericLearner):
             else None
         )
         with timer.stage("ingest_bin"):
-            prep = self._prepare(data, valid=valid, targets=False)
+            prep = self._prepare(
+                data, valid=valid, targets=False, timer=timer
+            )
             # The six arrays this job would hand the boosting loop may
             # be on the device already, kept with the Dataset by an
             # earlier job that made the same ones: then nothing that
@@ -388,7 +390,8 @@ class GradientBoostedTreesLearner(GenericLearner):
                 # chip holds one table.
                 release_device_inputs()
                 if "sample_weights" not in prep:
-                    self._encode_targets(prep)
+                    with timer.stage("ingest_bin.targets"):
+                        self._encode_targets(prep)
         binner = prep["binner"]
         bins_all = prep["bins"]
         set_all = prep.get("set_bits")
@@ -1186,6 +1189,11 @@ class GradientBoostedTreesLearner(GenericLearner):
                     "shape": dict(self.mesh.shape),
                     "input_devices": mesh_input_devices,
                 }
+        # What making this job's inputs cost is kept with the Dataset the
+        # caller handed over (not a re-typed copy of it), by the job that
+        # made them; every job on it reports it as `dataset.*`.
+        owner = data if isinstance(data, Dataset) else prep["dataset"]
+        timer.keep_build(owner.build_seconds, made=kept is None)
         # Per-stage wall breakdown (reference Monitoring per-stage logs);
         # `device_loop.compile` is the boosting program's build inside it.
         model.training_profile = timer.finish()

@@ -285,6 +285,7 @@ class GenericLearner(HyperparameterValidationMixin):
         data: InputData,
         valid: Optional[InputData] = None,
         targets: bool = True,
+        timer=None,
     ) -> Dict:
         """Common ingestion: dataset, binning, encoded label/weights.
         `targets=False` leaves "labels" and "sample_weights" of an
@@ -292,23 +293,21 @@ class GenericLearner(HyperparameterValidationMixin):
         that may hold them already (GBT's device inputs kept with the
         Dataset) decides once it has seen the bins.
 
-        Records wall-clock attribution on `self.last_data_timings`
-        ({"ingest_s": dataspec inference + label/weight encode,
-        "bin_s": Binner fit + transform}) — the two terms the bench
-        tracks separately (bench.py headline record)."""
-        import time as _time
-
+        Its steps are spans of `timer` (the calling train()'s
+        StageTimer; None: a throw-away one): `ingest_bin.dataspec`
+        (the learner's column types, and a re-inference where they
+        differ from the Dataset's), `ingest_bin.binner_fit`,
+        `ingest_bin.transform` and `ingest_bin.targets`."""
+        from ydf_tpu.config import resolve_num_bins
         from ydf_tpu.dataset.cache import DatasetCache
+        from ydf_tpu.utils.profiling import StageTimer
 
         if isinstance(data, DatasetCache):
-            out = self._prepare_from_cache(data, valid=valid)
-            self.last_data_timings = {"ingest_s": 0.0, "bin_s": 0.0}
-            return out
-        t_start = _time.perf_counter()
-        ds = self._infer_dataset(data)
+            return self._prepare_from_cache(data, valid=valid)
+        timer = timer or StageTimer()
+        with timer.stage("ingest_bin.dataspec"):
+            ds = self._infer_dataset(data)
         feature_names = self._select_feature_names(ds)
-        from ydf_tpu.config import resolve_num_bins
-
         # Auto-shrunk bins must still hold every categorical dictionary
         # (indices >= num_bins collapse to OOV).
         max_vocab = max(
@@ -320,15 +319,14 @@ class GenericLearner(HyperparameterValidationMixin):
             ),
             default=0,
         )
-        t_bin0 = _time.perf_counter()
-        binned = BinnedDataset.create(
-            ds, feature_names,
-            num_bins=resolve_num_bins(
-                self.num_bins, ds.num_rows, min_cat_vocab=max_vocab
-            ),
-        )
-        t_bin = _time.perf_counter() - t_bin0
-        if binned.binner.num_vs > 0 and not getattr(
+        with timer.stage("ingest_bin.binner_fit"):
+            binner = BinnedDataset.fit_binner(
+                ds, feature_names,
+                num_bins=resolve_num_bins(
+                    self.num_bins, ds.num_rows, min_cat_vocab=max_vocab
+                ),
+            )
+        if binner.num_vs > 0 and not getattr(
             self, "_supports_vs_features", False
         ):
             # An explicitly requested VS feature must not silently train
@@ -336,39 +334,42 @@ class GenericLearner(HyperparameterValidationMixin):
             raise NotImplementedError(
                 f"{type(self).__name__} does not support "
                 f"NUMERICAL_VECTOR_SEQUENCE features "
-                f"{binned.binner.vs_names}"
+                f"{binner.vs_names}"
             )
-
-        out = {
-            "dataset": ds,
-            "binned": binned,
-            "binner": binned.binner,
-            "bins": binned.bins,
-            "set_bits": binned.set_bits,  # None without CATEGORICAL_SET cols
-            "vs": binned.vs,  # None without NUMERICAL_VECTOR_SEQUENCE cols
-        }
+        with timer.stage("ingest_bin.transform"):
+            binned = BinnedDataset.of_binner(ds, binner)
+            out = {
+                "dataset": ds,
+                "binned": binned,
+                "binner": binner,
+                "bins": binned.bins,
+                "set_bits": binned.set_bits,  # None without set columns
+                "vs": binned.vs,  # None without vector-sequence columns
+            }
+            if valid is not None:
+                vds = Dataset.from_data(
+                    valid, label=self.label, dataspec=ds.dataspec
+                )
+                out["valid_dataset"] = vds
+                out["valid_bins"] = binner.transform(vds)
+                out["valid_set_bits"] = binner.transform_sets(vds)
+                out["valid_vs"] = binner.transform_vs(vds)
+                if self.label is not None:
+                    out["valid_labels"] = vds.encoded_label(
+                        self.label, self.task
+                    )
+                if self.weights is not None:
+                    out["valid_weights"] = vds.data[self.weights].astype(
+                        np.float32
+                    )
         if (
             self.label is not None
             and self._label_task() == Task.CLASSIFICATION
         ):
             out["classes"] = ds.label_classes(self.label)
-
-        if valid is not None:
-            vds = Dataset.from_data(valid, label=self.label, dataspec=ds.dataspec)
-            out["valid_dataset"] = vds
-            out["valid_bins"] = binned.binner.transform(vds)
-            out["valid_set_bits"] = binned.binner.transform_sets(vds)
-            out["valid_vs"] = binned.binner.transform_vs(vds)
-            if self.label is not None:
-                out["valid_labels"] = vds.encoded_label(self.label, self.task)
-            if self.weights is not None:
-                out["valid_weights"] = vds.data[self.weights].astype(np.float32)
-        self.last_data_timings = {
-            "ingest_s": _time.perf_counter() - t_start - t_bin,
-            "bin_s": t_bin,
-        }
         if targets:
-            self._encode_targets(out)
+            with timer.stage("ingest_bin.targets"):
+                self._encode_targets(out)
         return out
 
     def _label_task(self) -> Task:
@@ -385,11 +386,8 @@ class GenericLearner(HyperparameterValidationMixin):
         """Adds the training rows' encoded label ("labels") and weights
         ("sample_weights") to `out`, a `_prepare` result: the part of
         ingestion that passes over every row on every call (at 56M rows
-        a class label's encoding and `np.ones` are 2.2 s); its seconds
-        join `last_data_timings["ingest_s"]`."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        a class label's encoding and `np.ones` are 2.2 s): the span
+        `ingest_bin.targets` of whoever calls it."""
         ds = out["dataset"]
         if self.label is not None:
             out["labels"] = ds.encoded_label(self.label, self._label_task())
@@ -397,7 +395,6 @@ class GenericLearner(HyperparameterValidationMixin):
             out["sample_weights"] = ds.data[self.weights].astype(np.float32)
         else:
             out["sample_weights"] = np.ones((ds.num_rows,), np.float32)
-        self.last_data_timings["ingest_s"] += _time.perf_counter() - t0
 
     def train(self, data: InputData, valid: Optional[InputData] = None):
         raise NotImplementedError

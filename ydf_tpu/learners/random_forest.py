@@ -150,7 +150,7 @@ class RandomForestLearner(GenericLearner):
         self._train_start = time.monotonic()
         timer = StageTimer()
         with timer.stage("ingest_bin"):
-            prep = self._prepare(data)
+            prep = self._prepare(data, timer=timer)
         binner = prep["binner"]
         release_device_inputs()  # this job's table goes up: no second one
         bins = jnp.asarray(prep["bins"])

@@ -42,6 +42,10 @@ TRAIN_SPANS = tuple(
     "ydf." + name
     for name in (
         "ingest_bin",
+        "ingest_bin.dataspec",
+        "ingest_bin.binner_fit",
+        "ingest_bin.transform",
+        "ingest_bin.targets",
         "rank_groups",
         "split",
         "device_loop",
@@ -54,6 +58,36 @@ TRAIN_SPANS = tuple(
         "device_loop.merge",
         "finalize",
     )
+)
+
+# The spans of the ingest that makes a Dataset (`Dataset.from_data`
+# where it infers a dataspec), kept with it (`Dataset.build_seconds`)
+# and reported by every train() on it under the same keys.
+DATASET_SPANS = ("ydf.dataset.from_data", "ydf.dataset.from_data.infer")
+
+# The spans a job runs to make what later jobs on its Dataset reuse:
+# the bins (BinnedDataset.create's memo), the query structure and the
+# device arrays (Dataset.keep_device_inputs). The job that makes them
+# keeps their seconds with the Dataset (`StageTimer.keep_build`), and
+# every job on it reports them as `dataset.<span>`.
+BUILD_SPANS = (
+    "ingest_bin",
+    "ingest_bin.dataspec",
+    "ingest_bin.binner_fit",
+    "ingest_bin.transform",
+    "ingest_bin.targets",
+    "rank_groups",
+    "split",
+    "device_loop.h2d",
+)
+
+# Spans a job may skip, reported at 0.0 where it does.
+_SKIPPABLE = (
+    "ingest_bin.dataspec",
+    "ingest_bin.binner_fit",
+    "ingest_bin.transform",
+    "ingest_bin.targets",
+    "device_loop.compile",
 )
 
 # The `jax.named_scope`s of the boosting scan's body, each a string
@@ -99,10 +133,24 @@ class StageTimer:
             self.seconds[name] = self.seconds.get(name, 0.0) + dur / 1e9
             telemetry.emit_span("ydf." + name, t, dur)  # no-op unless armed
 
+    def keep_build(self, record: Dict[str, float], made: bool) -> None:
+        """Reports `record`, the build record of the Dataset this call
+        trained on (`Dataset.build_seconds`: `dataset.from_data` and
+        the `dataset.<span>` of `BUILD_SPANS`), whichever call paid it.
+        A call that `made` its inputs first replaces the record's
+        `BUILD_SPANS` with its own seconds. The keys are counts, no
+        spans: `other` and `total` do not see them."""
+        if made:
+            record.update(
+                {"dataset." + k: self.seconds.get(k, 0.0) for k in BUILD_SPANS}
+            )
+        self.counts.update(record)
+
     def finish(self) -> Dict[str, float]:
-        """The profile: every span's seconds (`device_loop.compile`,
-        the build of a boosting program inside `device_loop.dispatch`,
-        is 0.0 on a warm call), `total`, and `other` = total less the
+        """The profile: every span's seconds (0.0 for one the call
+        skipped: `device_loop.compile`, the build of a boosting program
+        inside `device_loop.dispatch`, on a warm call; an `ingest_bin.*`
+        step that an on-disk cache does not run), `total`, and `other` = total less the
         top-level (undotted) spans. A call that dispatched a boosting
         program also says what that program's build cost, whichever
         call paid it: `device_loop.program_build_s`, and
@@ -113,7 +161,8 @@ class StageTimer:
         that are compare-and-select passes and that are gathers
         (ops/lookup.py)."""
         out = {**self.seconds, **self.counts}
-        out.setdefault("device_loop.compile", 0.0)
+        for name in _SKIPPABLE:
+            out.setdefault(name, 0.0)
         if self.programs:
             builds = list(self.programs.values())
             out["device_loop.program_build_s"] = sum(b[0] for b in builds)
