@@ -189,6 +189,32 @@ def test_xe_ndcg_on_the_bucketed_layout():
     assert value == pytest.approx(total / count, rel=1e-5)
 
 
+@pytest.mark.parametrize("loss", [rl.LambdaMartNdcg(), rl.XeNdcg()],
+                         ids=["lambda_mart", "xe_ndcg"])
+@pytest.mark.parametrize("kind", ["tied", "random", "many_ties"])
+def test_one_view_gives_the_lambdas_and_the_loss(kind, loss):
+    """`grad_hess_loss` is `grad_hess` and `loss` called apart, bit for
+    bit, compiled and op by op, with queries of one document and of
+    fewer documents than the truncation."""
+    x, y = table()
+    ids = x[-1].astype(np.int64)
+    assert {1, 2} <= set(SIZES) and min(SIZES) < loss.ndcg_truncation
+    groups, _ = rl.build_rank_groups(ids)
+    groups = jax.tree.map(jnp.asarray, groups)
+    y_d = jnp.asarray(y)
+    s_d = jnp.asarray(scores(kind, len(y)))[:, None]
+    ctx = loss.group_context(y_d, groups)
+    for run in (jax.jit, lambda f: f):
+        g, h, value = run(loss.grad_hess_loss)(y_d, s_d, ctx)
+        g0, h0 = run(loss.grad_hess)(y_d, s_d, ctx)
+        value0 = run(lambda y_, s_, c: loss.loss(y_, s_, None, groups=c))(
+            y_d, s_d, ctx)
+        for a, b in ((g, g0), (h, h0), (value, value0)):
+            np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                          np.asarray(b).view(np.int32))
+    assert np.asarray(g).any() and float(value) != 0.0
+
+
 # ------------------------------------------- against the plain reference
 
 
@@ -350,9 +376,10 @@ def test_second_train_builds_no_program_and_sends_nothing():
 
 def test_the_ranking_program_names_its_device_scope(monkeypatch):
     """The compiled boosting chunk of a ranking job carries `ydf.rank`
-    in its operations' metadata, inside `ydf.grad` (the lambdas) and
-    inside `ydf.loss` (the NDCG), and takes the query structure as
-    arguments."""
+    in its operations' metadata, inside `ydf.grad` (the lambdas and the
+    NDCG of the forest before the tree) and inside `ydf.loss` (the
+    validation NDCG, and the chunk's last forest's), and takes the query
+    structure as arguments."""
     texts = []
     run_chunk = device_loop.run_chunk
 
@@ -369,6 +396,53 @@ def test_the_ranking_program_names_its_device_scope(monkeypatch):
     learner(num_trees=1).train(as_columns(x, y))
     (text,) = texts
     assert "ydf.grad/ydf.rank/" in text and "ydf.loss/ydf.rank/" in text
+
+
+@pytest.mark.parametrize("kind", ["one_chunk", "chunks", "dart"])
+def test_each_tree_logs_the_loss_of_the_forest_it_ends(kind, tmp_path):
+    """The training loss logged for tree t is -NDCG@5 of the scores of
+    trees 1 to t, though outside DART the program reads it off the
+    lambdas' view of tree t + 1 (and a chunk's last tree's off one more
+    view): in one chunk of four trees, across chunks of two that
+    overshoot five trees, and under DART, whose losses are read apart
+    (its final forest rescales the trees, so only the last loss is the
+    final forest's)."""
+    x, y = table(seed=5)
+    ids = x[-1].astype(np.int64)
+    kw = dict(validation_ratio=0.0, num_trees=4)
+    if kind == "chunks":
+        kw.update(num_trees=5, working_dir=str(tmp_path),
+                  resume_training_snapshot_interval_trees=2)
+    if kind == "dart":
+        kw.update(dart_dropout=0.5)
+    model = learner(**kw).train(as_columns(x, y))
+    logged = model.training_logs["train_loss"]
+    assert len(logged) == model.num_trees() == kw["num_trees"]
+    views = {"one_chunk": 5 / 4, "chunks": 9 / 5, "dart": 2.0}[kind]
+    assert model.training_profile["device_loop.rank_score_views"] == views
+    groups, _ = rl.build_rank_groups(ids)
+    ctx = rl.LambdaMartNdcg().group_context(
+        jnp.asarray(y), jax.tree.map(jnp.asarray, groups))
+    forest = model.forest
+    recomputed = []
+    for t in range(1, model.num_trees() + 1):
+        model.forest = forest.truncated(t)
+        s = model.predict(as_columns(x, y)).astype(np.float32)
+        recomputed.append(float(rl.LambdaMartNdcg().loss(
+            jnp.asarray(y), jnp.asarray(s)[:, None], None, groups=ctx)))
+    model.forest = forest
+    if kind == "dart":
+        logged, recomputed = logged[-1:], recomputed[-1:]
+    assert logged == pytest.approx(recomputed, rel=1e-6, abs=0)
+    assert len(set(recomputed)) == len(recomputed)  # a shift would show
+
+
+def test_a_pointwise_loss_counts_no_score_view():
+    x, y = table(seed=5)
+    model = ydf.GradientBoostedTreesLearner(
+        label="label", task=Task.REGRESSION, num_trees=2, max_depth=3,
+    ).train(as_columns(x[:4], y))
+    assert "device_loop.rank_score_views" not in model.training_profile
 
 
 def test_another_cap_or_truncation_misses_the_kept_inputs():

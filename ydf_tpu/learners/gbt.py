@@ -1469,6 +1469,16 @@ def _make_boost_fn(
             rank_tr = {"groups": loss_obj.group_context(y_tr, groups_tr)}
         if groups_va is not None:
             rank_va = {"groups": loss_obj.group_context(y_va, groups_va)}
+        # Outside DART the scores a tree's lambdas come from are the
+        # forest before it, so their one view of the training scores
+        # also gives that forest's loss (`grad_hess_loss`): the step
+        # reports the loss of the forest before its tree, and `run_chunk`
+        # hands each loss to the tree that made it.
+        loss_before = groups_tr is not None and not use_dart
+
+        def train_loss(preds):
+            with jax.named_scope("ydf.loss"):
+                return loss_obj.loss(y_tr, preds, w_tr, tag="train", **rank_tr)
 
         # Feature-major bins copy for the fused native route kernel,
         # computed HERE — outside the boosting scan — so the one
@@ -1752,9 +1762,14 @@ def _make_boost_fn(
                 w_eff = w_tr
             else:
                 with jax.named_scope("ydf.grad"):
-                    g, h = loss_obj.grad_hess(
-                        y_tr, preds_used, **rank_tr
-                    )  # [n, K]
+                    if loss_before:
+                        g, h, tl = loss_obj.grad_hess_loss(
+                            y_tr, preds_used, **rank_tr
+                        )
+                    else:
+                        g, h = loss_obj.grad_hess(
+                            y_tr, preds_used, **rank_tr
+                        )  # [n, K]
                     m = sample_mask(k_sub, g, preds_used)
                     w_eff = w_tr * m
 
@@ -2001,8 +2016,9 @@ def _make_boost_fn(
 
             trees = jax.tree.map(lambda *xs: jnp.stack(xs), *trees_k)
             lvs = jnp.stack(leaves_k)  # [K, N, 1]
+            if not loss_before:
+                tl = train_loss(preds)
             with jax.named_scope("ydf.loss"):
-                tl = loss_obj.loss(y_tr, preds, w_tr, tag="train", **rank_tr)
                 vl = (
                     loss_obj.loss(y_va, vpreds, w_va, tag="valid", **rank_va)
                     if nv > 0
@@ -2016,7 +2032,7 @@ def _make_boost_fn(
                 new_carry = (preds, vpreds, key)
             return new_carry, (trees, lvs, tl, vl, obl_w, obl_b, vs_a, vs_b)
 
-        return boost_step
+        return boost_step, (train_loss if loss_before else None)
 
     @jax.jit
     def init_state(y_tr, w_tr):
@@ -2031,13 +2047,18 @@ def _make_boost_fn(
         [start, start + chunk_len). Chunking is invisible to the result —
         the per-iteration RNG folds the iteration index into the carried
         key, so every chunk boundary gives the same forest."""
-        step = _make_step(
+        step, loss_after = _make_step(
             bins_tr, y_tr, w_tr, bins_va, y_va, w_va, x_tr_raw, x_va_raw,
             set_tr, set_va, vs_tr, vs_va, groups_tr, groups_va,
         )
-        return jax.lax.scan(
-            step, carry, start + jnp.arange(chunk_len)
-        )
+        carry, ys = jax.lax.scan(step, carry, start + jnp.arange(chunk_len))
+        if loss_after is not None:
+            # Each step read the loss of the forest before its tree: the
+            # chunk's last forest is viewed once more, for its loss
+            # alone, and every loss moves to the tree that made it.
+            tls = jnp.concatenate([ys[2][1:], loss_after(carry[0])[None]])
+            ys = ys[:2] + (tls,) + ys[3:]
+        return carry, ys
 
     return _BoostFns(init_state, run_chunk, use_dart)
 
@@ -2434,6 +2455,11 @@ def _train_gbt(
 
     chunks_done = 0
     chunk_walls = []
+    # A ranking loss's views of the training scores that this call's
+    # chunks made, and the trees they grew: one a tree and one a chunk
+    # for its last forest, two a tree under DART (`_make_step`'s
+    # `loss_before`).
+    rank_views = grown = 0
     with store.guard() as guard:
         while start < num_trees:
             c = _chunk_len(clen, start, num_trees, boost.use_dart)
@@ -2444,6 +2470,9 @@ def _train_gbt(
                 boost, carry, start, c, *data_args, timer=timer,
                 **data_kwargs
             )
+            if groups_tr is not None:
+                rank_views += 2 * c if boost.use_dart else c + 1
+                grown += min(c, num_trees - start)
             part = _chunk_arrays_from_ys(ys, timer)
             _note_chunk(
                 chunk_walls, start, c, num_trees, t0_ns, part, nv_rows
@@ -2499,6 +2528,8 @@ def _train_gbt(
             if deadline is not None and time.monotonic() >= deadline:
                 break
 
+    if grown:
+        timer.counts["device_loop.rank_score_views"] = rank_views / grown
     with timer.stage("device_loop.merge"):
         trees, lvs, tls, vls, obl_w, obl_b, vs_a, vs_b = _merge_chunk_parts(
             store.parts(), num_trees, boost.use_dart, carry

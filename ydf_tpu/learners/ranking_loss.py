@@ -43,6 +43,16 @@ G)), each top document against every document of its query, and never a
 `[G, G]` block; the top T come from T passes of arg-max (first position
 wins a tie: the tie rule), so nothing is sorted. The sums are those of
 the `[G, G]` formula in another order.
+
+**One view of the training scores a tree.** `grad_hess_loss` gives a
+tree's lambdas and the loss of the scores they come from out of one
+`to_groups` and one pass of top documents a bucket: the DCG is read off
+the lambdas' picks. Outside DART the scores a tree's lambdas come from
+are the forest before it, so the boosting step reports that forest's
+loss, and the chunk views its last forest once more for the loss alone
+(`learners/gbt.py` `run_chunk`): four trees in one chunk make five views
+of the training scores where one for the lambdas and one for the loss a
+tree made eight.
 """
 
 from __future__ import annotations
@@ -247,7 +257,8 @@ class LambdaMartNdcg:
         )
 
     def _bucket_lambdas(self, s, y, m, gains, inv_maxdcg):
-        """s, y, m, gains: [Q, G]; inv_maxdcg: [Q]. Returns (g, h) [Q, G]."""
+        """s, y, m, gains: [Q, G]; inv_maxdcg: [Q]. Returns (g, h) [Q, G]
+        and the DCG [Q] of the same top documents."""
         T = min(self.ndcg_truncation, s.shape[1])
         top = _top_documents(s, m, T)
         discs = _position_discounts(T)
@@ -276,31 +287,34 @@ class LambdaMartNdcg:
                 picked, -jnp.sum(lam, axis=1, keepdims=True), 0.0)
             h = h + hl + jnp.where(
                 picked, jnp.sum(hl, axis=1, keepdims=True), 0.0)
-        return g, h
+        dcg = sum(_take(gains, picked) * d for picked, d in zip(top, discs))
+        return g, h, dcg
 
-    def grad_hess(self, labels, preds, groups):
-        groups, ctx = groups
-        with jax.named_scope("ydf.rank"):
-            s_b = to_groups(groups, preds[:, 0])
-            gh = [self._bucket_lambdas(s, *c) for s, c in zip(s_b, ctx)]
-            g, h = from_groups(
-                groups, [a for a, _ in gh], [b for _, b in gh]
-            )
-        return g[:, None], h[:, None]
-
-    def loss(self, labels, preds, weights, tag: str = "train", groups=None):
-        """-NDCG@truncation averaged over the queries that have a
-        relevant document."""
+    def grad_hess_loss(self, labels, preds, groups):
+        """(g, h, loss) of `preds` from one view of them: the loss is
+        -NDCG@truncation averaged over the queries that have a relevant
+        document, the DCG taken from the lambdas' top documents."""
         groups, ctx = groups
         total = jnp.float32(0.0)
         count = jnp.float32(0.0)
+        g_b, h_b = [], []
         with jax.named_scope("ydf.rank"):
-            for s, (_, m, gains, inv) in zip(
-                to_groups(groups, preds[:, 0]), ctx
-            ):
-                total += jnp.sum(self._dcg(s, m, gains) * inv)
-                count += jnp.sum(inv > 0)
-        return -total / (count + _EPS)
+            for s, c in zip(to_groups(groups, preds[:, 0]), ctx):
+                g, h, dcg = self._bucket_lambdas(s, *c)
+                g_b.append(g)
+                h_b.append(h)
+                total += jnp.sum(dcg * c[3])
+                count += jnp.sum(c[3] > 0)
+            g, h = from_groups(groups, g_b, h_b)
+        return g[:, None], h[:, None], -total / (count + _EPS)
+
+    def grad_hess(self, labels, preds, groups):
+        return self.grad_hess_loss(labels, preds, groups)[:2]
+
+    def loss(self, labels, preds, weights, tag: str = "train", groups=None):
+        """`grad_hess_loss`'s loss: a compiled program drops the lambdas
+        that nothing reads."""
+        return self.grad_hess_loss(labels, preds, groups)[2]
 
     def predict_proba(self, preds):
         return preds
@@ -314,8 +328,8 @@ class XeNdcg(LambdaMartNdcg):
     normalized relevance-gain distribution. Gradients are the listwise
     softmax residual, no pairs.
 
-    Shares LambdaMartNdcg's group layout; only the gradient and loss
-    computations differ.
+    Shares LambdaMartNdcg's group layout; only `grad_hess_loss`
+    differs.
     """
 
     name = "XE_NDCG_MART"
@@ -332,21 +346,11 @@ class XeNdcg(LambdaMartNdcg):
         t = jnp.where(denom > 0, gains / (denom + _EPS), 0.0)
         return p, t, denom > 0
 
-    def grad_hess(self, labels, preds, groups):
+    def grad_hess_loss(self, labels, preds, groups):
+        """(g, h, loss) of `preds` from one view of them, the softmax
+        terms shared by the gradient and the loss."""
         groups, ctx = groups
         g_b, h_b = [], []
-        with jax.named_scope("ydf.rank"):
-            for s, (_, m, gains, _inv) in zip(
-                to_groups(groups, preds[:, 0]), ctx
-            ):
-                p, t, valid = self._softmax_terms(s, m, gains)
-                g_b.append(jnp.where(valid, p - t, 0.0))
-                h_b.append(jnp.where(valid, p * (1.0 - p), 0.0))
-            g, h = from_groups(groups, g_b, h_b)
-        return g[:, None], jnp.maximum(h[:, None], 1e-6)
-
-    def loss(self, labels, preds, weights, tag: str = "train", groups=None):
-        groups, ctx = groups
         total = jnp.float32(0.0)
         count = jnp.float32(0.0)
         with jax.named_scope("ydf.rank"):
@@ -354,7 +358,11 @@ class XeNdcg(LambdaMartNdcg):
                 to_groups(groups, preds[:, 0]), ctx
             ):
                 p, t, valid = self._softmax_terms(s, m, gains)
+                g_b.append(jnp.where(valid, p - t, 0.0))
+                h_b.append(jnp.where(valid, p * (1.0 - p), 0.0))
                 ce = -jnp.sum(t * jnp.log(p + _EPS), axis=1, keepdims=True)
                 total += jnp.sum(jnp.where(valid, ce, 0.0))
                 count += jnp.sum(valid)
-        return total / (count + _EPS)
+            g, h = from_groups(groups, g_b, h_b)
+        return g[:, None], jnp.maximum(h[:, None], 1e-6), total / (
+            count + _EPS)
