@@ -92,14 +92,15 @@ def test_learner_describes_its_stats_only_where_it_can_see(
     seen = []
     plain = histogram_ops._histogram_matmul
 
-    def spy(bins, slot, stats, num_slots, num_bins, chunk, stat_columns):
+    def spy(bins, slot, stats, num_slots, num_bins, chunk, stat_columns,
+            feature_num_bins):
         if stats.dtype == np.float32:
             seen.append(histogram_ops.narrow_columns_per_slot(stat_columns))
         else:
             assert stats.dtype in (np.int8, jax.numpy.bfloat16)
             seen.append(stats.shape[1])
         return plain(bins, slot, stats, num_slots, num_bins, chunk,
-                     stat_columns)
+                     stat_columns, feature_num_bins)
 
     fresh_programs.setattr(histogram_ops, "_histogram_matmul", spy)
     model = ydf.GradientBoostedTreesLearner(

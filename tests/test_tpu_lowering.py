@@ -152,13 +152,16 @@ def _described(columns_per_slot):
     return _hist_stat_columns(None, "RANDOM", loss())
 
 
-def _feature_loops(one_chip, L, quant="f32", columns_per_slot=9):
+def _feature_loops(one_chip, L, quant="f32", columns_per_slot=9, counts=None):
     """{loop body: its operations a feature as long as the chunk} of the
-    compiled `_histogram_matmul`. One chunk and a tail: both loops."""
+    compiled `_histogram_matmul`, of four features of 256 bins or of
+    features of the bin `counts` given. One chunk and a tail: both
+    loops."""
     chunk = 1 << 18
     hlo = tl.compile_histogram_matmul(
-        one_chip, L=L, F=4, quant=quant, n=chunk + 1000, chunk=chunk,
-        stat_columns=_described(columns_per_slot),
+        one_chip, L=L, F=len(counts) if counts else 4, quant=quant,
+        n=chunk + 1000, chunk=chunk,
+        stat_columns=_described(columns_per_slot), feature_num_bins=counts,
     )
     ops = tl.per_feature_body_ops(hlo, chunk)
     assert ops, "no loop over features in the compiled program"
@@ -199,6 +202,33 @@ def test_narrow_operand_is_built_once_a_chunk(
         assert not extra, extra
 
 
+@pytest.mark.parametrize("L,columns_per_slot", [(1, 7), (16, 7), (4, 9)])
+def test_narrow_operand_is_built_once_a_chunk_beside_packs(
+    one_chip, L, columns_per_slot
+):
+    """With the airline table's bin counts (PERF.md section 6:
+    four features of 8 to 32 bins in one tile of 128 one-hot rows,
+    beside four of 256) every loop of the compiled program holds the
+    bin columns' slices and the contraction(s) and nothing else as long
+    as the chunk: the narrow operand is built once a chunk, not once a
+    feature and not once a pack."""
+    chunk = 1 << 18
+    loops = _feature_loops(
+        one_chip, L, "f32", columns_per_slot,
+        counts=(256, 256, 13, 32, 8, 23, 256, 256),
+    )
+    for mine in loops.values():
+        assert any(op["contracts"] for op in mine), mine
+        extra = [
+            (op["name"], op["shape"]) for op in mine
+            if not op["contracts"] and not (
+                "dynamic-slice" in op["name"]
+                and op["shape"].startswith(f"s32[{chunk},1]")
+            )
+        ]
+        assert not extra, extra
+
+
 @pytest.mark.parametrize(
     "columns_per_slot,widths", [(9, [48, 96]), (7, [112]), (4, [64])]
 )
@@ -217,6 +247,102 @@ def test_level_5_is_one_dot_where_the_operand_is_described(
             for op in mine if op["contracts"]
         )
         assert got == widths, mine
+
+
+def test_histogram_at_256_bins_lowers_as_with_no_counts():
+    """Where every feature has more than 128 bins the one-hot has no
+    pack, and `_histogram_matmul` handed the bin counts lowers to the
+    text it lowers to with none, character for character (PERF.md
+    section 6): the numerical cells run the program they ran.
+    One feature of 13 bins changes it."""
+    from ydf_tpu.learners.gbt import _hist_stat_columns
+    from ydf_tpu.learners.losses import BinomialLogLikelihood
+    from ydf_tpu.ops.histogram import _histogram_matmul
+
+    n, F, L, chunk = 3 * 1024 + 100, 6, 4, 1024
+    args = (
+        jax.ShapeDtypeStruct((n, F), jnp.uint8),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+    )
+    columns = _hist_stat_columns(None, "RANDOM", BinomialLogLikelihood())
+
+    def text(counts):
+        return jax.jit(
+            lambda b, sl, st: _histogram_matmul(
+                b, sl, st, L, 256, chunk, columns, counts
+            )
+        ).lower(*args).as_text()
+
+    plain = text(None)
+    assert text((256,) * F) == plain
+    assert text((256,) * (F - 1) + (13,)) != plain
+
+
+class _Lowered(Exception):
+    """Ends a train() once its boosting program is lowered."""
+
+
+@pytest.mark.parametrize("loss", ["squared_error", "binomial", "lambdamart"])
+def test_boosting_program_at_256_bins_is_the_one_with_no_counts(
+    loss, monkeypatch
+):
+    """The program a GBT job dispatches, grower and all, at 4,000 rows
+    of numerical features of 256 bins: the learner hands the grower the
+    bin counts, and the lowered text is the one it gives where the
+    learner hands none (the CPU half of "nothing moves in the
+    numerical cells"). A first feature of 13 bins reaches the grower's
+    histograms and changes it."""
+    import ydf_tpu as ydf
+    from ydf_tpu.config import Task
+    from ydf_tpu.learners import gbt
+    from ydf_tpu.ops import device_loop
+
+    rng = np.random.default_rng(4)
+    n = 4_000
+    x = rng.normal(size=(n, 4)).astype(np.float32)
+    score = x[:, 0] - 0.5 * x[:, 1] * x[:, 2] + 0.3 * rng.normal(size=n)
+    data = {f"f{i}": x[:, i] for i in range(4)}
+    data["query"] = (np.arange(n) // 20).astype(np.int64)
+    task, data["label"] = {
+        "squared_error": (Task.REGRESSION, score),
+        "binomial": (Task.CLASSIFICATION, (score > 0).astype(np.int64)),
+        "lambdamart": (
+            Task.RANKING, np.digitize(score, [-1.0, 0.0, 1.0, 2.0])),
+    }[loss]
+    learner = ydf.GradientBoostedTreesLearner(
+        label="label", task=task, num_trees=2, max_depth=4, num_bins=256,
+        features=[f"f{i}" for i in range(4)],
+        **({"ranking_group": "query"} if loss == "lambdamart" else {}),
+    )
+    monkeypatch.setenv("YDF_TPU_HIST_IMPL", "matmul")
+    texts = []
+
+    def lower_and_stop(fn, timer, *args, **kwargs):
+        texts.append(fn.lower(*args, **kwargs).as_text())
+        raise _Lowered
+
+    monkeypatch.setattr(device_loop, "dispatch", lower_and_stop)
+    counts = []
+    described = gbt._hist_feature_num_bins
+    for describe in (
+        described, lambda binner: None,
+        lambda binner: (13,) + described(binner)[1:],
+    ):
+        monkeypatch.setattr(
+            gbt, "_hist_feature_num_bins",
+            lambda binner: counts.append(describe(binner)) or counts[-1],
+        )
+        jax.clear_caches()
+        gbt._make_boost_fn.cache_clear()
+        try:
+            with pytest.raises(_Lowered):
+                learner.train(data)
+        finally:
+            jax.clear_caches()
+            gbt._make_boost_fn.cache_clear()
+    assert counts == [(256,) * 4, None, (13,) + (256,) * 3]
+    assert len(texts) == 3 and texts[0] == texts[1] != texts[2]
 
 
 def test_per_feature_body_ops_reads_a_compiled_text():

@@ -52,6 +52,7 @@ from ydf_tpu.ops import device_loop, grower, lookup
 from ydf_tpu.ops.histogram import (
     StatColumn,
     narrow_columns_per_slot,
+    onehot_rows,
     resolve_hist_impl,
     resolve_hist_quant,
 )
@@ -976,9 +977,18 @@ class GradientBoostedTreesLearner(GenericLearner):
                         stat_columns, resolve_hist_quant(None)
                     )
                 )
+                feature_num_bins = _hist_feature_num_bins(binner)
+                streamed, packed = onehot_rows(
+                    feature_num_bins, bins_tr.shape[1], tree_cfg.num_bins
+                )
+                timer.counts["device_loop.hist_onehot_rows"] = float(streamed)
+                timer.counts["device_loop.hist_packed_features"] = float(
+                    packed
+                )
                 forest_stacked, leaf_values, logs = _train_gbt(
             **inputs,
             stat_columns=stat_columns,
+            feature_num_bins=feature_num_bins,
             groups_tr=rank[0] if rank is not None else None,
             groups_va=rank[1] if rank is not None else None,
             timer=timer,
@@ -1369,6 +1379,15 @@ def _hist_stat_columns(weights, sampling: str, loss_obj):
     return (StatColumn(), hessian, StatColumn(pieces=1))
 
 
+def _hist_feature_num_bins(binner):
+    """The bin count of each column of the bin matrix the boosting loop
+    grows every tree from, as the binner that made the bins says
+    (ops/histogram.py onehot_plan): every bin of column f is below
+    `binner.feature_num_bins[f]`. A pad column of a mesh, past them,
+    has none."""
+    return tuple(int(c) for c in binner.feature_num_bins[:binner.num_scalar])
+
+
 class _BoostFns(NamedTuple):
     """The jitted programs of one boosting configuration
     (`_make_boost_fn`): `init_state(y_tr, w_tr)` gives the first carry
@@ -1389,11 +1408,12 @@ def _make_boost_fn(
     oblique_weight_type="BINARY", oblique_weight_range=None,
     oblique_mode="SPARSE", mhld_max_attributes=4, num_label_classes=1,
     monotone=None, vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True,
-    stat_columns=None,
+    stat_columns=None, feature_num_bins=None,
 ):
     """Builds (and caches) the jitted boosting loop for one static config,
     as a `_BoostFns`. `stat_columns` is `_hist_stat_columns`' word on
-    the stats rows every tree is grown from.
+    the stats rows every tree is grown from; `feature_num_bins`, the
+    binner's bin count of each column of the bin matrix, or None.
 
     Caching the closure is what makes jax.jit's own cache effective across
     `train()` calls: a fresh closure per call would retrace + recompile the
@@ -1907,6 +1927,13 @@ def _make_boost_fn(
                     route_impl=route_impl,
                     route_fuse=route_fuse,
                     stat_columns=stat_columns,
+                    # The projections' columns sit between the numerical
+                    # and the categorical ones and have no count.
+                    feature_num_bins=None if feature_num_bins is None else (
+                        feature_num_bins[:num_numerical]
+                        + (B,) * (grow_num_numerical - num_numerical)
+                        + feature_num_bins[num_numerical:]
+                    ),
                 )
                 # Leaf values scaled by shrinkage at storage time, like the
                 # reference (set_leaf applies shrinkage). The raw
@@ -2355,7 +2382,7 @@ def _train_gbt(
     x_tr_raw=None, x_va_raw=None, set_tr=None, set_va=None,
     vs_tr=None, vs_va=None, groups_tr=None, groups_va=None,
     vs_Ac=0, vs_Ap=0, route_impl="xla", route_fuse=True, stat_columns=None,
-    cache_dir=None, resume=False, snapshot_interval=50,
+    feature_num_bins=None, cache_dir=None, resume=False, snapshot_interval=50,
     abort_after_chunks=None, preempt_after_chunks=None,
     early_stop_lookahead=0, deadline=None, timer,
 ):
@@ -2396,6 +2423,7 @@ def _train_gbt(
             route_impl=route_impl,
             route_fuse=route_fuse,
             stat_columns=stat_columns,
+            feature_num_bins=feature_num_bins,
         )
     nv_rows = bins_va.shape[0]
     data_args = (bins_tr, y_tr, w_tr, bins_va, y_va, w_va) + (

@@ -579,8 +579,8 @@ def grow_tree(
         "rule", "max_depth", "frontier", "max_nodes", "num_bins",
         "num_numerical", "min_examples", "min_split_gain",
         "candidate_features", "num_valid_features", "hist_impl",
-        "hist_subtract", "hist_quant", "stat_columns", "route_impl",
-        "route_fuse", "dense_lookups", "monotone",
+        "hist_subtract", "hist_quant", "stat_columns", "feature_num_bins",
+        "route_impl", "route_fuse", "dense_lookups", "monotone",
     ),
 )
 def _grow_tree_jit(
@@ -632,6 +632,13 @@ def _grow_tree_jit(
     # is derived where the stats are made (learners/gbt.py), never
     # taken from a user.
     stat_columns: Optional[tuple] = None,
+    # The bin count of each of the first columns of `bins` (the binner's
+    # feature_num_bins: every bin of column f is below it), or None:
+    # which columns the matmul histogram may give one-hot tiles they
+    # share (ops/histogram.py onehot_plan). The histograms are the same
+    # bit for bit with it and without; it is derived where the bins are
+    # made (learners/gbt.py), never taken from a user.
+    feature_num_bins: Optional[tuple] = None,
     # Example-routing impl for the per-layer slot/leaf update: "xla"
     # (default — the exact oracle chain of gathers/selects) or "native"
     # (the fused ydf_route_update CPU kernel, one multithreaded pass per
@@ -884,6 +891,7 @@ def _grow_tree_jit(
                         num_bins=B, impl=hist_impl, quant=hist_quant,
                         quant_scale=qscale, compact=_compact_cap(Lh),
                         stat_columns=stat_columns,
+                        feature_num_bins=feature_num_bins,
                     )  # [Lh, F, B, S] (dequantized f32 under quantization)
                 hist = sibling_reconstruct(
                     hist_small, parent_hist, small_is_left, Ld
@@ -900,6 +908,7 @@ def _grow_tree_jit(
                         bins, slot, hist_stats, num_slots=Ld, num_bins=B,
                         impl=hist_impl, quant=hist_quant,
                         quant_scale=qscale, stat_columns=stat_columns,
+                        feature_num_bins=feature_num_bins,
                     ), slot, leaf_id),
                     by_subtraction,
                 )
@@ -920,7 +929,7 @@ def _grow_tree_jit(
             hist = histogram(
                 bins, slot, hist_stats, num_slots=Ld, num_bins=B,
                 impl=hist_impl, quant=hist_quant, quant_scale=qscale,
-                stat_columns=stat_columns,
+                stat_columns=stat_columns, feature_num_bins=feature_num_bins,
             )  # [Ld, F, B, S]
         right_scalar = None
         if F > 0:

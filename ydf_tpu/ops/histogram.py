@@ -32,6 +32,12 @@ Two implementations:
     (`narrow` in _histogram_matmul): written as a [S, L, n] product and
     reshaped, it was rebuilt for every feature by the compiled program
     at L = 2 and 4 (PERF.md section 6, PR 34).
+    The one-hot goes by each feature's bin count where the caller gives
+    them (`feature_num_bins`, the binner's): features of at most 128
+    bins share one-hot tiles of 128 rows, one MXU tile high, each in
+    rows of its own (onehot_plan, _pack_onehot), where each took 256
+    rows; a feature of more keeps its own 256. Where no feature has 128
+    bins or fewer the program is the one it was.
 
   * "segment" (CPU / small data): `jax.ops.segment_sum` over the fused
     (slot, bin) index — fast on CPU where scatter-add is native; used by the
@@ -87,6 +93,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Concrete implementations _histogram_jit dispatches on; "auto" is
 # resolved to one of these BEFORE the jit boundary (resolve_hist_impl).
@@ -134,6 +141,9 @@ _HIST_QUANTS = frozenset({"f32", "bf16x2", "int8"})
 
 # Columns of one MXU tile (128 x 128 on every TPU generation so far).
 _MXU_COLUMNS = 128
+# Rows of the same tile: a one-hot of at most this many rows is one tile
+# high on the MXU, whatever its features' bin counts.
+_MXU_ROWS = 128
 
 
 class StatColumn(NamedTuple):
@@ -186,6 +196,55 @@ def narrow_columns_per_slot(
         stat_columns = stat_columns or (StatColumn(),) * num_stats
         return sum(_piece_counts(stat_columns, num_stats))
     return {"bf16x2": 2, "int8": 1}[quant] * num_stats
+
+
+class OneHotPlan(NamedTuple):
+    """How the `matmul` histogram's one-hot is laid out for one chunk:
+    `wide` features take `num_bins` rows each, in a loop over them; each
+    pack is one MXU tile high (`_MXU_ROWS`) and holds features whose
+    bins fit it side by side, as (feature, first row, bins)."""
+
+    wide: Tuple[int, ...]
+    packs: Tuple[Tuple[Tuple[int, int, int], ...], ...]
+
+
+def onehot_plan(feature_num_bins, num_features: int, num_bins: int):
+    """The one-hot's layout (OneHotPlan) for `num_features` columns of
+    bins, of which the first `len(feature_num_bins)` hold bins below
+    their count there (the binner's `feature_num_bins`): those of at
+    most `_MXU_ROWS` bins go first-fit by decreasing count into packs of
+    one tile. A column with no count (none given, or a pad column past
+    the description) keeps `num_bins` rows of its own. A wrong count is
+    not caught: a bin at or past it is left out of the sums, so a count
+    is derived where the bins are made, never passed through from a
+    user."""
+    described = tuple(feature_num_bins or ())[:num_features]
+    counts = {
+        f: max(1, min(int(c), num_bins)) for f, c in enumerate(described)
+        if int(c) <= _MXU_ROWS
+    }
+    packs = []  # [rows used, [(feature, first row, bins), ...]]
+    for f in sorted(counts, key=lambda f: -counts[f]):
+        room = [p for p in packs if p[0] + counts[f] <= _MXU_ROWS]
+        if not room:
+            packs.append([0, []])
+            room = packs[-1:]
+        room[0][1].append((f, room[0][0], counts[f]))
+        room[0][0] += counts[f]
+    return OneHotPlan(
+        wide=tuple(f for f in range(num_features) if f not in counts),
+        packs=tuple(tuple(members) for _, members in packs),
+    )
+
+
+def onehot_rows(feature_num_bins, num_features: int, num_bins: int):
+    """(one-hot rows the `matmul` histogram streams a chunk, features it
+    packs): `num_bins` a wide feature, `_MXU_ROWS` a pack."""
+    plan = onehot_plan(feature_num_bins, num_features, num_bins)
+    return (
+        num_bins * len(plan.wide) + _MXU_ROWS * len(plan.packs),
+        sum(len(pack) for pack in plan.packs),
+    )
 
 
 def split_bf16(x, pieces):
@@ -274,14 +333,41 @@ def _histogram_segment(
     return jnp.transpose(hist, (1, 0, 2, 3))  # [L, F, B, S]
 
 
+def _pack_onehot(pack, b_chunk):
+    """One pack's one-hot, bool [_MXU_ROWS, chunk], from its members
+    (feature, first row, bins) in the order of their first rows: each
+    row takes the bin column of the last member whose first row is at
+    or before it, by a chain of selects on the row, and holds a 1 where
+    that bin is the row less the member's first row; rows past the
+    members match no bin, and neither does a bin past its member's
+    count. One compare an element, and the selects and the compare fuse
+    into the contraction: on a v5e the fastest of three forms (PERF.md
+    section 6), 0.130 s a level call where a concatenation of
+    each member's own compares took 0.223 and a loop over packs with a
+    compare a member 0.142."""
+    rows = jnp.arange(_MXU_ROWS, dtype=jnp.int32)[:, None]
+    local = np.full((_MXU_ROWS, 1), -1, np.int32)  # the bin a row counts
+    code = None
+    for f, first, count in pack:
+        local[first:first + count, 0] = np.arange(count)
+        column = b_chunk[:, f].astype(jnp.int32)[None, :]
+        code = (
+            column if code is None else jnp.where(rows >= first, column, code)
+        )
+    return code == jnp.asarray(local)
+
+
 def _histogram_matmul(
     bins, slot, stats, num_slots: int, num_bins: int, chunk: int = 1 << 18,
-    stat_columns=None,
+    stat_columns=None, feature_num_bins=None,
 ):
     n, F = bins.shape
     S = stats.shape[1]
     L, B = num_slots, num_bins
     chunk = min(chunk, max(n, 1))
+    # Features of at most 128 bins share one-hot tiles (onehot_plan); a
+    # table where every feature has more keeps one loop over features.
+    plan = onehot_plan(feature_num_bins, F, B)
     # Whole chunks are sliced out of the operands as they come, inside
     # the loop; the ragged tail is one more call of the same body on its
     # rows padded to a chunk, after the loop. No operand is reshaped to
@@ -370,11 +456,9 @@ def _histogram_matmul(
             narrow(st_chunk[:, first:first + width]) for first, width in dots
         ]
 
-        def per_feature(f, acc):
-            oh = (
-                bvals[:, None] == b_chunk[:, f].astype(jnp.int32)[None, :]
-            ).astype(st_chunk.dtype)  # [B, chunk]
-            h = jnp.concatenate(
+        def contract(oh):
+            # [rows, chunk] one-hot -> [rows, blocks*L] sums
+            return jnp.concatenate(
                 [
                     jax.lax.dot_general(
                         oh,
@@ -385,10 +469,30 @@ def _histogram_matmul(
                     for a_dot in a_dots
                 ],
                 axis=1,
-            )  # [B, blocks*L]
-            return acc.at[f].add(h)
+            )
 
-        return jax.lax.fori_loop(0, F, per_feature, carry)
+        def onehot(f):
+            return (
+                bvals[:, None] == b_chunk[:, f].astype(jnp.int32)[None, :]
+            ).astype(st_chunk.dtype)  # [B, chunk]
+
+        def per_feature(f, acc):
+            return acc.at[f].add(contract(onehot(f)))  # [B, blocks*L]
+
+        if not plan.packs:
+            return jax.lax.fori_loop(0, F, per_feature, carry)
+        wide_acc, pack_acc = carry
+        if plan.wide:
+            wide_of = jnp.asarray(plan.wide, jnp.int32)
+            wide_acc = jax.lax.fori_loop(
+                0, len(plan.wide),
+                lambda i, acc: acc.at[i].add(contract(onehot(wide_of[i]))),
+                wide_acc,
+            )
+        return wide_acc, pack_acc + jnp.stack([
+            contract(_pack_onehot(pack, b_chunk).astype(st_chunk.dtype))
+            for pack in plan.packs
+        ])  # [packs, _MXU_ROWS, blocks*L]
 
     def whole_chunk(i, carry):
         rows = [
@@ -397,7 +501,15 @@ def _histogram_matmul(
         ]
         return one_chunk(carry, *rows)
 
-    hist = jnp.zeros((F, B, len(blocks) * L), dtype=acc_dtype)
+    if plan.packs:
+        hist = (
+            jnp.zeros((len(plan.wide), B, len(blocks) * L), acc_dtype),
+            jnp.zeros(
+                (len(plan.packs), _MXU_ROWS, len(blocks) * L), acc_dtype
+            ),
+        )
+    else:
+        hist = jnp.zeros((F, B, len(blocks) * L), dtype=acc_dtype)
     hist = jax.lax.fori_loop(0, n_full, whole_chunk, hist)
     if tail:
         # Padded examples land in the trash slot L and add exact zeros,
@@ -410,6 +522,19 @@ def _histogram_matmul(
             jnp.pad(slot[n - tail:], (0, pad), constant_values=L),
             jnp.pad(stats[n - tail:], ((0, pad), (0, 0))),
         )
+    if plan.packs:
+        # Each feature's sums from its own one-hot rows: a packed
+        # feature's bins past its count are exact zeros, as a wide
+        # feature's are.
+        wide_acc, pack_acc = hist
+        sums = {f: wide_acc[i] for i, f in enumerate(plan.wide)}
+        for p, pack in enumerate(plan.packs):
+            for f, first, count in pack:
+                sums[f] = jnp.pad(
+                    pack_acc[p, first:first + count],
+                    ((0, B - count), (0, 0)),
+                )
+        hist = jnp.stack([sums[f] for f in range(F)])
     # A column's slabs, smallest piece first: the f32 sums of three
     # slabs lose no more in this fold than one accumulator of whole
     # values would; a one-piece column is its one slab. A column that
@@ -464,12 +589,12 @@ def _compact_live_rows(bins, slot, stats, cap: int, num_slots: int):
     jax.jit,
     static_argnames=(
         "num_slots", "num_bins", "impl", "chunk", "quant", "compact",
-        "stat_columns",
+        "stat_columns", "feature_num_bins",
     ),
 )
 def _histogram_jit(
     bins, slot, stats, quant_scale, num_slots, num_bins, impl, chunk,
-    quant, compact, stat_columns=None,
+    quant, compact, stat_columns=None, feature_num_bins=None,
 ):
     if impl == "auto":
         # Refuse a literal "auto" INSIDE a jit boundary: callers that
@@ -546,10 +671,11 @@ def _histogram_jit(
             )
         elif impl == "matmul":
             # Only an f32 operand has pieces to leave out: the other
-            # modes' operands, and the other impls, take no description.
+            # modes' operands, and the other impls, take no description
+            # of the stats; no other impl takes the bins' counts.
             out = _histogram_matmul(
                 bins_d, slot_d, stats_q, num_slots, num_bins, chunk,
-                stat_columns,
+                stat_columns, feature_num_bins,
             )
         elif impl in ("pallas", "pallas_interpret"):
             from ydf_tpu.ops.histogram_pallas import histogram_pallas
@@ -723,6 +849,7 @@ def histogram(
     quant_scale: jax.Array | None = None,  # f32 [S] int8 scale (traced)
     compact: int = 0,
     stat_columns: Optional[Tuple[StatColumn, ...]] = None,
+    feature_num_bins: Optional[Tuple[int, ...]] = None,
 ) -> jax.Array:
     """Returns hist[num_slots, F, num_bins, S] = Σ_examples stats.
 
@@ -737,9 +864,14 @@ def histogram(
     full-row fallback when they don't fit). `stat_columns`, one
     StatColumn a column of f32 `stats`, lets the matmul impl leave the
     bf16 pieces that are zero by construction off the MXU; the sums are
-    the same bit for bit, and every other impl and mode ignores it."""
+    the same bit for bit, and every other impl and mode ignores it.
+    `feature_num_bins`, the bin count of each of the first features
+    (every bin of feature f is below its count), lets the matmul impl
+    give the features of at most 128 bins one-hot tiles they share
+    (onehot_plan); the sums are the same bit for bit, and every other
+    impl ignores it."""
     return _histogram_jit(
         bins, slot, stats, quant_scale, num_slots, num_bins,
         resolve_hist_impl(impl), chunk, resolve_hist_quant(quant),
-        compact, stat_columns,
+        compact, stat_columns, feature_num_bins,
     )

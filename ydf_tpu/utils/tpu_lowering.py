@@ -206,12 +206,14 @@ def deviceless_tpu_sharding(topology_name: str = "v5e:2x2"):
 def compile_histogram_matmul(
     sharding, L: int, F: int, quant: str = "f32", n: int = 3 << 18,
     chunk: int = 1 << 18, num_bins: int = 256, stat_columns=None,
+    feature_num_bins=None,
 ) -> str:
     """XLA:TPU's compiled text of `ops/histogram.py:_histogram_matmul`
     itself for the chip `sharding` describes, at `L` slots, `F`
     features and the stats operand the `quant` mode hands it, described
     by `stat_columns` (one `ops/histogram.py` StatColumn a column, as
-    `learners/gbt.py:_hist_stat_columns` gives them; None: any f32).
+    `learners/gbt.py:_hist_stat_columns` gives them; None: any f32),
+    its features' bin counts by `feature_num_bins` (None: none).
     The text carries the compiler's placement of every operation, its
     layouts and its `estimated_cycles`: no chip reading."""
     from ydf_tpu.ops.histogram import _histogram_matmul
@@ -224,7 +226,7 @@ def compile_histogram_matmul(
     )
     fn = jax.jit(
         lambda b, sl, st: _histogram_matmul(
-            b, sl, st, L, num_bins, chunk, stat_columns
+            b, sl, st, L, num_bins, chunk, stat_columns, feature_num_bins
         )
     )
     return fn.lower(*args).compile().as_text()
